@@ -1,11 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU set
-REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) for compiled Mosaic.
+The kernels run in interpret mode on the CPU backend and compile through
+Mosaic everywhere else; an explicit `interpret=` argument overrides that.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
@@ -19,25 +18,18 @@ from repro.kernels import paged_attention as _pa
 
 
 def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() == "cpu"
 
 
 def paged_kernel_enabled(override: Optional[bool] = None) -> bool:
     """Should the paged decode paths use the Pallas kernels?
 
-    Resolution order: explicit `override` (engine config / function arg)
-    > REPRO_PAGED_KERNEL env var > backend default (on for TPU, off for
-    CPU — interpret-mode kernels are Python-speed, so the XLA gather
-    fallback stays the CPU default; set REPRO_PAGED_KERNEL=1 to force
-    the kernel path, e.g. for interpret-mode parity runs)."""
+    An explicit `override` (engine config / function arg) wins; otherwise
+    the kernels are on for TPU and off elsewhere (interpret-mode kernels
+    run at Python speed, so the XLA gather path is the CPU default; pass
+    True to run the kernels in interpret mode, e.g. for parity tests)."""
     if override is not None:
         return bool(override)
-    env = os.environ.get("REPRO_PAGED_KERNEL")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() == "tpu"
 
 

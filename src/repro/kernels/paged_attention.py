@@ -242,9 +242,14 @@ def paged_flash_decode_mla(q_abs: jnp.ndarray, q_rope: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _write_kernel(phys_ref, pos_ref, val_ref, leaf_ref, out_ref, *, bs: int):
+    # masked whole-block write: a store at a dynamic row of a packed bf16
+    # tile cannot be proven tile-aligned by the TPU compiler, a select
+    # over the whole [bs, F] block can
     b = pl.program_id(0)
-    out_ref[...] = leaf_ref[...]
-    out_ref[0, pos_ref[b] % bs] = val_ref[0].astype(out_ref.dtype)
+    block = leaf_ref[0]                                  # [bs, F]
+    rows = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0)
+    val = jnp.broadcast_to(val_ref[0].astype(block.dtype), block.shape)
+    out_ref[0] = jnp.where(rows == pos_ref[b] % bs, val, block)
 
 
 def paged_write_token(leaf: jnp.ndarray, tables: jnp.ndarray,
@@ -262,7 +267,9 @@ def paged_write_token(leaf: jnp.ndarray, tables: jnp.ndarray,
     B, M = tables.shape
     F = int(np.prod(rest)) if rest else 1
     leaf2 = leaf.reshape(N, bs, F)
-    vals2 = values.reshape(B, F)
+    # [B, 1, F]: a (1, 1, F) block keeps its last two dims equal to the
+    # array's, as the TPU block-shape rule needs
+    vals2 = values.reshape(B, 1, F)
     blk = jnp.clip(positions.astype(jnp.int32) // bs, 0, M - 1)
     phys = jnp.take_along_axis(tables.astype(jnp.int32),
                                blk[:, None], axis=1)[:, 0]     # [B]
@@ -270,7 +277,7 @@ def paged_write_token(leaf: jnp.ndarray, tables: jnp.ndarray,
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, F), lambda b, ph, p: (b, 0)),
+            pl.BlockSpec((1, 1, F), lambda b, ph, p: (b, 0, 0)),
             pl.BlockSpec((1, bs, F), lambda b, ph, p: (ph[b], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bs, F), lambda b, ph, p: (ph[b], 0, 0)),

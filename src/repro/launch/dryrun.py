@@ -29,15 +29,6 @@ from repro.launch.specs import (adapt_for_shape, batch_specs, cache_specs,
 from repro.launch.steps import make_prefill, make_serve_step, make_train_step
 
 
-def _cost_dict(compiled):
-    """Normalized `cost_analysis()`: jax 0.4.x returns a one-element list
-    of dicts, jax >= 0.5 returns the dict directly."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _lower_for(cfg, shape, mesh, ctx, rules=None, opt_rules=None):
     """Build + lower the appropriate step for `shape.kind`."""
     with jax.default_device(jax.devices("cpu")[0]):
@@ -90,7 +81,7 @@ def _measured_costs(cfg, shape, mesh, ctx, rules=None, opt_rules=None):
     lowered = _lower_for(cfg, shape, mesh, ctx, rules=rules,
                          opt_rules=opt_rules)
     compiled = lowered.compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = rf.collective_bytes(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)), coll)
@@ -149,7 +140,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     memstats = compiled.memory_analysis()
     hlo = compiled.as_text()
     if skip_extrapolation:

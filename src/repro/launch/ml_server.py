@@ -4,9 +4,11 @@ Owns the large `ModelRunner` and serves batched regeneration over the
 socket RPC protocol in `repro.serving.remote`. The serving engine
 connects with ``--large-backend socket --ml-address host:port`` (or
 ``--large-backend pool`` across several of these); greedy parity across
-processes holds because `build_runners(arch, seed)` derives the large
-model's parameters deterministically from ``--arch``/``--seed`` — run
-the server and the engine with the same values.
+processes holds because `build_runners(arch, seed, widths)` derives the
+large model's parameters deterministically from ``--arch``/``--seed``/
+``--widths`` — run the server and the engine with the same values. On a
+host with one TPU chip only one process can hold the chip, so there the
+M_L tier stays in the engine's process (``--large-backend sync``).
 
     # one replica on a fixed port
     PYTHONPATH=src python -m repro.launch.ml_server --port 7070
@@ -25,7 +27,8 @@ from __future__ import annotations
 import argparse
 import time
 
-from repro.launch.serve import build_runners
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import WIDTHS, build_runners
 from repro.serving.remote import MLServer
 
 
@@ -36,6 +39,9 @@ def main():
                          "--arch for cross-process greedy parity")
     ap.add_argument("--seed", type=int, default=0,
                     help="parameter seed; must match the engine's --seed")
+    ap.add_argument("--widths", choices=WIDTHS, default="reduced",
+                    help="model ladder widths; must match the engine's "
+                         "--widths")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0,
                     help="listen port (0 = ephemeral, printed at startup)")
@@ -52,7 +58,8 @@ def main():
                     help="injected per-batch response delay (benches)")
     args = ap.parse_args()
 
-    _small, large, _cfg = build_runners(args.arch, args.seed)
+    enable_compile_cache()
+    _small, large, _cfg = build_runners(args.arch, args.seed, args.widths)
     srv = MLServer(large, max_new=args.max_new,
                    large_batch=args.large_batch or None,
                    max_wait=args.max_wait or None,

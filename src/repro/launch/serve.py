@@ -1,5 +1,8 @@
-"""Cascade serving driver: small + large model, Gatekeeper deferral
-(CPU-scale demonstration of the deployment path).
+"""Cascade serving entry point: small + large model, Gatekeeper deferral.
+
+--widths picks the model ladder: reduced (default; 2-layer CPU-scale
+variant of --arch) or published (the --arch config as published, params
+in bf16 — what a TPU serves; see chip_smoke.py).
 
 Two engines (see repro.serving):
   * static      — lock-step batches, full max_new decode before deferral
@@ -83,6 +86,7 @@ import jax
 from repro.configs import get_config, reduced
 from repro.core.deferral import SemanticAgreementSignal
 from repro.data.synthetic import make_lm_stream, make_ragged_lm_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.serving import (CascadeEngine, CascadeSpec, CascadeTier,
                            ContinuousCascadeEngine, DeferralEdge,
@@ -93,18 +97,30 @@ from repro.serving.obs import (Observability, add_obs_args,
                                obs_config_from_args)
 
 
-def build_runners(arch: str, seed: int):
-    small, large = build_ladder(arch, seed, 2)
+WIDTHS = ("reduced", "published")
+
+
+def build_runners(arch: str, seed: int, widths: str = "reduced"):
+    small, large = build_ladder(arch, seed, 2, widths)
     return small, large, small.cfg
 
 
-def build_ladder(arch: str, seed: int, n_tiers: int):
-    """One ModelRunner per tier, capacity interpolated from the reduced
-    `arch` (tier 0) up to the demo "large" config (last tier):
-    intermediate tiers grow depth/FFN only, keeping d_model/head count —
-    cheap enough that a CPU demo of a 3- or 4-tier ladder stays fast."""
+def build_ladder(arch: str, seed: int, n_tiers: int,
+                 widths: str = "reduced"):
+    """One ModelRunner per tier, capacity interpolated from `arch` (tier
+    0) up to the "large" config derived from it (last tier: 4 layers,
+    twice the width and FFN, 8 heads): intermediate tiers grow depth/FFN
+    only, keeping d_model/head count. `widths="reduced"` starts from the
+    2-layer CPU-scale variant, so a CPU demo of a 3- or 4-tier ladder
+    stays fast; `"published"` starts from the config as published, with
+    bf16 params."""
     key = jax.random.PRNGKey(seed)
-    small_cfg = reduced(get_config(arch))
+    if widths == "reduced":
+        small_cfg = reduced(get_config(arch))
+    elif widths == "published":
+        small_cfg = get_config(arch).replace(param_dtype="bfloat16")
+    else:
+        raise ValueError(f"widths must be one of {WIDTHS}, got {widths!r}")
     large_cfg = small_cfg.replace(name=small_cfg.name + "-large",
                                   n_layers=4, d_model=small_cfg.d_model * 2,
                                   n_heads=8, d_ff=small_cfg.d_ff * 2)
@@ -152,8 +168,12 @@ def make_remote_factory(kind: str, addresses, *, connect_timeout: float,
 
 
 def main(argv=None):
+    """Parse `argv`, build the ladder, serve; returns (engine, result)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
+    ap.add_argument("--widths", choices=WIDTHS, default="reduced",
+                    help="reduced: 2-layer CPU-scale variant of --arch; "
+                         "published: --arch as published, bf16 params")
     ap.add_argument("--engine", choices=("static", "continuous"),
                     default="continuous")
     ap.add_argument("--requests", type=int, default=32)
@@ -247,8 +267,7 @@ def main(argv=None):
                     default="auto",
                     help="paged backend: route decode through the Pallas "
                          "paged flash-decode kernels (auto = on for TPU, "
-                         "XLA gather fallback on CPU; env "
-                         "REPRO_PAGED_KERNEL overrides auto)")
+                         "XLA gather fallback on CPU)")
     ap.add_argument("--serial-prefill", action="store_true",
                     help="paged backend: disable batched same-offset "
                          "prefill chunk dispatch (debug/parity)")
@@ -384,8 +403,17 @@ def main(argv=None):
             ap.error("--large-backend socket takes exactly one "
                      "--ml-address; use --large-backend pool for several")
 
+    enable_compile_cache()
     key = jax.random.PRNGKey(args.seed)
-    runners = build_ladder(args.arch, args.seed, args.tiers)
+    runners = build_ladder(args.arch, args.seed, args.tiers, args.widths)
+    for i, r in enumerate(runners):
+        leaves = jax.tree.leaves(r.params)
+        print(f"tier {i}: {r.cfg.name} ({args.widths} widths, "
+              f"{r.cfg.n_layers} layers, d_model {r.cfg.d_model}, vocab "
+              f"{r.cfg.vocab_size}): "
+              f"{sum(x.size for x in leaves) / 1e9:.3f} B params, "
+              f"{sum(x.nbytes for x in leaves) / 1e9:.3f} GB "
+              f"{r.cfg.param_dtype}")
     small, large = runners[0], runners[-1]
     small_cfg = small.cfg
 
@@ -413,7 +441,7 @@ def main(argv=None):
               f"{res.deferral_ratio:.3f}, compute_cost={res.compute_cost:.3f}x,"
               f" mean_confidence={res.confidence.mean():.4f}")
         print("first tokens:", res.tokens[:4].tolist())
-        return
+        return engine, res
 
     ml_servers = []
     large_backend = args.large_backend
@@ -562,6 +590,7 @@ def main(argv=None):
     if args.profile_dir:
         print(f"jax.profiler trace in {args.profile_dir}")
     print("first tokens:", res.tokens[:4].tolist())
+    return engine, res
 
 
 if __name__ == "__main__":

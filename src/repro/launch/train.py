@@ -25,6 +25,7 @@ from repro.configs import ModelConfig, get_config, reduced
 from repro.core.gatekeeper import GatekeeperConfig
 from repro.data.pipeline import BatchIterator
 from repro.data.synthetic import make_lm_stream, make_qa
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import transformer as tfm
 from repro.sharding import ParallelContext
@@ -129,7 +130,9 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=20)
     ap.add_argument("--ckpt", default=None)
-    run(ap.parse_args())
+    args = ap.parse_args()
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -29,8 +29,8 @@ softmax, pinned by tests/test_paged_kernel.py):
   * Pallas kernel (default on TPU; kernels/paged_attention.py) — walks
     the page table inside the kernel, one block per kv grid step, no
     dense view in HBM; the single-token cache write is also a kernel.
-  Selection: the `paged_kernel` argument when given, else the
-  REPRO_PAGED_KERNEL env var, else backend default (kernels/ops.py).
+  Selection: the `paged_kernel` argument when given, else the backend
+  default (kernels/ops.py).
 
 Sharding: head dims carry logical axis "heads"/"kv_heads" (→ `model`);
 the output projection contracts the sharded head axis, so XLA inserts the
@@ -316,7 +316,7 @@ def _flash_decode_sharded(q, ck, cv, mask, scale, ctx: ParallelContext):
     Returns None when the cache's seq dim is not sharded (caller falls
     back to the dense path)."""
     from jax.sharding import PartitionSpec as P
-    from repro.sharding import logical_to_spec, shard_map
+    from repro.sharding import logical_to_spec
     mesh = ctx.mesh
     cache_spec = logical_to_spec(("batch", "cache_seq", "kv_heads", None),
                                  ck.shape, mesh, ctx.rules)
@@ -348,9 +348,9 @@ def _flash_decode_sharded(q, ck, cv, mask, scale, ctx: ParallelContext):
         return (out.transpose(0, 3, 1, 2, 4)
                 .reshape(B, Tq, H, hd).astype(ql.dtype))
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(qspec, kvspec, kvspec, mspec),
-                     out_specs=qspec, check_vma=False)(q, ck, cv, mask)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(qspec, kvspec, kvspec, mspec),
+                         out_specs=qspec, check_vma=False)(q, ck, cv, mask)
 
 
 def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
@@ -370,7 +370,7 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     token-identical to the dense path. Requires per-row positions.
     `paged_kernel` picks the Pallas paged flash-decode kernel (walks the
     page table in-kernel, no dense gather) vs the XLA gather fallback;
-    None = REPRO_PAGED_KERNEL env / backend default.
+    None = backend default.
 
     For sliding-window configs the cache is a ring buffer of size `window`;
     the write slot is position % window and relative order is handled by

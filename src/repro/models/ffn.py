@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.common import ParamFactory, swiglu
-from repro.sharding import ParallelContext, shard_map
+from repro.sharding import ParallelContext
 
 
 @dataclasses.dataclass(frozen=True)
@@ -383,7 +383,7 @@ def moe_forward(params: dict, cfg: MoEConfig, x: jnp.ndarray,
             dropped = jax.lax.pmean(dropped, allaxes)
             return y.reshape(Bl, Tl, d), aux, dropped
 
-        y, aux, _dropped = shard_map(
+        y, aux, _dropped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(xspec, wspec["router"], wspec["w_gate"],
                       wspec["w_up"], wspec["w_down"]),
@@ -417,7 +417,7 @@ def moe_forward(params: dict, cfg: MoEConfig, x: jnp.ndarray,
         aux = jax.lax.pmean(aux, tuple(a for a in mesh.axis_names if a != ma))
         return y.reshape(Bl, Tl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body_dec, mesh=mesh,
         in_specs=(xspec, wspec["router"], wspec["w_gate"], wspec["w_up"],
                   wspec["w_down"]),

@@ -431,8 +431,8 @@ def decode_step(params, cfg: ModelConfig, token, position, cache,
     each row attends/writes at its own position). `pages` [B, M] routes
     the per-row cache access through a page table (block-paged backend;
     requires vector positions); `paged_kernel` picks the Pallas paged
-    flash-decode kernels over the XLA gather fallback (None = env /
-    backend default). Returns (logits [B, V], cache)."""
+    flash-decode kernels over the XLA gather fallback (None = backend
+    default). Returns (logits [B, V], cache)."""
     if token.ndim == 1:
         token = token[:, None]
     x = _embed_inputs(params, cfg, token, None, ctx)

@@ -77,8 +77,8 @@ class PagedConfig:
     prefill_chunk  — prefill chunk tokens (None: whole prompt in one
                      chunk).
     paged_kernel   — True: Pallas paged flash-decode kernels; False: XLA
-                     gather fallback; None: REPRO_PAGED_KERNEL / platform
-                     default (TPU on, CPU off).
+                     gather fallback; None: platform default (TPU on,
+                     CPU off).
     batch_prefill  — pack same-offset prefill chunks of all mid-prefill
                      requests into one dispatch.
     prefix_sharing — copy-on-write prompt-prefix sharing through the

@@ -329,8 +329,8 @@ class ContinuousCascadeEngine:
     * ``paged_kernel`` — True routes paged decode through the Pallas
       paged flash-decode kernels (kernels/paged_attention.py: page-table
       walk in-kernel, no dense gather); False forces the XLA gather
-      fallback; None (default) defers to REPRO_PAGED_KERNEL / backend
-      default (kernel on TPU, fallback on CPU).
+      fallback; None (default) takes the backend default (kernel on
+      TPU, fallback on CPU).
     * every decode step slices the page table to the bucketed ACTIVE
       block prefix (`pool.active_prefix_blocks`), so both paths touch
       only `ceil((max_pos + steps_per_sync)/block_size)` blocks per row

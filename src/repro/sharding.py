@@ -19,25 +19,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """Version-portable `jax.make_mesh` with Auto axis types: jax 0.4.x
-    predates `jax.sharding.AxisType` (Auto is its only behaviour)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map: jax >= 0.5 exposes `jax.shard_map`
-    (replication check kwarg `check_vma`); 0.4.x ships it under
-    `jax.experimental.shard_map` with the kwarg named `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """`jax.make_mesh` with Auto axis types: the rule table below leaves
+    propagation to GSPMD."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 # ---------------------------------------------------------------------------
 # Rule table: logical axis -> preferred mesh axes, in priority order.

@@ -96,8 +96,15 @@ _FLASH_DECODE_SCRIPT = textwrap.dedent("""
         params, x, cache)
     np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_ref),
                                atol=1e-5, rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(c_sh["k"]), np.asarray(c_ref["k"]),
-                               atol=1e-6)
+    # entries the step did not write stay bit-identical; the written row
+    # is a fresh fp32 projection (32-term dot) + RoPE, which the
+    # partitioner may fuse/reorder: error <= ~(32+2) * eps_fp32 (1.2e-7)
+    # relative to the summed magnitudes, i.e. ~4e-6 -> rtol 1e-5
+    ck_sh, ck_ref = np.asarray(c_sh["k"]), np.asarray(c_ref["k"])
+    np.testing.assert_array_equal(np.delete(ck_sh, 40, axis=1),
+                                  np.delete(ck_ref, 40, axis=1))
+    np.testing.assert_allclose(ck_sh[:, 40], ck_ref[:, 40],
+                               atol=1e-6, rtol=1e-5)
     print("FLASH_DECODE_OK")
 """)
 

@@ -1,0 +1,107 @@
+"""Compile-only tests of the paged decode kernels for a TPU v5e.
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached, so these run on the CPU and refuse what the chip's
+compiler would refuse (block shapes off the (8, 128) tiling, stores it
+cannot prove aligned, VMEM overuse) — none of which interpret mode sees.
+Shapes are the published widths of the served models: internlm2-1.8b for
+the GQA kernels, deepseek-v2 for the MLA ones.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and a worker that decided at
+import time which tests exist would desynchronise pytest-xdist's workers.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import paged_attention as pa
+
+BLOCK = 16          # serving default block size (serving/config.py)
+SLOTS = 8
+MAX_LEN = 2048
+M = MAX_LEN // BLOCK + 1
+N_BLOCKS = SLOTS * M + 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described-chip compile can be written to the persistent cache but
+    # never read back without the chip; keep it out of the cache
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:   # no TPU compiler in this install
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_gqa_paged_decode_compiles(one_chip, dtype):
+    cfg = get_config("internlm2-1.8b")
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    _compile(functools.partial(pa.paged_flash_decode_gqa, interpret=False),
+             one_chip,
+             ((SLOTS, 1, H, hd), dtype),
+             ((N_BLOCKS, BLOCK, KV, hd), dtype),
+             ((N_BLOCKS, BLOCK, KV, hd), dtype),
+             ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+def _leaf_shapes():
+    """Per-token feature shape of every paged cache leaf the write kernel
+    serves: GQA k/v (internlm2) and MLA ckv/kr (deepseek-v2)."""
+    g = get_config("internlm2-1.8b")
+    mla = get_config("deepseek-v2-236b").mla
+    return {"gqa_kv": (g.n_kv_heads, g.head_dim),
+            "mla_ckv": (mla.kv_lora,),
+            "mla_kr": (mla.rope_dim,)}
+
+
+@pytest.mark.parametrize("leaf", ["gqa_kv", "mla_ckv", "mla_kr"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_paged_write_token_compiles(one_chip, dtype, leaf):
+    feat = _leaf_shapes()[leaf]
+    compiled = _compile(
+        functools.partial(pa.paged_write_token, interpret=False), one_chip,
+        ((N_BLOCKS, BLOCK) + feat, dtype), ((SLOTS, M), jnp.int32),
+        ((SLOTS,), jnp.int32), ((SLOTS,) + feat, dtype))
+    # the kernel writes the leaf (operand 3) in place
+    assert "output_to_operand_aliasing={{}: (3, {})}" in compiled.as_text()
+
+
+def test_mla_paged_decode_compiles(one_chip):
+    cfg = get_config("deepseek-v2-236b")
+    H, r, dr = cfg.n_heads, cfg.mla.kv_lora, cfg.mla.rope_dim
+    dt = jnp.bfloat16
+    _compile(functools.partial(pa.paged_flash_decode_mla,
+                               scale=(cfg.head_dim + dr) ** -0.5,
+                               interpret=False),
+             one_chip,
+             ((SLOTS, 1, H, r), dt), ((SLOTS, 1, H, dr), dt),
+             ((N_BLOCKS, BLOCK, r), dt), ((N_BLOCKS, BLOCK, dr), dt),
+             ((r,), dt), ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
