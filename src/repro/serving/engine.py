@@ -47,7 +47,6 @@ percentiles, early-exit savings, cache footprint) from
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 import time
 import warnings
@@ -107,7 +106,9 @@ class ModelRunner:
 
     `generate` runs the whole greedy loop on device (`lax.fori_loop` over
     decode steps, tokens accumulated into a preallocated buffer) and
-    transfers the token matrix + confidence vector to host ONCE.
+    transfers the token matrix + confidence vector to host ONCE. Its
+    program is named `ml_generate` (`jit_ml_generate` in a profiler
+    trace), `sample`'s `ml_sample`, whatever the shape.
     """
 
     def __init__(self, cfg: ModelConfig, params: Any,
@@ -153,10 +154,11 @@ class ModelRunner:
         key = (prompt_len, max_new)
         fn = self._gen_fns.get(key)
         if fn is None:
-            fn = jax.jit(functools.partial(self._generate_impl,
+            def ml_generate(params, prompts):
+                return self._generate_impl(params, prompts,
                                            prompt_len=prompt_len,
-                                           max_new=max_new))
-            self._gen_fns[key] = fn
+                                           max_new=max_new)
+            fn = self._gen_fns[key] = jax.jit(ml_generate)
         tokens, conf = fn(self.params, jnp.asarray(prompts))
         return np.asarray(tokens), np.asarray(conf)
 
@@ -203,11 +205,12 @@ class ModelRunner:
         key = (prompt_len, max_new, float(temperature), "sample")
         fn = self._gen_fns.get(key)
         if fn is None:
-            fn = jax.jit(functools.partial(self._sample_impl,
-                                           prompt_len=prompt_len,
-                                           max_new=max_new,
-                                           temperature=float(temperature)))
-            self._gen_fns[key] = fn
+            def ml_sample(params, prompts, seed):
+                return self._sample_impl(params, prompts, seed,
+                                         prompt_len=prompt_len,
+                                         max_new=max_new,
+                                         temperature=float(temperature))
+            fn = self._gen_fns[key] = jax.jit(ml_sample)
         tokens = fn(self.params, jnp.asarray(prompts),
                     jnp.uint32(seed & 0xFFFFFFFF))
         return np.asarray(tokens)
@@ -706,8 +709,10 @@ class ContinuousCascadeEngine:
         and saved-step accounting agree.
 
         `obs` selects the observability surface (`repro.serving.obs`):
-        ``None`` (default) keeps only the always-on bounded metrics +
-        phase attribution; an `ObsConfig` makes the engine build the
+        ``None`` (default) keeps only the always-on pieces — bounded
+        metrics, the compile counter (``stats["compiles"]``), and the
+        ``engine.*`` profiler spans that feed phase attribution; an
+        `ObsConfig` makes the engine build the
         runtime, run, and export/finish it (the one-shot CLI/bench
         path); a prebuilt `Observability` is caller-owned — the engine
         feeds it but never finishes it (e.g. serve.py keeping the
@@ -810,6 +815,9 @@ class ContinuousCascadeEngine:
         # the worker backend gets its own try/finally inside (a leaked
         # worker thread spins its poll loop for the life of the process)
         tel = ServingTelemetry(audit_path, obs=obs_rt)
+        # engine.<name> spans: profiler annotations, phase attribution,
+        # and the Chrome engine track when tracing is on
+        span = obs_rt.spans(lambda: tel.now, tel.phase_add)
         spec = self.spec
         n_edges = len(spec.edges)
         last_tier = spec.n_tiers - 1
@@ -827,6 +835,7 @@ class ContinuousCascadeEngine:
             return recal.tau(e) if recal is not None else spec.edges[e].tau
 
         backends: List[Any] = []
+        compiles = obs_rt.count_compiles()
         try:
             S = self.n_slots
             state = {
@@ -967,10 +976,18 @@ class ContinuousCascadeEngine:
                 in-flight deferred slots, release them, and deactivate on
                 device. Slots still prefilling are skipped — their device
                 state is stale until the final chunk seeds it."""
+                with span("sync"):
+                    n_gen = np.asarray(state["n_gen"])
+                    conf_sum = np.asarray(state["conf_sum"])
+                with span("retire"):
+                    retire(n_gen, conf_sum)
+
+            def retire(n_gen, conf_sum):
+                """Host side of `sync_retire`: gate each decoding slot on
+                its pulled counts, release the ones leaving, and hand
+                deferrals to the next tier's backend."""
                 nonlocal state
                 mid_prefill = {s for _, s, _ in prefilling}
-                n_gen = np.asarray(state["n_gen"])
-                conf_sum = np.asarray(state["conf_sum"])
                 toks = None
                 retired: List[int] = []
                 now = tel.now
@@ -993,7 +1010,8 @@ class ContinuousCascadeEngine:
                     if not (finished or evict):
                         continue
                     if toks is None:
-                        toks = np.asarray(state["tokens"])
+                        with span("sync"):
+                            toks = np.asarray(state["tokens"])
                     req.n_small_steps = n
                     req.small_tokens = toks[slot, :n].copy()
                     if evict:
@@ -1080,6 +1098,9 @@ class ContinuousCascadeEngine:
                     r.t_prefill_done = now
                 if tr is not None:
                     seed_conf_trace(admitted)
+                tel.event("admit", rids=[r.rid for _, r in admitted],
+                          slots=[s for s, _ in admitted])
+                sync_retire()       # min_tokens=1 / max_new=1 edges
 
             def run_prefill_chunk():
                 """Paged backend: run one chunk of the oldest mid-prefill
@@ -1351,215 +1372,130 @@ class ContinuousCascadeEngine:
                         if policy is None or not relieve_pressure(needy):
                             raise
 
-            try:
-                while len(queue) or sched.n_active:
-                    t_it = tel.now
-                    if profiler.enabled:
-                        profiler.tick()
-                    if overload_on:
-                        # admission overload control BEFORE admitting:
-                        # release arrivals into the ready queue, shed
-                        # deadline-expired entries, then bound the queue
-                        # (newest-first overflow). Admitted requests are
-                        # never expired — deadlines gate queueing only.
-                        queue.release(t_it)
-                        for r in queue.expire(t_it):
-                            finalize_shed(r, EXPIRED, "deadline")
-                        for r in queue.shed_overflow():
-                            finalize_shed(r, REJECTED, "queue_full")
-                    if paged:
-                        # admit one at a time: each admission reserves its
-                        # blocks immediately, so the capacity check for the
-                        # next FIFO head sees the updated reservation
-                        admitted = []
-                        relief0 = n_relief
-                        while True:
-                            got = sched.admit_ready(
-                                queue, tel.now, limit=1,
-                                can_admit=lambda r: pool.can_reserve(
-                                    r.prompt_len + r.max_new - 1))
-                            if not got:
-                                break
-                            slot, req = got[0]
-                            pool.reserve(slot,
-                                         req.prompt_len + req.max_new - 1)
-                            rs = req.resume
-                            end = prefill_end(req)
-                            start = 0
-                            if self.prefix_sharing or rs is not None:
-                                # map already-resident (or cached) prefix
-                                # blocks by refcount; prefill resumes at
-                                # the first unshared token. A fully-shared
-                                # prompt still recomputes its final token
-                                # for the seed logits — run_prefill_chunk
-                                # CoW-clones that block before the write.
-                                # Preempted requests walk the same chain:
-                                # their prompt blocks were registered at
-                                # eviction, so the resume recompute is
-                                # mostly (often entirely) a registry walk.
-                                shared = pool.share_prefix(slot, req.prompt)
-                                if rs is None:
-                                    start = min(shared, req.prompt_len - 1)
-                                    req.shared_prefix_tokens = start
-                                    n_shared_tokens += start
-                                else:
-                                    start = min(shared, end)
-                            L = req.prompt_len if rs is None \
-                                else rs["ctx_len"]
-                            with_relief(lambda s=slot, n=L:
-                                        pool.ensure_mapped(s, n),
-                                        needy=(slot,))
-                            if rs is not None and start >= end:
-                                # every surviving prompt block came
-                                # straight from the registry: restore the
-                                # decode-written tail and resume now
-                                apply_resume(slot, req)
-                            else:
-                                prefilling.append([req, slot, start])
-                            admitted.append((slot, req))
-                            if n_relief != relief0:
-                                # pressure fired while admitting: stop —
-                                # admitting more this iteration would
-                                # thrash straight back into it
-                                break
-                        if admitted:
-                            tel.event("admit",
-                                      rids=[r.rid for _, r in admitted],
-                                      slots=[s for s, _ in admitted],
-                                      shared=[r.shared_prefix_tokens
-                                              for _, r in admitted])
-                        t_sched = tel.now
-                        did_prefill = bool(prefilling)
-                        if did_prefill:
-                            run_prefill_chunk()
-                    else:
-                        admitted = sched.admit_ready(queue, tel.now)
-                        t_sched = tel.now
-                        did_prefill = bool(admitted)
-                        if admitted:
-                            admit_slot_groups(admitted)
-                            tel.event("admit",
-                                      rids=[r.rid for _, r in admitted],
-                                      slots=[s for s, _ in admitted])
-                            sync_retire()   # min_tokens=1 / max_new=1 edges
-                    t_prefill = tel.now
-                    tel.phase_add("schedule", t_sched - t_it)
-                    if did_prefill:
-                        tel.phase_add("prefill", t_prefill - t_sched)
-                    peak_active = max(peak_active, sched.n_active)
-                    decoding = decoding_slots()
-                    if paged and decoding:
-                        # mapping the decode cover can hit BlockPressure
-                        # under oversubscription: relieve (the victim may
-                        # itself be a decoding slot) and redo the prep
-                        # against the survivors — ensure_mapped /
-                        # ensure_writable are idempotent, so the retry
-                        # resumes exactly where pressure struck
-                        while True:
-                            decoding = decoding_slots()
-                            if not decoding:
-                                break
-                            try:
-                                pos_host = np.asarray(state["pos"])
-                                need = 1
-                                covers = {}
-                                for slot in decoding:
-                                    req = sched.running[slot]
-                                    total = (req.prompt_len
-                                             + req.max_new - 1)
-                                    cover = min(int(pos_host[slot])
-                                                + self.steps_per_sync,
-                                                total)
-                                    pool.ensure_mapped(slot, cover)
-                                    # decode writes [pos, cover):
-                                    # CoW-clone any still-shared block in
-                                    # that span so the in-flight write
-                                    # scatter stays row-disjoint
-                                    pool.ensure_writable(
-                                        slot, int(pos_host[slot]), cover)
-                                    covers[slot] = cover
-                                    need = max(need, cover)
-                                pool.check_write_disjoint(
-                                    (s, int(pos_host[s]), c)
-                                    for s, c in covers.items())
-                                break
-                            except BlockPressure:
-                                if (policy is None
-                                        or not relieve_pressure()):
-                                    raise
-                    t_dec = tel.now
-                    if decoding:
-                        if paged:
-                            # active-prefix tightening: hand the jitted step
-                            # only the bucketed block prefix the masks can
-                            # reach — the gather/kernel walk shrinks with it
-                            mb = pool.active_prefix_blocks(need)
-                            pool.cache, state = step_fn(
-                                self.small.params, pool.cache, state,
-                                pool.tables_device(mb))
+            def admit() -> List[Tuple[int, Request]]:
+                """Release arrivals and admit the FIFO head into free
+                slots. Admission overload control runs BEFORE admitting:
+                shed deadline-expired entries, then bound the queue
+                (newest-first overflow); admitted requests are never
+                expired — deadlines gate queueing only. Paged: one at a
+                time, each admission reserving its blocks immediately so
+                the capacity check for the next head sees the updated
+                reservation; admitted requests queue for chunked
+                prefill."""
+                nonlocal n_shared_tokens
+                if overload_on:
+                    now = tel.now
+                    queue.release(now)
+                    for r in queue.expire(now):
+                        finalize_shed(r, EXPIRED, "deadline")
+                    for r in queue.shed_overflow():
+                        finalize_shed(r, REJECTED, "queue_full")
+                if not paged:
+                    return sched.admit_ready(queue, tel.now)
+                admitted = []
+                relief0 = n_relief
+                while True:
+                    got = sched.admit_ready(
+                        queue, tel.now, limit=1,
+                        can_admit=lambda r: pool.can_reserve(
+                            r.prompt_len + r.max_new - 1))
+                    if not got:
+                        break
+                    slot, req = got[0]
+                    pool.reserve(slot, req.prompt_len + req.max_new - 1)
+                    rs = req.resume
+                    end = prefill_end(req)
+                    start = 0
+                    if self.prefix_sharing or rs is not None:
+                        # map already-resident (or cached) prefix blocks
+                        # by refcount; prefill resumes at the first
+                        # unshared token. A fully-shared prompt still
+                        # recomputes its final token for the seed logits
+                        # — run_prefill_chunk CoW-clones that block
+                        # before the write. Preempted requests walk the
+                        # same chain: their prompt blocks were registered
+                        # at eviction, so the resume recompute is mostly
+                        # (often entirely) a registry walk.
+                        shared = pool.share_prefix(slot, req.prompt)
+                        if rs is None:
+                            start = min(shared, req.prompt_len - 1)
+                            req.shared_prefix_tokens = start
+                            n_shared_tokens += start
                         else:
-                            pool.cache, state = step_fn(self.small.params,
-                                                        pool.cache, state)
-                        if dev_timer.enabled:
-                            t_dev = tel.now
-                            jax.block_until_ready(state)
-                            dec_dev = tel.now - t_dev
-                        else:
-                            dec_dev = 0.0
-                        n_steps += self.steps_per_sync
-                        tel.event("step", slots=decoding,
-                                  n=self.steps_per_sync,
-                                  ml_pending=ml.n_pending)
-                        if tr is not None:
-                            record_conf_trace(decoding)
-                        sync_retire()
-                        t_dec_end = tel.now
-                        tel.phase_add("decode", t_dec_end - t_dec, dec_dev)
-                        tel.m_decode_step.observe(
-                            (t_dec_end - t_dec) / self.steps_per_sync)
-                    elif not sched.n_active and len(queue):
-                        nxt = queue.next_arrival
-                        if nxt is not None:
-                            time.sleep(min(max(nxt - tel.now, 0.0), 1e-3)
-                                       + 1e-5)
-                        t_dec_end = tel.now
+                            start = min(shared, end)
+                    L = req.prompt_len if rs is None else rs["ctx_len"]
+                    with_relief(lambda s=slot, n=L: pool.ensure_mapped(s, n),
+                                needy=(slot,))
+                    if rs is not None and start >= end:
+                        # every surviving prompt block came straight from
+                        # the registry: restore the decode-written tail
+                        # and resume now
+                        apply_resume(slot, req)
                     else:
-                        t_dec_end = t_dec
-                    t_poll = tel.now
-                    ml_depths.append(ml.n_pending)
-                    poll_large()
-                    t_end = tel.now
-                    tel.phase_add("ml_wait", t_end - t_poll)
-                    if tr is not None:
-                        # engine-iteration span + nested phase spans on
-                        # the engine track (shared timestamps guarantee
-                        # proper nesting in the exported trace)
-                        if admitted:
-                            tr.complete("schedule", "engine", t_it,
-                                        t_sched - t_it, 0)
-                        if did_prefill:
-                            tr.complete("prefill", "engine", t_sched,
-                                        t_prefill - t_sched, 0)
-                        if decoding:
-                            tr.complete("decode", "engine", t_dec,
-                                        t_dec_end - t_dec, 0)
-                        tr.complete("ml_poll", "engine", t_poll,
-                                    t_end - t_poll, 0)
-                        tr.complete("iteration", "engine", t_it,
-                                    t_end - t_it, 0,
-                                    args={"n_active": sched.n_active,
-                                          "ml_pending": ml.n_pending})
+                        prefilling.append([req, slot, start])
+                    admitted.append((slot, req))
+                    if n_relief != relief0:
+                        # pressure fired while admitting: stop — admitting
+                        # more this iteration would thrash straight back
+                        # into it
+                        break
+                if admitted:
+                    tel.event("admit", rids=[r.rid for _, r in admitted],
+                              slots=[s for s, _ in admitted],
+                              shared=[r.shared_prefix_tokens
+                                      for _, r in admitted])
+                return admitted
 
-                # all M_S work is done: drain the ladder edge by edge.
-                # Backend e is flushed only once every backend upstream of
-                # it is empty — deferred traffic from edge e-1 is edge e's
-                # arrival traffic, so flushing earlier would cut partial
-                # batches that a sequential reference run would have
-                # batched together. Remote backends advertise
-                # drain_stall_timeout: when a replica dies mid-drain and
-                # nothing can make progress, abort with the pending count
-                # instead of spinning forever
-                t_drain = tel.now
+            def decode_prep():
+                """Paged: map every decoding slot's write span [pos,
+                pos + steps_per_sync), CoW-clone any still-shared block
+                in it so the in-flight write scatter stays row-disjoint,
+                and slice the device page table to the bucketed active
+                block prefix the masks can reach (the gather/kernel walk
+                shrinks with it). Mapping can hit BlockPressure under
+                oversubscription: relieve (the victim may itself be a
+                decoding slot) and redo the prep against the survivors —
+                ensure_mapped / ensure_writable are idempotent, so the
+                retry resumes exactly where pressure struck. Returns the
+                slots left to decode and their table."""
+                while True:
+                    decoding = decoding_slots()
+                    if not decoding:
+                        return decoding, None
+                    try:
+                        pos_host = np.asarray(state["pos"])
+                        need = 1
+                        covers = {}
+                        for slot in decoding:
+                            req = sched.running[slot]
+                            total = req.prompt_len + req.max_new - 1
+                            cover = min(int(pos_host[slot])
+                                        + self.steps_per_sync, total)
+                            pool.ensure_mapped(slot, cover)
+                            pool.ensure_writable(slot, int(pos_host[slot]),
+                                                 cover)
+                            covers[slot] = cover
+                            need = max(need, cover)
+                        pool.check_write_disjoint(
+                            (s, int(pos_host[s]), c)
+                            for s, c in covers.items())
+                        break
+                    except BlockPressure:
+                        if policy is None or not relieve_pressure():
+                            raise
+                return decoding, pool.tables_device(
+                    pool.active_prefix_blocks(need))
+
+            def drain_ladder():
+                """All M_S work is done: drain the ladder edge by edge.
+                Backend e is flushed only once every backend upstream of
+                it is empty — deferred traffic from edge e-1 is edge e's
+                arrival traffic, so flushing earlier would cut partial
+                batches that a sequential reference run would have
+                batched together. Remote backends advertise
+                drain_stall_timeout: when a replica dies mid-drain and
+                nothing can make progress, abort with the pending count
+                instead of spinning forever."""
                 stalls = [getattr(b, "drain_stall_timeout", None)
                           for b in backends]
                 stall_s = min((s for s in stalls if s is not None),
@@ -1574,7 +1510,7 @@ class ContinuousCascadeEngine:
                     poll_large()
                     pending = total_pending()
                     if not pending:
-                        break
+                        return
                     if pending != last_pending:
                         last_pending = pending
                         t_progress = time.perf_counter()
@@ -1587,11 +1523,67 @@ class ContinuousCascadeEngine:
                             f"still pending ({', '.join(names)}) with no "
                             f"progress for {stall_s}s")
                     time.sleep(2e-3)
+
+            try:
+                while len(queue) or sched.n_active:
+                    if profiler.enabled:
+                        profiler.tick()
+                    with span("iteration", n_active=sched.n_active,
+                              ml_pending=ml.n_pending):
+                        with span("schedule", phase="schedule"):
+                            admitted = admit()
+                        if prefilling:
+                            with span("prefill", phase="prefill"):
+                                run_prefill_chunk()
+                        elif admitted and not paged:
+                            with span("prefill", phase="prefill"):
+                                admit_slot_groups(admitted)
+                        peak_active = max(peak_active, sched.n_active)
+                        decoding = decoding_slots()
+                        if decoding:
+                            # the decode phase holds its prep: the pos
+                            # pull, block mapping and page table
+                            with span("decode", phase="decode") as dec:
+                                tables = ()
+                                if paged:
+                                    with span("decode_prep"):
+                                        decoding, tbl = decode_prep()
+                                    tables = (tbl,)
+                                if decoding:
+                                    step = n_steps // self.steps_per_sync
+                                    with span("decode_dispatch", step=step,
+                                              rows=len(decoding)):
+                                        pool.cache, state = step_fn(
+                                            self.small.params, pool.cache,
+                                            state, *tables)
+                                    if dev_timer.enabled:
+                                        t_dev = tel.now
+                                        jax.block_until_ready(state)
+                                        tel.phase_add("decode", 0.0,
+                                                      tel.now - t_dev)
+                                    n_steps += self.steps_per_sync
+                                    tel.event("step", step=step,
+                                              slots=decoding,
+                                              n=self.steps_per_sync,
+                                              ml_pending=ml.n_pending)
+                                    if tr is not None:
+                                        record_conf_trace(decoding)
+                                    sync_retire()
+                            if decoding:
+                                tel.m_decode_step.observe(
+                                    dec.seconds / self.steps_per_sync)
+                        elif not sched.n_active and len(queue):
+                            nxt = queue.next_arrival
+                            if nxt is not None:
+                                with span("wait"):
+                                    time.sleep(min(max(nxt - tel.now, 0.0),
+                                                   1e-3) + 1e-5)
+                        with span("ml_poll", phase="ml_wait"):
+                            ml_depths.append(ml.n_pending)
+                            poll_large()
+                with span("drain", phase="drain"):
+                    drain_ladder()
                 makespan = tel.now
-                tel.phase_add("drain", makespan - t_drain)
-                if tr is not None:
-                    tr.complete("drain", "engine", t_drain,
-                                makespan - t_drain, 0)
             finally:
                 for be in backends:
                     be.close()
@@ -1599,6 +1591,7 @@ class ContinuousCascadeEngine:
             # a still-open jax.profiler window must be stopped even when
             # the run raises (leaking one poisons later profiled runs)
             profiler.close()
+            compiles.close()
             tel.close()
 
         reqs = sorted(requests, key=lambda r: r.rid)
@@ -1618,6 +1611,10 @@ class ContinuousCascadeEngine:
             float(np.mean([b["n_real"] / max(b["pad_to"], 1)
                            for b in ml.batch_log]))
             if ml.batch_log else float("nan"))
+        stats["ml_batch_s"] = [{"batch_id": b["batch_id"],
+                                "seconds": b["seconds"]}
+                               for b in ml.batch_log if "seconds" in b]
+        stats["compiles"] = compiles.counts
         stats["ml_queue_depth_peak"] = int(max(ml_depths, default=0))
         stats["ml_queue_depth_mean"] = (float(np.mean(ml_depths))
                                         if ml_depths else 0.0)

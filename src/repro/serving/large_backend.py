@@ -54,6 +54,7 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, Tuple
 
 import numpy as np
 
+from repro.serving.obs.trace import Spans
 from repro.serving.request import Request
 
 # flush reasons recorded per batch (telemetry / no-starvation tests)
@@ -63,6 +64,9 @@ FLUSH_DRAIN = "drain"        # end-of-run drain
 
 # batch occupancy is a fraction in (0, 1]: fixed fine-grained buckets
 OCCUPANCY_BUCKETS = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
+# one M_L batch: milliseconds on a toy model, seconds at published widths
+BATCH_SECONDS_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 1.5, 2.0, 3.0,
+                         5.0, 10.0)
 
 
 class _BackendMetrics:
@@ -82,16 +86,23 @@ class _BackendMetrics:
             "serving_ml_batch_occupancy",
             "real rows / dispatched rows per M_L batch",
             buckets=OCCUPANCY_BUCKETS)
+        self._seconds = registry.histogram(
+            "serving_ml_batch_seconds",
+            "wall seconds per M_L batch, dispatch to tokens on the host",
+            buckets=BATCH_SECONDS_BUCKETS)
         if backend is not None:
             registry.gauge("serving_ml_queue_depth",
                            "requests submitted to the M_L backend and "
                            "not yet returned",
                            fn=lambda: backend.n_pending)
 
-    def record_batch(self, n_real: int, pad_to: int, reason: str) -> None:
+    def record_batch(self, entry: Dict[str, Any]) -> None:
+        """One batch's `batch_log` entry (see `_generate_batch`)."""
         if self.enabled:
-            self._batches.labels(reason=reason).inc()
-            self._occupancy.observe(n_real / max(pad_to, 1))
+            self._batches.labels(reason=entry["reason"]).inc()
+            self._occupancy.observe(entry["n_real"]
+                                    / max(entry["pad_to"], 1))
+            self._seconds.observe(entry["seconds"])
 
 
 @dataclasses.dataclass
@@ -212,22 +223,29 @@ class BatchPolicy:
 
 
 def _generate_batch(generate: Callable, group: List[_Pending], pad_to: int,
-                    max_new: int) -> Tuple[np.ndarray, np.ndarray]:
+                    max_new: int, span: Spans, batch_id: int, reason: str
+                    ) -> Tuple[np.ndarray, np.ndarray, Dict[str, Any]]:
     """Run one rid-sorted, uniform-length group through M_L, padded to
     `pad_to` rows by duplicating the first row (the compiled shape is
-    then reused across partial flushes). Returns
-    ([len(group), max_new] tokens, [len(group)] mean confidences)."""
-    prompts = np.stack([p.prompt for p in group])
+    then reused across partial flushes), inside an ``ml.batch`` span.
+    Returns ([len(group), max_new] tokens, [len(group)] mean
+    confidences, the batch's `batch_log` entry: batch_id, n_real,
+    pad_to, reason, prompt_len and `seconds`, the span's wall time from
+    dispatch to tokens on the host)."""
     b = len(group)
-    if pad_to > b:
-        prompts = np.concatenate(
-            [prompts, np.repeat(prompts[:1], pad_to - b, axis=0)])
-    tokens, conf = generate(prompts, int(prompts.shape[1]), max_new)
+    info = {"batch_id": batch_id, "n_real": b, "pad_to": pad_to,
+            "reason": reason, "prompt_len": int(group[0].prompt.shape[0])}
+    with span("batch", **info) as sp:
+        prompts = np.stack([p.prompt for p in group])
+        if pad_to > b:
+            prompts = np.concatenate(
+                [prompts, np.repeat(prompts[:1], pad_to - b, axis=0)])
+        tokens, conf = generate(prompts, int(prompts.shape[1]), max_new)
     # runners may report no confidence (conf=None) — nan rows then, the
     # LargeResult default, so only ladder-gated tiers require the signal
     conf = (np.full(b, math.nan) if conf is None
             else np.asarray(conf, np.float64))
-    return tokens[:b], conf[:b]
+    return tokens[:b], conf[:b], {**info, "seconds": sp.seconds}
 
 
 class LargeBackend(Protocol):
@@ -271,6 +289,7 @@ class SyncLocalBackend:
         self._n_batches = 0
         self.batch_log: List[Dict[str, Any]] = []
         self._metrics = _BackendMetrics(registry, self)
+        self._span = Spans("ml")
 
     def submit(self, requests: List[Request]) -> int:
         for r in requests:
@@ -283,15 +302,13 @@ class SyncLocalBackend:
     def _run_ready(self, drain: bool = False) -> None:
         for group, pad_to, reason in self._policy.take(
                 time.perf_counter(), drain=drain):
-            tokens, conf = _generate_batch(self._generate, group, pad_to,
-                                           self.max_new)
             bid = self._n_batches
             self._n_batches += 1
-            self.batch_log.append({
-                "batch_id": bid, "n_real": len(group), "pad_to": pad_to,
-                "reason": reason,
-                "prompt_len": int(group[0].prompt.shape[0])})
-            self._metrics.record_batch(len(group), pad_to, reason)
+            tokens, conf, entry = _generate_batch(
+                self._generate, group, pad_to, self.max_new, self._span,
+                bid, reason)
+            self.batch_log.append(entry)
+            self._metrics.record_batch(entry)
             for i, p in enumerate(group):
                 self._results.append(LargeResult(
                     rid=p.rid, tokens=tokens[i].copy(), batch_id=bid,
@@ -341,6 +358,7 @@ class _WorkerBackend:
         self.max_new = max_new
         self._poll_interval = poll_interval
         self._metrics = _BackendMetrics(registry, self)
+        self._span = Spans("ml")
         self._policy = BatchPolicy(large_batch, max_wait)
         self._inq: "queue.Queue" = queue.Queue()
         self._outq: "queue.Queue" = queue.Queue()
@@ -416,17 +434,16 @@ class _WorkerBackend:
             drain = self._drain_flag.is_set() and self._inq.empty()
             for group, pad_to, reason in self._policy.take(
                     time.perf_counter(), drain=drain):
-                tokens, conf = _generate_batch(self._generate, group, pad_to,
-                                               self.max_new)
-                self._sleep_latency()
                 with self._lock:
                     bid = self._n_batches
                     self._n_batches += 1
-                    self.batch_log.append({
-                        "batch_id": bid, "n_real": len(group),
-                        "pad_to": pad_to, "reason": reason,
-                        "prompt_len": int(group[0].prompt.shape[0])})
-                self._metrics.record_batch(len(group), pad_to, reason)
+                tokens, conf, entry = _generate_batch(
+                    self._generate, group, pad_to, self.max_new, self._span,
+                    bid, reason)
+                self._sleep_latency()
+                with self._lock:
+                    self.batch_log.append(entry)
+                self._metrics.record_batch(entry)
                 for i, p in enumerate(group):
                     self._outq.put(self._encode_result(LargeResult(
                         rid=p.rid, tokens=tokens[i].copy(), batch_id=bid,

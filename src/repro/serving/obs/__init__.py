@@ -3,7 +3,9 @@ export, and device-time attribution.
 
 Three pillars (see docs/observability.md):
 
-``trace``        Per-request lifecycle spans (queued -> prefill ->
+``trace``        Named host spans on the profiler's clock (``engine.*``,
+                 ``ml.batch``: `jax.profiler.TraceAnnotation`, always
+                 on), per-request lifecycle spans (queued -> prefill ->
                  decode -> ml_wait -> done, with the per-token eq.-8
                  confidence record on decode spans) and per-engine-
                  iteration phase spans, exported as Chrome trace-event
@@ -13,8 +15,9 @@ Three pillars (see docs/observability.md):
                  file dump (``--metrics-out``) or live ``/metrics``
                  endpoint (``httpd.MetricsServer``, ``--metrics-port``).
 ``device_time``  Opt-in host/device wall-time split per dispatch
-                 (``--device-timing``) and a bounded ``jax.profiler``
-                 capture window (``--profile-dir``).
+                 (``--device-timing``), a bounded ``jax.profiler``
+                 capture window (``--profile-dir``), and a per-run
+                 compile counter by function.
 
 ``config.ObsConfig`` / ``config.Observability`` tie them together;
 ``ContinuousCascadeEngine.run(..., obs=...)`` accepts either. Everything
@@ -23,17 +26,18 @@ a few percent tokens/s of an uninstrumented run (gated in CI).
 """
 from repro.serving.obs.config import (Observability, ObsConfig,
                                       add_obs_args, obs_config_from_args)
-from repro.serving.obs.device_time import DeviceTimer, ProfilerWindow
+from repro.serving.obs.device_time import (CompileCounter, DeviceTimer,
+                                          ProfilerWindow)
 from repro.serving.obs.httpd import MetricsServer
 from repro.serving.obs.metrics import (DEFAULT_BUCKETS, MetricFamily,
                                        MetricsRegistry)
-from repro.serving.obs.trace import (PID_ENGINE, PID_REQUESTS, Tracer,
-                                     emit_request_spans,
+from repro.serving.obs.trace import (PID_ENGINE, PID_REQUESTS, Spans,
+                                     Tracer, emit_request_spans,
                                      validate_chrome_trace)
 
 __all__ = [
-    "DEFAULT_BUCKETS", "DeviceTimer", "MetricFamily", "MetricsRegistry",
+    "DEFAULT_BUCKETS", "CompileCounter", "DeviceTimer", "MetricFamily", "MetricsRegistry",
     "MetricsServer", "ObsConfig", "Observability", "PID_ENGINE",
-    "PID_REQUESTS", "ProfilerWindow", "Tracer", "add_obs_args",
+    "PID_REQUESTS", "ProfilerWindow", "Spans", "Tracer", "add_obs_args",
     "emit_request_spans", "obs_config_from_args", "validate_chrome_trace",
 ]

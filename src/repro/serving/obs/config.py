@@ -3,7 +3,9 @@
 ``ObsConfig`` is the declarative knob set (CLI flags map 1:1 onto it);
 ``Observability`` owns the live objects — one `MetricsRegistry` (always,
 bounded memory), plus the opt-in `Tracer`, `DeviceTimer`,
-`ProfilerWindow`, and `MetricsServer`.
+`ProfilerWindow`, and `MetricsServer` — and builds the always-on pieces
+of a run: its span factory (`Spans`: profiler annotations, phase
+attribution, the Chrome track when tracing) and its `CompileCounter`.
 
 Everything beyond the registry is **off by default**: with a default
 config the engine's instrumented paths see ``tracer is None``, a
@@ -25,9 +27,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from repro.serving.obs.device_time import DeviceTimer, ProfilerWindow
+from repro.serving.obs.device_time import (CompileCounter, DeviceTimer,
+                                          ProfilerWindow)
 from repro.serving.obs.metrics import MetricsRegistry
-from repro.serving.obs.trace import Tracer
+from repro.serving.obs.trace import Spans, Tracer
 
 
 @dataclasses.dataclass
@@ -106,6 +109,19 @@ class Observability:
         self.profiler = ProfilerWindow(self.cfg.profile_dir,
                                        self.cfg.profile_iters)
         self.server = None
+
+    def spans(self, clock, on_phase=None) -> Spans:
+        """The engine's span factory (``engine.<name>``): profiler
+        annotations always, phase attribution through `on_phase`, and
+        the Chrome engine track when tracing is on. `clock` is the
+        run-relative clock the tracer's spans use."""
+        return Spans("engine", clock, self.tracer, on_phase)
+
+    def count_compiles(self) -> CompileCounter:
+        """Start counting backend compiles by function into the
+        registry's ``serving_compiles_total{fn}``; the caller closes the
+        counter when its run ends."""
+        return CompileCounter(self.registry)
 
     def start_server(self):
         """Bind + start the /metrics endpoint when configured. Returns

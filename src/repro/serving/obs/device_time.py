@@ -20,13 +20,21 @@ Two opt-in tools recover the split:
     a run into ``profile_dir``. A bounded window rather than
     whole-run capture: profiler traces grow quickly and one window is
     what kernel-level analysis needs.
+
+``CompileCounter``
+    Counts backend compiles by function while a run is open, through
+    JAX's monitoring events, so an operator sees which program
+    recompiled (``serving_compiles_total{fn}`` on ``/metrics``).
 """
 from __future__ import annotations
 
+import threading
 import time
-from typing import Any, Optional
+from typing import Any, Dict, Optional
 
 import jax
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class DeviceTimer:
@@ -86,3 +94,45 @@ class ProfilerWindow:
         if self._running:
             jax.profiler.stop_trace()
             self._running = False
+
+
+class CompileCounter:
+    """Counts backend compiles (persistent-cache loads included) by
+    function name from construction to ``close()``: a `jax.monitoring`
+    duration listener on COMPILE_EVENT, which names the function
+    (``jit(step_fn)`` counts as ``step_fn``). A compile on any thread of
+    the process counts — the M_L worker compiles its own programs. With a
+    registry the counts also go to ``serving_compiles_total{fn}``."""
+
+    def __init__(self, registry=None):
+        self._lock = threading.Lock()
+        self._counts: Dict[str, int] = {}   # guarded_by: self._lock
+        self._metric = (registry.counter(
+            "serving_compiles_total",
+            "backend compiles during engine runs, by function", ("fn",))
+            if registry is not None else None)
+        self._open = True
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, secs: float, fun_name: str = "?",
+                  **_) -> None:
+        if event != COMPILE_EVENT:
+            return
+        fn = (fun_name[4:-1] if fun_name.startswith("jit(")
+              and fun_name.endswith(")") else fun_name)
+        with self._lock:
+            self._counts[fn] = self._counts.get(fn, 0) + 1
+        if self._metric is not None:
+            self._metric.labels(fn=fn).inc()
+
+    @property
+    def counts(self) -> Dict[str, int]:
+        """{function name: compiles} so far (a copy)."""
+        with self._lock:
+            return dict(self._counts)
+
+    def close(self) -> None:
+        """Stop counting (unregisters the listener); idempotent."""
+        if self._open:
+            jax.monitoring.unregister_event_duration_listener(self._on_event)
+            self._open = False

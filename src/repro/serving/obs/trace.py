@@ -22,15 +22,83 @@ https://ui.perfetto.dev and ``chrome://tracing`` load directly.
 test (and anything else consuming these traces) uses: required keys per
 event, non-negative microsecond timestamps, and proper span nesting per
 (pid, tid) track.
+
+:class:`Spans` names the host's work on the profiler's own clock: each
+span is a ``jax.profiler.TraceAnnotation`` (``engine.<name>`` on the
+engine thread, ``ml.<name>`` on an M_L worker), so a profiler trace —
+the benchmark's traced window or ``--profile-dir`` — shows what the host
+was doing in every gap between device programs. The same span feeds the
+engine's phase attribution and, when a `Tracer` is on, the Chrome
+engine track.
 """
 from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, List, Optional
+import time
+from typing import Any, Callable, Dict, List, Optional
+
+import jax
 
 PID_ENGINE = 1
 PID_REQUESTS = 2
+
+
+class Spans:
+    """Factory of named host spans: ``with spans(name, phase, **args)``.
+
+    Every span opens ``jax.profiler.TraceAnnotation(f"{prefix}.{name}",
+    **args)``, which records nothing unless a profiler session is
+    running (a span then costs a microsecond or two). On exit it leaves
+    its wall seconds on the span object (``.seconds``), adds them to
+    `on_phase` under `phase` when one is given
+    (`ServingTelemetry.phase_add`), and writes the span under its
+    unprefixed name to the engine track (PID_ENGINE, tid 0) of `tracer`
+    when one is on. `clock` gives seconds; the Chrome
+    track takes its timestamps from it, so it must be the clock the
+    tracer's other spans use (`ServingTelemetry.now`)."""
+
+    def __init__(self, prefix: str,
+                 clock: Callable[[], float] = time.perf_counter,
+                 tracer: Optional[Tracer] = None,
+                 on_phase: Optional[Callable[[str, float], None]] = None):
+        self.prefix = prefix
+        self.clock = clock
+        self.tracer = tracer
+        self.on_phase = on_phase
+
+    def __call__(self, name: str, phase: Optional[str] = None,
+                 **args: Any) -> _Span:
+        return _Span(self, name, phase, args)
+
+
+class _Span:
+    __slots__ = ("_spans", "_name", "_phase", "_args", "_ann", "_t0",
+                 "seconds")
+
+    def __init__(self, spans: Spans, name: str, phase: Optional[str],
+                 args: Dict[str, Any]):
+        self._spans, self._name, self._phase, self._args = (spans, name,
+                                                            phase, args)
+        self.seconds = 0.0
+
+    def __enter__(self) -> _Span:
+        sp = self._spans
+        self._ann = jax.profiler.TraceAnnotation(
+            f"{sp.prefix}.{self._name}", **self._args)
+        self._ann.__enter__()
+        self._t0 = sp.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        sp = self._spans
+        self.seconds = sp.clock() - self._t0
+        self._ann.__exit__(*exc)
+        if self._phase is not None and sp.on_phase is not None:
+            sp.on_phase(self._phase, self.seconds)
+        if sp.tracer is not None:
+            sp.tracer.complete(self._name, sp.prefix, self._t0,
+                               self.seconds, 0, args=self._args or None)
 
 
 class Tracer:

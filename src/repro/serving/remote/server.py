@@ -47,6 +47,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from repro.serving.large_backend import (BatchPolicy, _BackendMetrics,
                                          _generate_batch, _Pending)
+from repro.serving.obs.trace import Spans
 from repro.serving.remote import wire
 
 # server-internal rid mangling: results of a superseded session must not
@@ -102,6 +103,7 @@ class MLServer:
         self._n_batches = 0                # guarded_by: self._lock
         self.batch_log: List[Dict[str, Any]] = []   # guarded_by: self._lock
         self._metrics = _BackendMetrics(registry, self)
+        self._span = Spans("ml")
         self._t_start = time.perf_counter()
 
         # fault injection (tests): delay the next N RPC responses by
@@ -207,18 +209,17 @@ class MLServer:
             drain = self._drain_flag.is_set() and self._inq.empty()
             for group, pad_to, reason in self._policy.take(
                     time.perf_counter(), drain=drain):
-                tokens, conf = _generate_batch(self._generate, group, pad_to,
-                                               self.max_new)
-                if self.latency > 0:
-                    time.sleep(self.latency)
                 with self._lock:
                     bid = self._n_batches
                     self._n_batches += 1
-                    self.batch_log.append({
-                        "batch_id": bid, "n_real": len(group),
-                        "pad_to": pad_to, "reason": reason,
-                        "prompt_len": int(group[0].prompt.shape[0])})
-                self._metrics.record_batch(len(group), pad_to, reason)
+                tokens, conf, entry = _generate_batch(
+                    self._generate, group, pad_to, self.max_new, self._span,
+                    bid, reason)
+                if self.latency > 0:
+                    time.sleep(self.latency)
+                with self._lock:
+                    self.batch_log.append(entry)
+                self._metrics.record_batch(entry)
                 for i, p in enumerate(group):
                     epoch, rid = divmod(p.rid, _RID_SPAN)
                     res = {
