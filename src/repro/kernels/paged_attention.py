@@ -5,25 +5,33 @@ DIRECTLY against the block-paged KV cache. The XLA fallback in
 `models/attention.py` materializes a dense `[B, M*block_size, ...]` view of
 every row's pages (`gather_blocks`) before attending — O(B * view) HBM
 traffic per decoded token regardless of how much of the view is valid.
-These kernels never build that view:
+These kernels never build that view. Unmapped pages (table entry 0 = the
+shared trash block) and pages past a row's `idx <= pos` prefix never reach
+the online softmax, whose fp32 (m, l, acc) state lives in VMEM; rows whose
+table is all zeros produce exact zeros.
 
-  * the kv grid dimension walks each row's page table via scalar prefetch
-    (`PrefetchScalarGridSpec`): step (b, j) DMAs exactly ONE
-    `[block_size, ...]` physical block, `tables[b, j]`, into VMEM;
-  * unmapped pages (table entry 0 = the shared trash block) and pages
-    entirely beyond the row's `idx <= pos` prefix are early-masked — the
-    online-softmax state is simply not updated, so trash contents can
-    never contribute (rows whose table is all zeros produce exact zeros);
-  * fp32 (m, l, acc) online-softmax scratch lives in VMEM across the kv
-    walk; the final kv step normalizes and writes the output tile.
+GQA — paged K/V `[n_blocks+1, block_size, KV, hd]`. The grid is one step
+per row; inside it a loop runs over the row's LIVE pages only (through its
+last mapped page at or before `pos // block_size`), `P` pages per compute
+block (`gqa_pages_per_block`: ~256 KB of K per buffer, 8 pages of 16
+tokens at 8 KV heads of 128 in bf16). K and V stay in HBM; each live page
+is one contiguous `[block_size, KV*hd]` DMA into a double-buffered VMEM
+scratch, and the next block's copies (or the next live row's first block)
+start before the current block is computed. The math is two 2-D matmuls
+per block in the cache dtype with fp32 accumulation: q, spread over the
+KV*hd lanes of its own KV head, against the block's `[P*bs, KV*hd]` keys,
+then p against the values, which lands each head's context in its own
+head's lanes (8x the attention FLOPs, negligible beside the bytes). An
+fp32 q or p meets bf16 pages as a bf16 hi/lo pair (`_hi_lo`), so the
+products keep ~16 mantissa bits. The work of a call scales with its live
+pages, not with `B * M`.
 
-Two variants share the dataflow:
-  * GQA  — paged K/V `[n_blocks+1, block_size, KV, hd]`; kv head = q head
-    // group, computed in-kernel on the `[KV, group]` score layout.
-  * MLA  — weight-absorbed decode against the COMPRESSED cache
-    (`ckv` `[*, kv_lora]` + shared rope key `[*, rope_dim]`): the kernel
-    applies the kv rms-norm per block in fp32 and returns the latent
-    context `[B, 1, H, kv_lora]`; the caller applies W_uv / W_o.
+MLA — weight-absorbed decode against the COMPRESSED cache (`ckv`
+`[*, kv_lora]` + shared rope key `[*, rope_dim]`): the grid is `(B, M)`,
+step (b, j) DMAs ONE `[block_size, ...]` block, `tables[b, j]`, and
+masks it out when unmapped or past `pos`; the kernel applies the kv
+rms-norm per block in fp32 and returns the latent context
+`[B, 1, H, kv_lora]`; the caller applies W_uv / W_o.
 
 `paged_write_token` is the companion single-token scatter: grid (B,), the
 output BlockSpec selects the physical block holding each row's `pos`
@@ -32,6 +40,8 @@ through the page table, and the kernel rewrites only `pos % block_size`
 block is unmapped write into the trash block — same contract as the XLA
 `_paged_write` they replace. Mapped blocks are pairwise disjoint across
 rows (pool invariant), so block revisits only ever hit the trash block.
+The GQA decode kernel reads the `[N, block_size, KV*hd]` view this kernel
+writes, so behind it the view costs no copy.
 
 Oracle: the dense-gather paths in `models/attention.py` (parity pinned by
 tests/test_paged_kernel.py). Wrappers with interpret-mode defaults live in
@@ -54,53 +64,135 @@ NEG = -1e30
 # GQA paged flash-decode
 # ---------------------------------------------------------------------------
 
-def _gqa_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, n_mb: int, bs: int, group: int,
+# one compute block: about this many bytes of K (and as many of V) per
+# buffer, and never more than this many pages (each page is its own DMA)
+_BLOCK_BYTES = 256 * 1024
+_MAX_BLOCK_PAGES = 16
+
+
+def gqa_pages_per_block(block_size: int, kv_heads: int, head_dim: int,
+                        dtype) -> int:
+    """Pages per compute block of the GQA kernel: ~256 KB of K per buffer
+    (8 pages of 16 tokens x 8 KV heads x 128 in bf16). Independent of the
+    table width, so slicing the table to its active prefix cannot change
+    how a row is blocked (nor its result)."""
+    page = block_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    return int(np.clip(_BLOCK_BYTES // page, 1, _MAX_BLOCK_PAGES))
+
+
+def _hi_lo(x, dtype):
+    """x [n, c] as matmul operands of `dtype`: x itself where it already
+    is no wider, else its rounding stacked on the rounding of what that
+    left ([2n, c]). A product in `dtype` then keeps ~16 mantissa bits of
+    x once `_fold` sums the two halves of the result."""
+    hi = x.astype(dtype)
+    if jnp.dtype(x.dtype).itemsize <= jnp.dtype(dtype).itemsize:
+        return hi
+    return jnp.concatenate([hi, (x - hi.astype(x.dtype)).astype(dtype)])
+
+
+def _fold(r, n: int):
+    return r if r.shape[0] == n else r[:n] + r[n:]
+
+
+def _gqa_kernel(tbl_ref, pos_ref, live_ref, blk0_ref, nxt_ref,
+                q_ref, k_hbm, v_hbm, o_ref,
+                kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
+                n_rows: int, M: int, P: int, bs: int, KV: int, group: int,
                 scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    H, hd = q_ref.shape[2], q_ref.shape[3]
+    T = P * bs
 
-    @pl.when(j == 0)
+    def page(row, blk, i):
+        """(page id, live) of page `i` of compute block `blk` of `row`:
+        live = mapped and before the row's live-page count."""
+        col = blk * P + i
+        pid = tbl_ref[row * M + jnp.minimum(col, M - 1)]
+        return pid, (col < live_ref[row]) & (pid > 0)
+
+    def dmas(row, blk, slot, op):
+        # one contiguous [bs, KV*hd] copy per live page, K and V each
+        def one(i, carry):
+            pid, live = page(row, blk, i)
+
+            @pl.when(live)
+            def _():
+                for n, (src, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                    getattr(pltpu.make_async_copy(
+                        src.at[pid], buf.at[slot, i], sems.at[n, slot]),
+                        op)()
+            return carry
+        jax.lax.fori_loop(0, P, one, 0)
+
+    @pl.when(b == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # buffer pages no copy reaches still meet p == 0: keep them finite
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
 
-    page = tbl_ref[b, j]
+        @pl.when(nxt_ref[0] < n_rows)
+        def _():
+            dmas(nxt_ref[0], 0, 0, "start")
+
+    m_ref[...] = jnp.full_like(m_ref, NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     pos = pos_ref[b]
-    # early-mask: skip unmapped/trash pages and pages fully beyond the
-    # row's valid prefix — their DMA'd block never touches the softmax
-    valid_block = (page > 0) & (j * bs <= pos)
+    # q spread over the KV*hd lanes of its own KV head, zeros elsewhere:
+    # one 2-D matmul against the block's [T, KV*hd] keys then gives every
+    # head its scores, and p . v lands each head's context in its lanes
+    q = q_ref[0, 0]
+    kv_of = jax.lax.broadcasted_iota(jnp.int32, (H, hd), 0) // group
+    qx = _hi_lo(jnp.concatenate(
+        [jnp.where(kv_of == h, q, 0) for h in range(KV)], axis=1),
+        kbuf.dtype)                                    # [(2)H, KV*hd]
+    n_blk = (live_ref[b] + P - 1) // P
 
-    @pl.when(valid_block)
-    def _():
-        q = q_ref[0, 0].astype(jnp.float32)              # [H, hd]
-        k = k_ref[0].astype(jnp.float32)                 # [bs, KV, hd]
-        v = v_ref[0].astype(jnp.float32)
-        KV = k.shape[1]
-        qg = q.reshape(KV, group, q.shape[-1])
-        s = jnp.einsum("kgh,skh->kgs", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        idx = j * bs + jax.lax.broadcasted_iota(jnp.int32,
-                                                (KV, group, bs), 2)
-        mask = idx <= pos                                # per-row validity
+    def body(j, carry):
+        slot = (blk0_ref[b] + j) % 2
+        # start the next block (this row's, else the next live row's
+        # first) before computing this one
+        last = j + 1 == n_blk
+        nb = jnp.where(last, nxt_ref[b + 1], b)
+
+        @pl.when(nb < n_rows)
+        def _():
+            dmas(nb, jnp.where(last, 0, j + 1), 1 - slot, "start")
+
+        dmas(b, j, slot, "wait")
+        k = kbuf[slot].reshape(T, -1)                          # [T, KV*hd]
+        v = vbuf[slot].reshape(T, -1)
+        s = _fold(jax.lax.dot_general(
+            qx, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), H) * scale    # [H, T]
+        # a token counts if it is at or before pos on a page copied for
+        # this block: the rest of the buffer holds stale pages or zeros
+        tok = jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
+        copied = jax.lax.fori_loop(
+            0, P, lambda i, ok: jnp.where(
+                (tok // bs == i) & page(b, j, i)[1], 1, ok),
+            jnp.zeros_like(tok))
+        mask = (j * T + tok <= pos) & (copied > 0)
         s = jnp.where(mask, s, NEG)
-        bm = s.max(axis=-1)
         m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, bm)
+        m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_old - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + jnp.einsum(
-            "kgs,skh->kgh", p, v, preferred_element_type=jnp.float32)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _fold(jnp.dot(
+            _hi_lo(p, v.dtype), v, preferred_element_type=jnp.float32), H)
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(j == n_mb - 1)
-    def _final():
-        H, hd = q_ref.shape[2], q_ref.shape[3]
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[..., None]
-                       ).reshape(H, hd).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    # each head's context sits in its own KV head's hd lanes
+    acc = acc_ref[...]
+    out = jnp.zeros((H, hd), jnp.float32)
+    for h in range(KV):
+        out = out + jnp.where(kv_of == h, acc[:, h * hd:(h + 1) * hd], 0.0)
+    o_ref[0, 0] = (out / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -111,37 +203,59 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
     """q [B,1,H,hd]; k/v_pages [n_blocks+1, block_size, KV, hd];
     tables [B, M] int32 (0 = unmapped); positions [B] int32. Returns
     [B,1,H,hd] — masked-softmax attention over each row's valid prefix,
-    identical (up to fp32 online-softmax rounding) to the dense-gather
-    path. Fully-unmapped rows return exact zeros."""
+    equal to the dense-gather path up to fp32 online-softmax rounding
+    (bf16 pages with an fp32 q: ~1e-5 of a float64 reference). Rows
+    with no mapped page at or before pos return exact zeros."""
     B, T, H, hd = q.shape
     assert T == 1
-    bs, KV = k_pages.shape[1], k_pages.shape[2]
+    N, bs, KV = k_pages.shape[:3]
     M = tables.shape[1]
     group = H // KV
     scale = scale or 1.0 / np.sqrt(hd)
-    kernel = functools.partial(_gqa_kernel, n_mb=M, bs=bs, group=group,
-                               scale=scale)
+    P = gqa_pages_per_block(bs, KV, hd, k_pages.dtype)
+    tables = tables.astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    # live pages of a row: through its last mapped page at or before
+    # pos // bs (0 for an all-trash row); compute blocks are numbered
+    # across rows so the double buffer alternates through the whole call
+    col = jnp.arange(M, dtype=jnp.int32)
+    mapped = (tables > 0) & (col[None, :] <= (positions // bs)[:, None])
+    live = jnp.max(jnp.where(mapped, col + 1, 0), axis=1)
+    n_blk = (live + P - 1) // P
+    blk0 = jnp.cumsum(n_blk) - n_blk
+    # nxt[b]: the first row >= b with live pages (B if none); nxt[B] = B
+    nxt = jax.lax.cummin(jnp.where(n_blk > 0, jnp.arange(B), B),
+                         reverse=True)
+    nxt = jnp.concatenate([nxt, jnp.full((1,), B, jnp.int32)])
+    kernel = functools.partial(_gqa_kernel, n_rows=B, M=M, P=P, bs=bs,
+                               KV=KV, group=group, scale=scale)
+    row = pl.BlockSpec((1, 1, H, hd),
+                       lambda b, tbl, pos, live, blk0, nxt: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, M),
-        in_specs=[
-            pl.BlockSpec((1, 1, H, hd), lambda b, j, t, p: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, KV, hd),
-                         lambda b, j, t, p: (t[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, KV, hd),
-                         lambda b, j, t, p: (t[b, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, H, hd), lambda b, j, t, p: (b, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((KV, group), jnp.float32),
-                        pltpu.VMEM((KV, group), jnp.float32),
-                        pltpu.VMEM((KV, group, hd), jnp.float32)],
+        num_scalar_prefetch=5,
+        grid=(B,),
+        in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row,
+        # the two K/V landing buffers hold the pages' own bytes, as
+        # copied; the online-softmax state (m, l, acc) below is fp32
+        scratch_shapes=[
+            pltpu.VMEM((2, P, bs, KV * hd), k_pages.dtype),  # repro: ignore[PL004]
+            pltpu.VMEM((2, P, bs, KV * hd), v_pages.dtype),  # repro: ignore[PL004]
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, KV * hd), jnp.float32)],
     )
+    # rows run in order: each prefetches the next live row's first block
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32), q,
-      k_pages, v_pages)
+    )(tables.reshape(-1), positions, live, blk0, nxt, q,
+      k_pages.reshape(N, bs, KV * hd), v_pages.reshape(N, bs, KV * hd))
 
 
 # ---------------------------------------------------------------------------

@@ -27,8 +27,9 @@ softmax, pinned by tests/test_paged_kernel.py):
     only O(log M) shapes ever compile) — the gather then reads only
     blocks the mask can reach.
   * Pallas kernel (default on TPU; kernels/paged_attention.py) — walks
-    the page table inside the kernel, one block per kv grid step, no
-    dense view in HBM; the single-token cache write is also a kernel.
+    the page table inside the kernel (GQA: each row's live pages only,
+    several per step), no dense view in HBM; the single-token cache
+    write is also a kernel.
   Selection: the `paged_kernel` argument when given, else the backend
   default (kernels/ops.py).
 

@@ -1457,11 +1457,15 @@ class ContinuousCascadeEngine:
                 decoding slot) and redo the prep against the survivors —
                 ensure_mapped / ensure_writable are idempotent, so the
                 retry resumes exactly where pressure struck. Returns the
-                slots left to decode and their table."""
+                slots left to decode, their table and the span args
+                `kv_pages` (the pages the paged kernel reads per layer:
+                each decoding row's `pos // bs + 1`) and `table_pages`
+                (rows x columns of the table, what a walk of all of it
+                would visit)."""
                 while True:
                     decoding = decoding_slots()
                     if not decoding:
-                        return decoding, None
+                        return decoding, None, {}
                     try:
                         pos_host = np.asarray(state["pos"])
                         need = 1
@@ -1483,8 +1487,11 @@ class ContinuousCascadeEngine:
                     except BlockPressure:
                         if policy is None or not relieve_pressure():
                             raise
-                return decoding, pool.tables_device(
-                    pool.active_prefix_blocks(need))
+                tbl = pool.tables_device(pool.active_prefix_blocks(need))
+                return decoding, tbl, {
+                    "kv_pages": sum(int(pos_host[slot]) // bs + 1
+                                    for slot in decoding),
+                    "table_pages": tbl.shape[0] * tbl.shape[1]}
 
             def drain_ladder():
                 """All M_S work is done: drain the ladder edge by edge.
@@ -1544,15 +1551,15 @@ class ContinuousCascadeEngine:
                             # the decode phase holds its prep: the pos
                             # pull, block mapping and page table
                             with span("decode", phase="decode") as dec:
-                                tables = ()
+                                tables, pages = (), {}
                                 if paged:
                                     with span("decode_prep"):
-                                        decoding, tbl = decode_prep()
+                                        decoding, tbl, pages = decode_prep()
                                     tables = (tbl,)
                                 if decoding:
                                     step = n_steps // self.steps_per_sync
                                     with span("decode_dispatch", step=step,
-                                              rows=len(decoding)):
+                                              rows=len(decoding), **pages):
                                         pool.cache, state = step_fn(
                                             self.small.params, pool.cache,
                                             state, *tables)

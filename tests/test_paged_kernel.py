@@ -50,31 +50,115 @@ POS = np.asarray([9, 6, 2, 3], np.int32)       # rows 0-2 mapped, row 3 trash
 # Kernel-level parity vs the dense-gather reference
 # ---------------------------------------------------------------------------
 
-def test_gqa_kernel_parity_ragged():
-    key = jax.random.PRNGKey(1)
-    B, H, KV, hd, bs, N = 4, 4, 2, 16, 4, 8
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (N, bs, KV, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (N, bs, KV, hd), jnp.float32)
-    tables, pos = jnp.asarray(TABLES), jnp.asarray(POS)
+# GQA kernel cases. Tiny widths (block 4, 2 KV heads of 16) give 16 pages
+# per compute block; the served widths (block 16, 8 KV heads of 128) give
+# 8 in bf16 and 4 in fp32. Every row maps all M columns unless `unmapped`
+# (row, columns) says otherwise; pages no row may read hold NaN.
+SERVED = (16, 8, 128, 16)                       # bs, KV, hd, H
+SERVED_PAGES = 1 + 4 * 13                       # one pool for M 13 and 1
+GQA_CASES = {
+    # the original ragged table: M (4) below the block's 16 pages
+    "ragged": dict(dims=(4, 2, 16, 4), tables=TABLES, pos=POS,
+                   dtype=jnp.float32, atol=1e-5),
+    # M 13 = 8 + 5: each row's last block is ragged, ends mid-block
+    "m_not_multiple": dict(dims=SERVED, M=13, pos=[200, 150, 40, 7],
+                           dtype=jnp.bfloat16, atol=2e-5),
+    # ends on the last token of block 0, the first of block 1, the last
+    # of the table; one mid-block
+    "block_boundary": dict(dims=SERVED, M=13, pos=[127, 128, 207, 143],
+                           dtype=jnp.bfloat16, atol=2e-5),
+    # holes in both blocks; a first page unmapped; a whole first block
+    # unmapped; trailing live columns unmapped
+    "unmapped_inside": dict(dims=SERVED, M=13, pos=[200, 100, 150, 180],
+                            unmapped=[(0, [3, 8]), (1, [0]),
+                                      (2, list(range(8))),
+                                      (3, list(range(5, 13)))],
+                            dtype=jnp.bfloat16, atol=2e-5),
+    # the first and last rows all trash: the prefetch chain skips them
+    "all_trash": dict(dims=SERVED, M=13, pos=[50, 120, 9, 60],
+                      unmapped=[(0, list(range(13))),
+                                (3, list(range(13)))],
+                      dtype=jnp.bfloat16, atol=2e-5),
+    "m1": dict(dims=SERVED, M=1, pos=[3, 15, 0, 9],
+               unmapped=[(2, [0])], dtype=jnp.bfloat16, atol=2e-5),
+    # fp32 pages: 4 per block, so M 13 leaves a 1-page last block
+    "fp32": dict(dims=SERVED, M=13, pos=[200, 63, 64, 5],
+                 dtype=jnp.float32, atol=1e-5),
+    # bf16 q as well: the output is rounded to bf16 (2^-9 relative)
+    "bf16_q": dict(dims=SERVED, M=13, pos=[200, 150, 40, 7],
+                   dtype=jnp.bfloat16, q_dtype=jnp.bfloat16, atol=1e-2),
+}
 
-    out = kops.paged_flash_decode_gqa(q, kp, vp, tables, pos)
 
-    kk, vv = gather_blocks(kp, tables), gather_blocks(vp, tables)
-    S = kk.shape[1]
-    mask = jnp.arange(S)[None, :] <= pos[:, None]
-    qg = q.reshape(B, 1, KV, H // KV, hd)
-    s = jnp.einsum("btkgh,bskh->bkgts", qg, kk) / np.sqrt(hd)
-    s = jnp.where(mask[:, None, None, None, :], s, -1e30)
-    ref = jnp.einsum("bkgts,bskh->btkgh", jax.nn.softmax(s, axis=-1),
-                     vv).reshape(B, 1, H, hd)
-    # mapped rows: epsilon parity (fp32 online softmax vs XLA softmax)
-    np.testing.assert_allclose(np.asarray(out[:3]), np.asarray(ref[:3]),
-                               atol=1e-5)
-    # all-trash row: every page early-masked -> exact zeros, no NaN
-    np.testing.assert_array_equal(np.asarray(out[3]),
-                                  np.zeros_like(np.asarray(out[3])))
+def _gqa_case(case, seed=1):
+    """(q, k_pages, v_pages, tables, pos) of a case. The served cases
+    share one pool, so each dtype and table width compiles once."""
+    bs, KV, hd, H = case["dims"]
+    pos = np.asarray(case["pos"], np.int32)
+    if "tables" in case:
+        tables = np.asarray(case["tables"], np.int32)
+    else:
+        mapped = np.ones((len(pos), case["M"]), bool)
+        for row, cols in case.get("unmapped", ()):
+            mapped[row, cols] = False
+        tables = np.zeros(mapped.shape, np.int32)
+        tables[mapped] = np.arange(1, mapped.sum() + 1)
+    B, M = tables.shape
+    N = int(tables.max()) + 1 if "tables" in case else SERVED_PAGES
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((B, 1, H, hd), np.float32)
+    kp, vp = rng.standard_normal((2, N, bs, KV, hd), np.float32)
+    live = (tables > 0) & (np.arange(M)[None, :] <= pos[:, None] // bs)
+    dead = np.setdiff1d(np.arange(N), tables[live])
+    kp[dead] = np.nan
+    vp[dead] = np.nan
+    return (jnp.asarray(q, case.get("q_dtype", jnp.float32)),
+            jnp.asarray(kp, case["dtype"]), jnp.asarray(vp, case["dtype"]),
+            tables, pos)
+
+
+def _gqa_reference(q, kp, vp, tables, pos):
+    """float64 masked softmax over each row's mapped `idx <= pos` tokens;
+    rows without one give zeros."""
+    q, kp, vp = (np.asarray(a.astype(jnp.float32), np.float64)
+                 for a in (q, kp, vp))
+    B, _, H, hd = q.shape
+    bs, KV = kp.shape[1], kp.shape[2]
+    kk = kp[tables].reshape(B, -1, KV, hd)
+    vv = vp[tables].reshape(B, -1, KV, hd)
+    idx = np.arange(kk.shape[1])[None, :]
+    ok = (idx <= pos[:, None]) & np.repeat(tables > 0, bs, axis=1)
+    kk = np.where(ok[..., None, None], kk, 0.0)
+    vv = np.where(ok[..., None, None], vv, 0.0)
+    qg = q[:, 0].reshape(B, KV, H // KV, hd)
+    s = np.einsum("bkgh,bskh->bkgs", qg, kk) / np.sqrt(hd)
+    s = np.where(ok[:, None, None, :], s, -np.inf)
+    m = np.max(s, axis=-1, keepdims=True)
+    p = np.where(ok[:, None, None, :], np.exp(s - np.where(
+        np.isfinite(m), m, 0.0)), 0.0)
+    p = p / np.maximum(p.sum(-1, keepdims=True), 1e-300)
+    return np.einsum("bkgs,bskh->bkgh", p, vv).reshape(B, 1, H, hd)
+
+
+@pytest.mark.parametrize("name", list(GQA_CASES))
+def test_gqa_kernel_parity_ragged(name):
+    """The many-pages-per-step kernel against a float64 dense gather:
+    rows with a readable token agree within the case's `atol`; rows with
+    none (all trash) are exact zeros; NaN in every page no row may read
+    (trash block, holes, pages past `pos`) never reaches the output."""
+    case = GQA_CASES[name]
+    q, kp, vp, tables, pos = _gqa_case(case)
+    out = np.asarray(kops.paged_flash_decode_gqa(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(pos)), np.float64)
+    ref = _gqa_reference(q, kp, vp, tables, pos)
+    bs = kp.shape[1]
+    readable = ((tables > 0)
+                & (np.arange(tables.shape[1])[None, :]
+                   <= pos[:, None] // bs)).any(axis=1)
+    np.testing.assert_allclose(out[readable], ref[readable],
+                               atol=case["atol"], rtol=0)
+    np.testing.assert_array_equal(out[~readable],
+                                  np.zeros_like(out[~readable]))
 
 
 def test_mla_kernel_parity_ragged():

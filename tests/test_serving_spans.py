@@ -146,6 +146,21 @@ def test_dispatch_spans_and_audit_steps_share_the_index(profiled):
     assert [d["rows"] for d in disp] == [len(e["slots"]) for e in steps]
 
 
+def test_dispatch_spans_count_kernel_pages(profiled):
+    """Each paged dispatch says how many pages the kernel reads per layer
+    (each decoding row's `pos // block_size + 1`) against the page
+    table's rows x columns."""
+    _, spans, _ = profiled
+    disp = [s[3] for line in spans.values() for s in line
+            if s[0] == "engine.decode_dispatch"]
+    assert disp
+    for d in disp:
+        assert d["rows"] <= d["kv_pages"] <= d["table_pages"]
+        assert d["table_pages"] % 3 == 0          # n_slots rows
+    # a row past its first block reads more than one page
+    assert max(d["kv_pages"] - d["rows"] for d in disp) > 0
+
+
 def test_upper_tier_program_is_named(runners):
     small, large, prompts, _ = runners
     large.generate(np.asarray(prompts), 8, 6)

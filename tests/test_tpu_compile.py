@@ -61,14 +61,21 @@ def _compile(fn, sharding, *shapes):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
 def test_gqa_paged_decode_compiles(one_chip, dtype):
+    """The multi-page kernel at the table widths the engine hands it: the
+    full table with q in the pages' dtype, then 16 slots at the largest
+    power-of-two prefix (128) and at the clamp (145, not a multiple of
+    the 8-page block) with q in fp32, as served (rope leaves it fp32)."""
     cfg = get_config("internlm2-1.8b")
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    _compile(functools.partial(pa.paged_flash_decode_gqa, interpret=False),
-             one_chip,
-             ((SLOTS, 1, H, hd), dtype),
-             ((N_BLOCKS, BLOCK, KV, hd), dtype),
-             ((N_BLOCKS, BLOCK, KV, hd), dtype),
-             ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
+    assert pa.gqa_pages_per_block(BLOCK, KV, hd, jnp.bfloat16) == 8
+    fn = functools.partial(pa.paged_flash_decode_gqa, interpret=False)
+    for slots, m, q_dtype in ((SLOTS, M, dtype), (16, 128, jnp.float32),
+                              (16, 145, jnp.float32)):
+        _compile(fn, one_chip,
+                 ((slots, 1, H, hd), q_dtype),
+                 ((N_BLOCKS, BLOCK, KV, hd), dtype),
+                 ((N_BLOCKS, BLOCK, KV, hd), dtype),
+                 ((slots, m), jnp.int32), ((slots,), jnp.int32))
 
 
 def _leaf_shapes():
