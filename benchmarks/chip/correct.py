@@ -1,10 +1,10 @@
 """The comparison that decides `correct`.
 
 After the window has closed, every request due in the window that
-finished is checked against the plain fp32 reference
-(`model.reference_readings`), run once the program's device state has
-been freed. Two numbers are read; each is compared with its limit where
-the configuration file gives one:
+finished is checked against the plain fp32 reference of each tier that
+read it (its architecture module's `reference_readings`), run once the
+program's device state has been freed. Two numbers are read; each is
+compared with its limit where the configuration file gives one:
 
 * served_gap: the widest gap, over every served token, by which the
   served token's reference logit lies below the reference's best logit
@@ -99,7 +99,7 @@ def gate_miss(nent: np.ndarray, c: Checked, gate: Gate) -> float:
     return max(miss)
 
 
-def readings(checked: List[Checked], tiers: List[model.Tier],
+def readings(checked: List[Checked], archs: list, tiers: list,
              weights_seed: int, served_dtype, gate: Gate,
              control: bool = False) -> Dict[str, Dict[str, float]]:
     """{"program": {"served_gap": .., "gate_miss": ..}} and, with
@@ -112,7 +112,7 @@ def readings(checked: List[Checked], tiers: List[model.Tier],
     quants = (None, "fp8") if control else (None,)
     gap = {q: 0.0 for q in quants}
     miss = -np.inf
-    for k, t in enumerate(tiers):
+    for k, (arch, t) in enumerate(zip(archs, tiers)):
         if k == 0:
             rows = [(c, c.tokens if c.tier == 0 else c.small_tokens)
                     for c in checked]
@@ -121,7 +121,7 @@ def readings(checked: List[Checked], tiers: List[model.Tier],
             rows = [(c, c.tokens) for c in checked if c.tier == k]
         if not rows:
             continue
-        res = model.reference_readings(
+        res = arch.reference_readings(
             t, model.tier_key(weights_seed, k),
             [model.Sequence_(c.prompt, toks) for c, toks in rows],
             served_dtype, quants)

@@ -41,56 +41,30 @@ class Compiles:
             self.seconds += secs
 
 
-def program_params(canon: Dict[str, jax.Array]) -> dict:
-    """The benchmark's stacked leaves in the program's parameter layout
-    (`repro.models.transformer.init_params`, dense family)."""
-    return {
-        "embedding": canon["embedding"],
-        "final_norm": canon["final_norm"],
-        "unembed": canon["unembed"],
-        "blocks": {"dense": {
-            "norm1": canon["norm1"],
-            "attn": {k: canon[k] for k in ("wq", "wk", "wv", "wo")},
-            "norm2": canon["norm2"],
-            "mlp": {k: canon[k] for k in ("w_gate", "w_up", "w_down")},
-        }},
-    }
-
-
-def model_config(t: model.Tier, dtype: str):
-    from repro.configs import ModelConfig
-    return ModelConfig(name=t.name, family="dense", n_layers=t.n_layers,
-                       d_model=t.d_model, n_heads=t.n_heads,
-                       n_kv_heads=t.n_kv_heads, head_dim=t.head_dim,
-                       d_ff=t.d_ff, vocab_size=t.vocab_size,
-                       rope_theta=t.rope_theta, norm_eps=t.norm_eps,
-                       param_dtype=dtype, compute_dtype=dtype)
-
-
 @dataclasses.dataclass
 class Program:
     engine: Any
     runners: List[Any]
-    tiers: List[model.Tier]
+    archs: List[Any]            # each tier's architecture module
+    tiers: List[Any]
     serving: dict
     taus: List[float]
 
 
-def build(cfg: dict, weights_seed: int) -> Program:
-    """Weights from `weights_seed`, one jitted call per tier, then the
-    ladder."""
+def build(cfg: dict, archs: list, tiers: list, weights_seed: int
+          ) -> Program:
+    """Weights from `weights_seed`, one jitted call per tier through its
+    architecture module (`model.load_tiers`), then the ladder."""
     from repro.serving import (CascadeSpec, CascadeTier,
                                ContinuousCascadeEngine, DeferralEdge,
                                EngineConfig, MLBackendConfig, ModelRunner,
                                PagedConfig)
-    tiers = [model.Tier.from_json(t) for t in cfg["tiers"]]
     dtype = cfg["dtype"]
     runners = []
-    for i, t in enumerate(tiers):
-        canon = model.make_weights(t, model.tier_key(weights_seed, i),
-                                   DTYPES[dtype])
-        runners.append(ModelRunner(model_config(t, dtype),
-                                   program_params(canon)))
+    for i, (arch, t) in enumerate(zip(archs, tiers)):
+        weights = arch.make_weights(t, model.tier_key(weights_seed, i),
+                                    DTYPES[dtype])
+        runners.append(ModelRunner(*arch.program(t, weights, dtype)))
     sv = cfg["serving"]
     n = len(tiers)
     costs = [0.2 * (1.0 / 0.2) ** (i / (n - 1)) for i in range(n)]
@@ -112,7 +86,7 @@ def build(cfg: dict, weights_seed: int) -> Program:
                            max_wait=sv["max_wait_s"]))
     engine = ContinuousCascadeEngine(spec, config)
     jax.block_until_ready([r.params for r in runners])
-    return Program(engine, runners, tiers, sv, [])
+    return Program(engine, runners, archs, tiers, sv, [])
 
 
 def calibrate(prog: Program, cal_prompts: np.ndarray, max_new: int,

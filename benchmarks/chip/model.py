@@ -1,77 +1,101 @@
-"""The benchmark's own model: seeded weights and the plain fp32 reference.
+"""The benchmark's own model: what every architecture shares, and the
+loader that finds each tier's architecture by name.
+
+Architectures. Each tier of a configuration file names its architecture
+under `"arch"`: a module `archs/<arch>.py` of the benchmark's own
+directory, loaded by file path. A module provides these functions and
+nothing else is asked of it:
+
+* `tier(entry) -> T`: a frozen, hashable description of the tier from
+  its configuration entry, with at least `name`, `n_layers`,
+  `vocab_size` and `n_params`;
+* `make_weights(t, key, dtype)`: every leaf, on the device, in `dtype`
+  (the dtype they are served in), drawn through `leaf_key` from `key`;
+* `program(t, weights, dtype_name) -> (ModelConfig, params)`: the
+  weights in the program's own layout, through its public surface;
+* `reference_readings(t, key, seqs, served_dtype, quants)`: the plain
+  fp32 reference over each sequence, as `reference_readings` below
+  returns it, with the weights drawn again from `key`;
+* `decode_step_flops(t, positions)`: FLOPs of one decode step over rows
+  writing the given positions;
+* `paged_attn_calls(t, positions, rows) -> [(bytes, flops), ...]`: one
+  entry per paged-attention kernel call that such a step makes.
 
 Weights. Every leaf of every tier is drawn from the configuration's
 weights seed through a fixed key path (seed -> tier -> leaf -> layer),
-so the program's weights (made here in one jitted call, in the dtype
-they are served in) and the reference's (made again, layer by layer,
-after the program has been freed) are the same numbers. The reference
-takes nothing the program made.
+so the program's weights (made in one jitted call, in the dtype they
+are served in) and the reference's (made again, layer by layer, after
+the program has been freed) are the same numbers. The reference takes
+nothing the program made.
 
-Reference. A decoder as the configuration describes it, in float32 at
-`highest` matmul precision, written from the equations with no cache,
-kernel or batching: token embedding; per layer a pre-norm GQA attention
-block (RMSNorm with gain 1 + w, rotary embedding on the two halves of
-each head, causal softmax(q k^T / sqrt(head_dim)) v) and a pre-norm
-SwiGLU MLP, both added to the residual; a final RMSNorm and an untied
-unembedding. `quant="fp8"` computes the same model with every matmul
-input (weights per output channel, activations per row) rounded to
-float8 e4m3 after scaling: the control that the comparison must fail.
+Reference. Written from the equations with no cache, kernel or
+batching, in float32 at `highest` matmul precision: token embedding, the
+architecture's layers, a final RMSNorm and an untied unembedding.
+`quant="fp8"` computes the same model with every matmul input (weights
+per output channel, activations per row) rounded to float8 e4m3 after
+scaling: the control that the comparison must fail.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence
+import importlib.util
+import pathlib
+import re
+import sys
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 NORM_STD = 0.1          # norm gains are 1 + N(0, 0.1^2)
-LAYER_LEAVES = ("norm1", "wq", "wk", "wv", "wo", "norm2", "w_gate", "w_up",
-                "w_down")
 GLOBAL_LEAVES = ("embedding", "unembed", "final_norm")
 
 
-@dataclasses.dataclass(frozen=True)
-class Tier:
-    name: str
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab_size: int
-    rope_theta: float
-    norm_eps: float
+# ---------------------------------------------------------------------------
+# architectures, found by name
+# ---------------------------------------------------------------------------
 
-    @classmethod
-    def from_json(cls, d: dict) -> "Tier":
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)})
+def load_arch(bench_dir: pathlib.Path, name: str):
+    """The module `<bench_dir>/archs/<name>.py`, loaded by file path."""
+    path = pathlib.Path(bench_dir) / "archs" / f"{name}.py"
+    mod_name = "arch_" + re.sub(r"\W", "_", name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # dataclasses look their module up while the class is made
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
-    def leaf_shape(self, leaf: str) -> tuple:
-        d, H, KV, hd, f, V = (self.d_model, self.n_heads, self.n_kv_heads,
-                              self.head_dim, self.d_ff, self.vocab_size)
-        return {"norm1": (d,), "norm2": (d,), "final_norm": (d,),
-                "wq": (d, H, hd), "wk": (d, KV, hd), "wv": (d, KV, hd),
-                "wo": (H, hd, d), "w_gate": (d, f), "w_up": (d, f),
-                "w_down": (f, d), "embedding": (V, d),
-                "unembed": (V, d)}[leaf]
 
-    def leaf_std(self, leaf: str) -> float:
-        if leaf.startswith("norm") or leaf == "final_norm":
-            return NORM_STD
-        fan_in = {"wo": self.n_heads * self.head_dim,
-                  "w_down": self.d_ff}.get(leaf, self.d_model)
-        return 1.0 / float(np.sqrt(fan_in))
+def load_tiers(bench_dir: pathlib.Path, cfg: dict, cfg_file: str):
+    """(modules, tiers): each tier's architecture module and its
+    description. A tier that names no architecture, or one that has no
+    module, or whose entry its module refuses, stops the run."""
+    mods, tiers = [], []
+    for i, entry in enumerate(cfg["tiers"]):
+        where = f"{cfg_file}: tier {i} ({entry.get('name', '?')})"
+        name = entry.get("arch")
+        if not name:
+            raise SystemExit(f"{where} names no architecture (\"arch\")")
+        path = pathlib.Path(bench_dir) / "archs" / f"{name}.py"
+        if not path.is_file():
+            raise SystemExit(f"{where} names architecture {name!r}, which "
+                             f"has no module {path}")
+        mod = load_arch(bench_dir, name)
+        try:
+            tiers.append(mod.tier(entry))
+        except (KeyError, TypeError, ValueError) as e:
+            raise SystemExit(f"{where}: architecture {name!r} refuses the "
+                             f"entry: {e!r}") from e
+        mods.append(mod)
+    return mods, tiers
 
-    @property
-    def n_params(self) -> int:
-        per_layer = sum(int(np.prod(self.leaf_shape(k))) for k in LAYER_LEAVES)
-        return (self.n_layers * per_layer
-                + sum(int(np.prod(self.leaf_shape(k))) for k in GLOBAL_LEAVES))
 
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
 
 def tier_key(seed: int, tier: int) -> jax.Array:
     lo, hi = seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF
@@ -80,48 +104,58 @@ def tier_key(seed: int, tier: int) -> jax.Array:
     return jax.random.fold_in(key, np.uint32(tier))
 
 
-def _leaf_key(key, leaf: str):
-    idx = (GLOBAL_LEAVES + LAYER_LEAVES).index(leaf)
-    return jax.random.fold_in(key, idx)
+def leaf_key(key, leaves: Sequence[str], leaf: str, layer=None):
+    """The key of one leaf: the tier's key folded with the leaf's index in
+    `leaves` (GLOBAL_LEAVES first, then the architecture's layer leaves)
+    and, for a layer leaf, with the layer."""
+    k = jax.random.fold_in(key, tuple(leaves).index(leaf))
+    return k if layer is None else jax.random.fold_in(k, layer)
 
 
 def _draw(key, shape, std, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def global_leaf(t: Tier, key, leaf: str, dtype=jnp.float32):
-    return _draw(_leaf_key(key, leaf), t.leaf_shape(leaf), t.leaf_std(leaf),
-                 dtype)
-
-
-def layer_leaf(t: Tier, key, leaf: str, layer, dtype=jnp.float32):
-    k = jax.random.fold_in(_leaf_key(key, leaf), layer)
-    return _draw(k, t.leaf_shape(leaf), t.leaf_std(leaf), dtype)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 2))
-def make_weights(t: Tier, key, dtype) -> Dict[str, jax.Array]:
-    """All leaves of one tier, on the device, in `dtype`; layer leaves
-    stacked on a leading axis of n_layers."""
-    out = {k: global_leaf(t, key, k, dtype) for k in GLOBAL_LEAVES}
-    layers = jnp.arange(t.n_layers)
-    for k in LAYER_LEAVES:
-        out[k] = jax.vmap(lambda l, k=k: layer_leaf(t, key, k, l, dtype))(
-            layers)
-    return out
-
-
-@functools.partial(jax.jit, static_argnums=(0, 2))
-def _layer_weights(t: Tier, key, layer_dtype, layer):
-    """One layer's leaves in float32, rounded through the served dtype so
-    that they equal the program's values."""
-    return {k: layer_leaf(t, key, k, layer, layer_dtype).astype(jnp.float32)
-            for k in LAYER_LEAVES}
+def count_params(t, layer_leaves: Sequence[str]) -> int:
+    """Parameters of a tier whose every layer holds `layer_leaves`, with
+    shapes from `t.leaf_shape`."""
+    def size(k):
+        return int(np.prod(t.leaf_shape(k)))
+    return (t.n_layers * sum(size(k) for k in layer_leaves)
+            + sum(size(k) for k in GLOBAL_LEAVES))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3))
-def _global_weights(t: Tier, key, served_dtype, leaf):
-    return global_leaf(t, key, leaf, served_dtype).astype(jnp.float32)
+def stacked_weights(t, key, dtype, layer_leaves: tuple
+                    ) -> Dict[str, jax.Array]:
+    """All leaves of a tier whose every layer holds `layer_leaves`, on
+    the device, in `dtype`; layer leaves stacked on a leading axis of
+    n_layers. `t` gives each leaf's `leaf_shape` and `leaf_std`."""
+    leaves = GLOBAL_LEAVES + layer_leaves
+    out = {k: _draw(leaf_key(key, leaves, k), t.leaf_shape(k),
+                    t.leaf_std(k), dtype) for k in GLOBAL_LEAVES}
+    layers = jnp.arange(t.n_layers)
+    for k in layer_leaves:
+        out[k] = jax.vmap(lambda l, k=k: _draw(
+            leaf_key(key, leaves, k, l), t.leaf_shape(k), t.leaf_std(k),
+            dtype))(layers)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def layer_weights(t, key, served_dtype, layer_leaves: tuple, layer):
+    """One layer's leaves in float32, rounded through the served dtype so
+    that they equal the program's values."""
+    leaves = GLOBAL_LEAVES + layer_leaves
+    return {k: _draw(leaf_key(key, leaves, k, layer), t.leaf_shape(k),
+                     t.leaf_std(k), served_dtype).astype(jnp.float32)
+            for k in layer_leaves}
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def global_weight(t, key, served_dtype, leaf):
+    return _draw(leaf_key(key, GLOBAL_LEAVES, leaf), t.leaf_shape(leaf),
+                 t.leaf_std(leaf), served_dtype).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -156,42 +190,13 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def _layer(t: Tier, w, x, quant):
-    """One decoder layer over one sequence x [T, d]."""
-    with jax.default_matmul_precision("highest"):
-        T = x.shape[0]
-        pos = jnp.arange(T)
-        h = _q(_rms(x, w["norm1"], t.norm_eps), -1, quant)
-        q = jnp.einsum("td,dhk->thk", h, _q(w["wq"], 0, quant))
-        k = jnp.einsum("td,dhk->thk", h, _q(w["wk"], 0, quant))
-        v = jnp.einsum("td,dhk->thk", h, _q(w["wv"], 0, quant))
-        q = _q(_rope(q, pos, t.rope_theta), -1, quant)
-        k = _q(_rope(k, pos, t.rope_theta), -1, quant)
-        v = _q(v, -1, quant)
-        g = t.n_heads // t.n_kv_heads
-        k = jnp.repeat(k, g, axis=1)                 # head h reads kv h // g
-        v = jnp.repeat(v, g, axis=1)
-        s = jnp.einsum("qhk,shk->hqs", q, k) / np.sqrt(t.head_dim)
-        s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
-        p = _q(jax.nn.softmax(s, axis=-1), -1, quant)
-        o = _q(jnp.einsum("hqs,shk->qhk", p, v), (-2, -1), quant)
-        x = x + jnp.einsum("thk,hkd->td", o, _q(w["wo"], (0, 1), quant))
-        h = _q(_rms(x, w["norm2"], t.norm_eps), -1, quant)
-        a = jax.nn.silu(jnp.einsum("td,df->tf", h, _q(w["w_gate"], 0, quant)))
-        b = jnp.einsum("td,df->tf", h, _q(w["w_up"], 0, quant))
-        y = jnp.einsum("tf,fd->td", _q(a * b, -1, quant),
-                       _q(w["w_down"], 0, quant))
-        return x + y
-
-
 @functools.partial(jax.jit, static_argnums=(0, 5))
-def _readout(t: Tier, final_norm, unembed, h, tokens, quant):
+def _readout(norm_eps: float, final_norm, unembed, h, tokens, quant):
     """Logit statistics at selected rows h [N, d] (final rows of the
     trunk): the best logit, the logit of `tokens`, the argmax, and the
     eq.-8 negative entropy sum(p log p) of the next-token distribution."""
     with jax.default_matmul_precision("highest"):
-        x = _q(_rms(h, final_norm, t.norm_eps), -1, quant)
+        x = _q(_rms(h, final_norm, norm_eps), -1, quant)
         logits = jnp.einsum("nd,vd->nv", x, _q(unembed, 1, quant))
         logp = jax.nn.log_softmax(logits, -1)
         return {"best": logits.max(-1),
@@ -210,19 +215,22 @@ class Sequence_:
     tokens: np.ndarray
 
 
-def reference_readings(t: Tier, key, seqs: Sequence[Sequence_],
-                       served_dtype, quants: Sequence[Optional[str]] = (None,),
+def reference_readings(t, key, seqs: Sequence[Sequence_], served_dtype,
+                       quants: Sequence[Optional[str]],
+                       layer_leaves: tuple, layer: Callable,
                        rows: int = 256, pad: int = 256
                        ) -> List[Dict[str, List[np.ndarray]]]:
-    """Run the reference over each sequence (prompt + its tokens but the
+    """Run a reference over each sequence (prompt + its tokens but the
     last), padded at the end to a multiple of `pad` positions (causal, so
     the pad changes nothing before it), layer by layer with weights drawn
-    on the fly. For each `quant` in `quants` returns per-sequence arrays
-    over the emitted tokens' positions (at most `rows`): best logit, the
-    emitted token's logit, the argmax, the fp32 run's logit of that
-    argmax, and the negative entropy."""
+    on the fly: `layer(i, w, x, quant)` is layer i over one sequence
+    x [T, d], with w its `layer_leaves` in float32. `t` gives `n_layers`,
+    `norm_eps` and the leaves' shapes. For each `quant` in `quants`
+    returns per-sequence arrays over the emitted tokens' positions (at
+    most `rows`): best logit, the emitted token's logit, the argmax, the
+    fp32 run's logit of that argmax, and the negative entropy."""
     xs = {q: [] for q in quants}
-    emb = _global_weights(t, key, served_dtype, "embedding")
+    emb = global_weight(t, key, served_dtype, "embedding")
     for s in seqs:
         full = np.concatenate([s.prompt, s.tokens[:-1]]).astype(np.int32)
         if s.tokens.shape[0] > rows:
@@ -233,13 +241,13 @@ def reference_readings(t: Tier, key, seqs: Sequence[Sequence_],
         for q in quants:
             xs[q].append(jnp.take(emb, jnp.asarray(ids), axis=0))
     del emb
-    for layer in range(t.n_layers):
-        w = _layer_weights(t, key, served_dtype, layer)
+    for i in range(t.n_layers):
+        w = layer_weights(t, key, served_dtype, layer_leaves, i)
         for q in quants:
-            xs[q] = [_layer(t, w, x, q) for x in xs[q]]
+            xs[q] = [layer(i, w, x, q) for x in xs[q]]
         del w
-    fn = _global_weights(t, key, served_dtype, "final_norm")
-    un = _global_weights(t, key, served_dtype, "unembed")
+    fn = global_weight(t, key, served_dtype, "final_norm")
+    un = global_weight(t, key, served_dtype, "unembed")
     out, fp32_logits = [], []
     for q in quants:
         per = {"best": [], "chosen": [], "argmax": [], "ref_of_argmax": [],
@@ -250,7 +258,8 @@ def reference_readings(t: Tier, key, seqs: Sequence[Sequence_],
             at[:n] = np.arange(n) + s.prompt.shape[0] - 1
             tok = np.zeros(rows, np.int32)
             tok[:n] = s.tokens
-            r = _readout(t, fn, un, jnp.take(x, jnp.asarray(at), axis=0),
+            r = _readout(t.norm_eps, fn, un,
+                         jnp.take(x, jnp.asarray(at), axis=0),
                          jnp.asarray(tok), q)
             logits = r.pop("logits")
             if q is None:

@@ -6,11 +6,13 @@
 
 Everything a cell is made of is found by name: the cell in
 `BENCHMARK.json`, its configuration file (tiers, widths, serving
-settings, weights seed, limits), its traffic file (`traffic/<name>.json`,
-naming a mix in `mixes/`), and one reader per metric
-(`metrics/<name>.py`). The run builds the cascade from the
-configuration's weights seed, orders the mix's fixed requests by the run
-seed (`traffic.py`), warms up every shape its traffic uses,
+settings, weights seed, limits), each tier's architecture module
+(`archs/<arch>.py`: weights, the program's layout, the reference, the
+counts), its traffic file (`traffic/<name>.json`, naming a mix in
+`mixes/`), and one reader per metric (`metrics/<name>.py`). The run
+builds the cascade from the configuration's weights seed, orders the
+mix's fixed requests by the run seed (`traffic.py`), warms up every
+shape its traffic uses,
 serves the schedule through `ContinuousCascadeEngine.run`, reads the
 metrics (with `--trace 1` the per-layer ones, from a profiler trace of
 the middle of the window and the engine's audit log), checks the served
@@ -107,6 +109,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
 
     import correct
     import harness
+    import model
     import trace_reduce
     import traffic as traffic_lib
     import window
@@ -117,6 +120,7 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     bench_dir = root / bench["paths"][0]
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = json.loads((root / cfg_entry["file"]).read_text())
+    archs, tiers = model.load_tiers(bench_dir, cfg, cfg_entry["file"])
     devices = devices or jax.devices()[:cell["chips"]]
     peaks = json.loads((bench_dir / "peaks.json").read_text())
     kind = devices[0].device_kind
@@ -126,14 +130,14 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     enable_cache(root)
     compiles = harness.Compiles()
 
-    vocab = cfg["tiers"][0]["vocab_size"]
+    vocab = tiers[0].vocab_size
     schedule = traffic_lib.build_schedule(bench_dir, cell["traffic"], seed,
                                           seconds, vocab)
     log(f"schedule: {traffic_lib.describe(schedule)}, max_len "
         f"{schedule.max_len}")
     before = time.time() - t_start
     t0 = time.perf_counter()
-    prog = harness.build(cfg, cfg["weights_seed"])
+    prog = harness.build(cfg, archs, tiers, cfg["weights_seed"])
     for t in prog.tiers:
         log(f"tier {t.name}: {t.n_params / 1e9:.3f} B params")
     t1 = time.perf_counter()
@@ -187,9 +191,9 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
 
     run = types.SimpleNamespace(
         reqs=reqs, measured=measured, window=schedule.window, stats=stats,
-        schedule=schedule, tiers=prog.tiers, peak=peak, seconds=seconds,
-        n_chips=len(devices), setup_s=clock["setup_s"], trace=None,
-        trace_window=None, events=None, audit=None, cache={})
+        schedule=schedule, archs=prog.archs, tiers=prog.tiers, peak=peak,
+        seconds=seconds, n_chips=len(devices), setup_s=clock["setup_s"],
+        trace=None, trace_window=None, events=None, audit=None, cache={})
     result = {"correct": False, "attempted": counts["attempted"],
               "failed": counts["failed"], "metrics": {},
               "device": {"platform": devices[0].platform, "kind": kind,
@@ -231,9 +235,9 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     del reqs, run
     harness.free(prog)
     t_ref = time.perf_counter()
-    got = correct.readings(checked, prog.tiers, cfg["weights_seed"],
-                           harness.DTYPES[cfg["dtype"]], gate,
-                           control=control)
+    got = correct.readings(checked, prog.archs, prog.tiers,
+                           cfg["weights_seed"], harness.DTYPES[cfg["dtype"]],
+                           gate, control=control)
     log(f"reference over {len(checked)} requests "
         f"({sum(len(c.tokens) for c in checked)} served tokens, tiers "
         f"{sorted({c.tier for c in checked})}) in "

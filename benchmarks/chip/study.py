@@ -70,6 +70,7 @@ def main(argv=None) -> int:
         return 2
     import correct
     import harness
+    import model
     import run
     import traffic as traffic_lib
     import window
@@ -85,13 +86,14 @@ def main(argv=None) -> int:
         raise SystemExit(f"--runs spans configurations {sorted(configs)}")
     cfg_entry = next(c for c in bench["configs"] if c["name"] in configs)
     cfg = json.loads((ROOT / cfg_entry["file"]).read_text())
+    archs, tiers = model.load_tiers(bench_dir, cfg, cfg_entry["file"])
     run.enable_cache(ROOT)
     seeds = [int(s) for s in args.seeds.split(",")]
     faults = [f for f in args.fault.split(",") if f] or [None]
-    vocab = cfg["tiers"][0]["vocab_size"]
+    vocab = tiers[0].vocab_size
     sv = cfg["serving"]
     t0 = time.time()
-    prog = harness.build(cfg, cfg["weights_seed"])
+    prog = harness.build(cfg, archs, tiers, cfg["weights_seed"])
     first = traffic_lib.build_schedule(bench_dir, runs[0][0]["traffic"],
                                        seeds[0], args.seconds, vocab,
                                        runs[0][1])
@@ -139,7 +141,7 @@ def main(argv=None) -> int:
                     checked = correct.collect(reqs, measured)
                     t_ref = time.time()
                     out["readings"] = correct.readings(
-                        checked, prog.tiers, cfg["weights_seed"],
+                        checked, prog.archs, prog.tiers, cfg["weights_seed"],
                         harness.DTYPES[cfg["dtype"]], gate,
                         control=bool(args.control))
                     out["reference_s"] = time.time() - t_ref

@@ -1,9 +1,12 @@
-"""Cells are found by name, and the command refuses to run off the chip."""
+"""Cells and architectures are found by name, and the command refuses to
+run off the chip."""
 import json
 import os
 import pathlib
 import subprocess
 import sys
+
+import pytest
 
 import run
 import tinybench
@@ -54,10 +57,82 @@ def test_new_cell_from_added_files_only(tmp_path):
     assert reader(fake) == 4
 
 
+def _add_config(root, name, edit):
+    """A copy of the tiny configuration under `name`, changed by `edit`,
+    and a cell `<name>.rate` that runs it on the tiny traffic."""
+    bench_dir = root / "bench"
+    cfg = json.loads((bench_dir / "configs" / "tiny.x2.json").read_text())
+    cfg["name"] = name
+    edit(cfg)
+    (bench_dir / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    bench = run.load_bench(root)
+    bench["configs"].append(dict(bench["configs"][0], name=name,
+                                 file=f"bench/configs/{name}.json"))
+    bench["workloads"].append({"name": f"{name}.rate", "config": name,
+                               "traffic": "tiny.rate", "chips": 1,
+                               "why": "CPU test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return f"{name}.rate"
+
+
+@pytest.mark.parametrize("reference_biases", [True, False],
+                         ids=["sound", "reference_drops_biases"])
+def test_new_architecture_from_added_files_only(tmp_path, reference_biases):
+    """An architecture module (the dense block with learned Q/K/V biases)
+    and a configuration whose tiers name it, added as files with their
+    BENCHMARK.json entries, run as a cell: nothing else is edited. The
+    module's own reference decides `correct`: with the biases the run is
+    correct, and a reference that drops them fails served_gap."""
+    root = tinybench.make(tmp_path)
+    src = (HERE / "testdata" / "dense_qkv_bias.py").read_text()
+    if not reference_biases:
+        src = src.replace("REFERENCE_BIASES = True",
+                          "REFERENCE_BIASES = False")
+    (root / "bench" / "archs" / "dense_qkv_bias.py").write_text(src)
+
+    def biased(cfg):
+        for t in cfg["tiers"]:
+            t["arch"] = "dense_qkv_bias"
+
+    cell = _add_config(root, "tinyqkvb.x2", biased)
+    res = run.run_cell(root, cell, 2**31 + 21, 2.0, trace=False,
+                       log=lambda *_: None)
+    gap = res["checks"]["served_gap"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    if reference_biases:
+        assert res["correct"], res["checks"]
+    else:
+        assert not res["correct"] and gap["value"] > gap["limit"], \
+            res["checks"]
+
+
+@pytest.mark.parametrize("arch", [None, "no_such_arch"],
+                         ids=["no_arch", "missing_module"])
+def test_tier_without_an_architecture_stops_the_run(tmp_path, arch):
+    root = tinybench.make(tmp_path)
+
+    def edit(cfg):
+        cfg["tiers"][1].pop("arch")
+        if arch is not None:
+            cfg["tiers"][1]["arch"] = arch
+
+    cell = _add_config(root, "tinybad.x2", edit)
+    with pytest.raises(SystemExit) as e:
+        run.run_cell(root, cell, 3, 2.0, trace=False, log=lambda *_: None)
+    msg = str(e.value)
+    assert "bench/configs/tinybad.x2.json" in msg, msg
+    assert "tier 1 (tiny-large)" in msg, msg
+    assert (arch or "names no architecture") in msg, msg
+
+
 def test_every_benchmark_metric_has_a_reader():
     bench = run.load_bench(ROOT)
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert (HERE / "metrics" / f"{m['name']}.py").is_file(), m["name"]
+    for c in bench["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        for t in cfg["tiers"]:
+            assert (HERE / "archs" / f"{t['arch']}.py").is_file(), t
     for w in bench["workloads"]:
         assert (HERE / "traffic" / f"{w['traffic']}.json").is_file()
         for kind in ("end_to_end", "per_layer"):

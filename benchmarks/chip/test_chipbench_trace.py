@@ -77,14 +77,22 @@ def _reader(name):
     return mod.read
 
 
+# what each reader read on this trace and audit log while the harness
+# counted tier 0 itself (costs.py): the dense module must give the same
+READ_BEFORE = {"paged_attn_roofline.lat": 5.535180630786274,
+               "step_mfu.lat": 0.503260840592426}
+
+
 @pytest.mark.parametrize("name", ["paged_attn_roofline.lat",
                                   "step_mfu.lat"])
 def test_shares_of_peak_on_the_recorded_trace(name):
     """The recorded trace's two decode steps, at 16 rows writing positions
     near 1100 (as the audit log would give them): each share of a peak
-    lies in (0, 100], and a run with no trace or no steps reads nothing."""
+    lies in (0, 100] and reads as it did before the counts moved into the
+    dense architecture module, and a run with no trace or no steps reads
+    nothing."""
     cfg = json.loads((HERE / "configs" / "ilm2-1.8b.x2.json").read_text())
-    tier = model.Tier.from_json(cfg["tiers"][0])
+    archs, tiers = model.load_tiers(HERE, cfg, "ilm2-1.8b.x2.json")
     red = tr.reduce(tr.read_events(HERE / "testdata" / "trace_v5e.json.gz"))
     peaks = json.loads((HERE / "peaks.json").read_text())
     slots = list(range(16))
@@ -96,10 +104,12 @@ def test_shares_of_peak_on_the_recorded_trace(name):
     reqs = [types.SimpleNamespace(rid=s, prompt_len=1024 + 8 * s)
             for s in slots]
     run = types.SimpleNamespace(trace=red, audit=audit, reqs=reqs, cache={},
-                                trace_window=(0.9, 3.0), tiers=[tier],
+                                trace_window=(0.9, 3.0), archs=archs,
+                                tiers=tiers,
                                 peak=peaks["TPU v5 lite"], n_chips=1)
     read = _reader(name)
     assert 0.0 < read(run) <= 100.0
+    assert read(run) == pytest.approx(READ_BEFORE[name], rel=1e-12)
     assert read(types.SimpleNamespace(**dict(run.__dict__, trace=None))) \
         is None
     empty = dict(run.__dict__, trace_window=(5.0, 6.0), cache={})

@@ -1,6 +1,7 @@
 """A tiny cell for the CPU tests: the same files and layout as the real
-benchmark (BENCHMARK.json, a configuration, a mix, a traffic file and the
-metric readers), at widths a CPU runs in seconds."""
+benchmark (BENCHMARK.json, a configuration, a mix, a traffic file, the
+architecture modules and the metric readers), at widths a CPU runs in
+seconds."""
 from __future__ import annotations
 
 import json
@@ -9,8 +10,8 @@ import shutil
 
 HERE = pathlib.Path(__file__).resolve().parent
 
-TIER = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
-        "head_dim": 16, "d_ff": 128, "vocab_size": 512,
+TIER = {"arch": "dense", "n_layers": 2, "d_model": 64, "n_heads": 4,
+        "n_kv_heads": 2, "head_dim": 16, "d_ff": 128, "vocab_size": 512,
         "rope_theta": 10000.0, "norm_eps": 1e-5}
 CONFIG = {
     "name": "tiny.x2",
@@ -38,12 +39,13 @@ TRAFFIC = {"mix": "tiny", "arrival": "poisson", "rate_per_s": 4.0,
 
 def make(root: pathlib.Path, **overrides) -> pathlib.Path:
     """Write a benchmark root holding one tiny cell `tiny.x2.rate`; the
-    metric readers are the real ones, copied."""
+    architecture modules and the metric readers are the real ones,
+    copied."""
     bench_dir = root / "bench"
     for sub in ("configs", "mixes", "traffic"):
         (bench_dir / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(HERE / "metrics", bench_dir / "metrics",
-                    dirs_exist_ok=True)
+    for sub in ("archs", "metrics"):
+        shutil.copytree(HERE / sub, bench_dir / sub, dirs_exist_ok=True)
     shutil.copy(HERE / "peaks.json", bench_dir / "peaks.json")
     cfg = json.loads(json.dumps(CONFIG))
     for k, v in overrides.items():
