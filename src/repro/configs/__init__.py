@@ -23,6 +23,36 @@ class MoESpec:
     n_dense_layers: int = 1          # leading layers with dense FFN
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # experts this device holds, [first_held, first_held + n_held) of
+    # n_experts (expert parallelism: the rest live on other devices);
+    # None = every expert, on the sharded paths above
+    n_held: Optional[int] = None
+    first_held: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary embedding of one attention kind. `kind="yarn"` rescales the
+    frequencies as YaRN does (arXiv:2309.00071: interpolate the slow
+    dimensions by `factor`, keep the fast ones, ramp between the
+    `beta_fast`/`beta_slow` rotation counts over `original_max_positions`)
+    and multiplies cos and sin by `attention_factor`."""
+    theta: float
+    kind: str = "default"            # default | yarn
+    factor: float = 1.0
+    original_max_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer: its window (None = full causal) and
+    its rotary embedding (None = the config's `rope_theta`)."""
+    name: str
+    window: Optional[int] = None
+    rope: Optional[RopeSpec] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +100,10 @@ class ModelConfig:
     encoder: Optional[EncoderSpec] = None
     vision: Optional[VisionSpec] = None
     sliding_window: Optional[int] = None
+    # per-layer attention kinds, repeated with this period: layer i is of
+    # kind layer_kinds[i % len(layer_kinds)] (empty = every layer alike).
+    # Windowed kinds keep full-length caches and mask by position.
+    layer_kinds: Tuple[AttnKind, ...] = ()
     # runtime policy
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
@@ -128,6 +162,7 @@ ARCH_IDS = (
     "qwen1_5_4b",
     "internlm2_1_8b",
     "zamba2_1_2b",
+    "mellum2_12b_a2_5b",
 )
 
 # public ids (with dashes) -> module names
@@ -143,6 +178,7 @@ _ALIAS.update({
     "qwen1.5-4b": "qwen1_5_4b",
     "internlm2-1.8b": "internlm2_1_8b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 })
 
 
@@ -170,11 +206,19 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         param_dtype="float32", compute_dtype="float32",
     )
     if cfg.moe is not None:
+        held = cfg.moe.n_held is not None
         kw["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=2, d_ff_expert=64,
             n_shared_experts=min(cfg.moe.n_shared_experts, 1),
             shared_d_ff=64 if cfg.moe.n_shared_experts else None,
-            n_dense_layers=1)
+            n_dense_layers=min(cfg.moe.n_dense_layers, 1),
+            n_held=2 if held else None)
+    if cfg.layer_kinds:
+        # one period of kinds, windows cut to the small sizes
+        kw["n_layers"] = len(cfg.layer_kinds)
+        kw["layer_kinds"] = tuple(
+            dataclasses.replace(k, window=min(k.window, 16))
+            if k.window else k for k in cfg.layer_kinds)
     if cfg.mla is not None:
         kw["mla"] = MLASpec(q_lora=64, kv_lora=32, rope_dim=16, v_head_dim=32)
         kw["head_dim"] = 32

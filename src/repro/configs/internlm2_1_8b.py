@@ -15,5 +15,6 @@ CONFIG = ModelConfig(
     d_ff=8192,
     vocab_size=92544,
     rope_theta=1000000.0,
+    norm_eps=1e-5,
     source="arXiv:2403.17297",
 )

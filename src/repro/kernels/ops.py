@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.kernels import deferral_entropy as _de
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gatekeeper_loss as _gk
+from repro.kernels import moe_gmm as _moe
 from repro.kernels import paged_attention as _pa
 
 
@@ -86,15 +87,17 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_flash_decode_gqa(q, k_pages, v_pages, tables, positions, *,
-                           scale: Optional[float] = None,
+def paged_flash_decode_gqa(q, k_pages, v_pages, tables, positions,
+                           window=None, *, scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
     """One-token GQA attention directly against the block-paged cache
     (see paged_attention.py). q [B,1,H,hd]; k/v_pages [N, bs, KV, hd];
-    tables [B, M]; positions [B]. No dense gather is materialized."""
+    tables [B, M]; positions [B]; `window` (None, or a scalar that may
+    be traced) limits each row to positions in (pos - window, pos]. No
+    dense gather is materialized."""
     interpret = _default_interpret() if interpret is None else interpret
     return _pa.paged_flash_decode_gqa(q, k_pages, v_pages, tables,
-                                      positions, scale=scale,
+                                      positions, scale=scale, window=window,
                                       interpret=interpret)
 
 
@@ -110,6 +113,18 @@ def paged_flash_decode_mla(q_abs, q_rope, ckv_pages, kr_pages, kv_norm,
                                       kv_norm, tables, positions,
                                       scale=scale, eps=eps,
                                       interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def moe_gmm_decode(x, gates, w_gate, w_up, w_down, layer=None, *,
+                   interpret: Optional[bool] = None):
+    """Decode-step SwiGLU over the held experts that received a token
+    (see moe_gmm.py). x [T, d]; gates [T, n_held]; weights
+    [n_held, d, f] / [n_held, f, d], or stacked over layers with `layer`
+    picking one. Returns [T, d]."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _moe.moe_gmm_decode(x, gates, w_gate, w_up, w_down, layer,
+                               interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret",))

@@ -12,7 +12,8 @@ table is all zeros produce exact zeros.
 
 GQA — paged K/V `[n_blocks+1, block_size, KV, hd]`. The grid is one step
 per row; inside it a loop runs over the row's LIVE pages only (through its
-last mapped page at or before `pos // block_size`), `P` pages per compute
+last mapped page at or before `pos // block_size`, from the page holding
+`pos - window + 1` for a windowed layer), `P` pages per compute
 block (`gqa_pages_per_block`: ~256 KB of K per buffer, 8 pages of 16
 tokens at 8 KV heads of 128 in bf16). K and V stay in HBM; each live page
 is one contiguous `[block_size, KV*hd]` DMA into a double-buffered VMEM
@@ -95,7 +96,7 @@ def _fold(r, n: int):
     return r if r.shape[0] == n else r[:n] + r[n:]
 
 
-def _gqa_kernel(tbl_ref, pos_ref, live_ref, blk0_ref, nxt_ref,
+def _gqa_kernel(tbl_ref, pos_ref, first_ref, live_ref, blk0_ref, nxt_ref,
                 q_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
                 n_rows: int, M: int, P: int, bs: int, KV: int, group: int,
@@ -105,9 +106,10 @@ def _gqa_kernel(tbl_ref, pos_ref, live_ref, blk0_ref, nxt_ref,
     T = P * bs
 
     def page(row, blk, i):
-        """(page id, live) of page `i` of compute block `blk` of `row`:
+        """(page id, live) of page `i` of compute block `blk` of `row`
+        (blocks count from the page holding the row's first position):
         live = mapped and before the row's live-page count."""
-        col = blk * P + i
+        col = first_ref[row] // bs + blk * P + i
         pid = tbl_ref[row * M + jnp.minimum(col, M - 1)]
         return pid, (col < live_ref[row]) & (pid > 0)
 
@@ -148,7 +150,8 @@ def _gqa_kernel(tbl_ref, pos_ref, live_ref, blk0_ref, nxt_ref,
     qx = _hi_lo(jnp.concatenate(
         [jnp.where(kv_of == h, q, 0) for h in range(KV)], axis=1),
         kbuf.dtype)                                    # [(2)H, KV*hd]
-    n_blk = (live_ref[b] + P - 1) // P
+    lo = first_ref[b] // bs
+    n_blk = (jnp.maximum(live_ref[b] - lo, 0) + P - 1) // P
 
     def body(j, carry):
         slot = (blk0_ref[b] + j) % 2
@@ -167,14 +170,15 @@ def _gqa_kernel(tbl_ref, pos_ref, live_ref, blk0_ref, nxt_ref,
         s = _fold(jax.lax.dot_general(
             qx, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32), H) * scale    # [H, T]
-        # a token counts if it is at or before pos on a page copied for
+        # a token counts if it lies in [first, pos] on a page copied for
         # this block: the rest of the buffer holds stale pages or zeros
         tok = jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
         copied = jax.lax.fori_loop(
             0, P, lambda i, ok: jnp.where(
                 (tok // bs == i) & page(b, j, i)[1], 1, ok),
             jnp.zeros_like(tok))
-        mask = (j * T + tok <= pos) & (copied > 0)
+        at = (lo + j * P) * bs + tok
+        mask = (at <= pos) & (at >= first_ref[b]) & (copied > 0)
         s = jnp.where(mask, s, NEG)
         m_old = m_ref[...]
         m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
@@ -199,13 +203,18 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, tables: jnp.ndarray,
                            positions: jnp.ndarray, *,
                            scale: float | None = None,
+                           window=None,
                            interpret: bool = False) -> jnp.ndarray:
     """q [B,1,H,hd]; k/v_pages [n_blocks+1, block_size, KV, hd];
     tables [B, M] int32 (0 = unmapped); positions [B] int32. Returns
     [B,1,H,hd] — masked-softmax attention over each row's valid prefix,
     equal to the dense-gather path up to fp32 online-softmax rounding
     (bf16 pages with an fp32 q: ~1e-5 of a float64 reference). Rows
-    with no mapped page at or before pos return exact zeros."""
+    with no mapped page at or before pos return exact zeros. With a
+    `window` (an int, or a traced scalar), a row attends to positions in (pos - window, pos] only: its
+    walk starts at the page holding pos - window + 1, so it reads at most
+    ceil(window / block_size) + 1 pages, and the earlier positions of
+    that page are masked."""
     B, T, H, hd = q.shape
     assert T == 1
     N, bs, KV = k_pages.shape[:3]
@@ -221,7 +230,10 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
     col = jnp.arange(M, dtype=jnp.int32)
     mapped = (tables > 0) & (col[None, :] <= (positions // bs)[:, None])
     live = jnp.max(jnp.where(mapped, col + 1, 0), axis=1)
-    n_blk = (live + P - 1) // P
+    # first position a row attends to; its page starts the walk
+    first = (jnp.zeros_like(positions) if window is None else
+             jnp.maximum(positions - window + 1, 0).astype(jnp.int32))
+    n_blk = (jnp.maximum(live - first // bs, 0) + P - 1) // P
     blk0 = jnp.cumsum(n_blk) - n_blk
     # nxt[b]: the first row >= b with live pages (B if none); nxt[B] = B
     nxt = jax.lax.cummin(jnp.where(n_blk > 0, jnp.arange(B), B),
@@ -230,9 +242,10 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
     kernel = functools.partial(_gqa_kernel, n_rows=B, M=M, P=P, bs=bs,
                                KV=KV, group=group, scale=scale)
     row = pl.BlockSpec((1, 1, H, hd),
-                       lambda b, tbl, pos, live, blk0, nxt: (b, 0, 0, 0))
+                       lambda b, tbl, pos, first, live, blk0, nxt: (
+                           b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(B,),
         in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -254,7 +267,7 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables.reshape(-1), positions, live, blk0, nxt, q,
+    )(tables.reshape(-1), positions, first, live, blk0, nxt, q,
       k_pages.reshape(N, bs, KV * hd), v_pages.reshape(N, bs, KV * hd))
 
 

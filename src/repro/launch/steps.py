@@ -178,7 +178,7 @@ def _final_hidden(params, cfg: ModelConfig, batch, ctx: ParallelContext):
     extra = batch.get("patches")
     x = tfm._embed_inputs(params, cfg, batch["tokens"], extra, ctx)
     positions = jnp.arange(x.shape[1])[None, :]
-    x, _, aux = tfm._trunk(params, cfg, x, positions, ctx)
+    x, _, aux, _ = tfm._trunk(params, cfg, x, positions, ctx)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     mask = batch.get("loss_mask")
     if extra is not None:
@@ -299,8 +299,8 @@ def _decode_hidden(params, cfg, token, position, cache, ctx):
     if token.ndim == 1:
         token = token[:, None]
     x = tfm._embed_inputs(params, cfg, token, None, ctx)
-    x, new_cache, _ = tfm._trunk(params, cfg, x, None, ctx, cache=cache,
-                                 decode=True, position=position)
+    x, new_cache, _, _ = tfm._trunk(params, cfg, x, None, ctx, cache=cache,
+                                    decode=True, position=position)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x[:, 0, :], new_cache
 

@@ -16,7 +16,10 @@ Cache layouts (per layer-sliced leaf):
     the same per-row validity mask — so paged and dense attention compute
     identical masked softmaxes over the valid prefix.
   Paged mode is selected by passing `pages`; sliding-window ring caches
-  cannot be paged (serving.paged_pool rejects those configs).
+  cannot be paged (serving.paged_pool rejects those configs), but a
+  windowed layer over a full-length paged cache can: it keeps every page
+  and masks positions at or before `pos - window` (the paged kernel's
+  walk starts at the window's first page).
 
 Paged DECODE has two interchangeable implementations (same masked
 softmax, pinned by tests/test_paged_kernel.py):
@@ -47,8 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kernel_ops
-from repro.models.common import (ParamFactory, apply_rope, make_causal_mask,
-                                 make_sliding_mask, rms_norm)
+from repro.models.common import (ParamFactory, apply_rope, apply_rope_freqs,
+                                 make_causal_mask, make_sliding_mask,
+                                 rms_norm)
 from repro.sharding import ParallelContext
 
 NEG_INF = -1e30
@@ -117,6 +121,19 @@ def _paged_write(leaf: jnp.ndarray, pages: jnp.ndarray, tpos: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Standard GQA
 # ---------------------------------------------------------------------------
+
+def _rope_and_window(cfg: AttnConfig, layer: Optional[dict]):
+    """(rope fn, window) of one layer. `layer` carries a layer's kind as
+    traced values (models with per-layer attention kinds, scanned as one
+    stack): "window" (a full layer's exceeds any position), "inv_freq"
+    [head_dim/2] and "rope_scale"; else the config's own."""
+    if layer is None:
+        return (lambda t, p: apply_rope(t, p, cfg.rope_theta),
+                cfg.sliding_window)
+    return (lambda t, p: apply_rope_freqs(t, p, layer["inv_freq"],
+                                          layer["rope_scale"]),
+            layer["window"])
+
 
 def init_gqa(pf: ParamFactory, cfg: AttnConfig, stacked: int = 0) -> dict:
     L = (stacked,) if stacked else ()
@@ -228,7 +245,8 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
                 positions: jnp.ndarray, ctx: ParallelContext,
                 cache: Optional[dict] = None,
                 cache_offset=0,
-                pages: Optional[jnp.ndarray] = None
+                pages: Optional[jnp.ndarray] = None,
+                layer: Optional[dict] = None
                 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Full-sequence self-attention (training / prefill).
 
@@ -238,8 +256,11 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     chunk's start). With `pages` [B, M] the cache is block-paged
     ([n_blocks, block_size, ...] leaves): the chunk's K/V scatter through
     the page table and attention runs over the gathered logical view.
+    `layer` gives this layer's window and rope as traced values (see
+    `_rope_and_window`).
     """
     B, T, d = x.shape
+    rope, window = _rope_and_window(cfg, layer)
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"])
     v = jnp.einsum("btd,dhk->bthk", x, params["wv"])
@@ -247,8 +268,8 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
         q = q + params["bq"]
         k = k + params["bk"]
         v = v + params["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions)
+    k = rope(k, positions)
     q = ctx.constrain(q, ("batch", "seq", "heads", "head_dim"))
     k = ctx.constrain(k, ("batch", "kv_seq", "kv_heads", "head_dim"))
     v = ctx.constrain(v, ("batch", "kv_seq", "kv_heads", "head_dim"))
@@ -256,15 +277,19 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
 
     new_cache = None
     if pages is not None:
-        assert cache is not None and cfg.sliding_window is None, \
-            "paged caches do not support sliding-window attention"
+        # a windowed layer keeps every page of its row and masks the
+        # positions at or before pos - window
+        assert cache is not None
         tpos = cache_offset + jnp.arange(T)
         ck = _paged_write(cache["k"], pages, tpos, k)
         cv = _paged_write(cache["v"], pages, tpos, v)
         new_cache = {"k": ck, "v": cv}
         kk = gather_blocks(ck, pages)
         vv = gather_blocks(cv, pages)
-        mask = make_causal_mask(T, kk.shape[1], cache_offset)
+        if window is not None:
+            mask = make_sliding_mask(T, kk.shape[1], cache_offset, window)
+        else:
+            mask = make_causal_mask(T, kk.shape[1], cache_offset)
         out = _attend(q, kk.astype(q.dtype), vv.astype(q.dtype), mask, scale,
                       ctx, cfg.attn_chunk)
     elif cache is not None and cache["k"].shape[1] < T:
@@ -288,8 +313,8 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
             cache["v"], v.astype(cache["v"].dtype), (0, cache_offset, 0, 0))
         new_cache = {"k": ck, "v": cv}
         S = ck.shape[1]
-        if cfg.sliding_window:
-            mask = make_sliding_mask(T, S, cache_offset, cfg.sliding_window)
+        if window is not None:
+            mask = make_sliding_mask(T, S, cache_offset, window)
         else:
             mask = make_causal_mask(T, S, cache_offset)
         out = _attend(q, ck.astype(q.dtype), cv.astype(q.dtype), mask, scale,
@@ -297,8 +322,8 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     else:
         if not cfg.causal:
             mask = jnp.ones((T, T), bool)
-        elif cfg.sliding_window:
-            mask = make_sliding_mask(T, T, 0, cfg.sliding_window)
+        elif window is not None:
+            mask = make_sliding_mask(T, T, 0, window)
         else:
             mask = make_causal_mask(T, T, 0)
         out = _attend(q, k, v, mask, scale, ctx, cfg.attn_chunk)
@@ -357,7 +382,8 @@ def _flash_decode_sharded(q, ck, cv, mask, scale, ctx: ParallelContext):
 def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
                position, cache: dict, ctx: ParallelContext,
                pages: Optional[jnp.ndarray] = None,
-               paged_kernel: Optional[bool] = None
+               paged_kernel: Optional[bool] = None,
+               layer: Optional[dict] = None
                ) -> Tuple[jnp.ndarray, dict]:
     """One-token decode. x [B,1,d]; position is either a scalar int (whole
     batch at the same depth — the static serving engine) or an int vector
@@ -375,10 +401,14 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
 
     For sliding-window configs the cache is a ring buffer of size `window`;
     the write slot is position % window and relative order is handled by
-    the positional mask below. Ring caches cannot be paged.
+    the positional mask below. Ring caches cannot be paged; a paged
+    windowed layer masks positions at or before `pos - window`. `layer`
+    gives this layer's window and rope as traced values
+    (`_rope_and_window`); its cache is never a ring.
     """
     B, T, d = x.shape
     assert T == 1
+    rope, window = _rope_and_window(cfg, layer)
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"])
     v = jnp.einsum("btd,dhk->bthk", x, params["wv"])
@@ -389,21 +419,21 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     pos = jnp.asarray(position)
     per_row = pos.ndim == 1                    # [B] per-slot positions
     pos_bt = pos[:, None] if per_row else pos[None, None]   # [B,1] / [1,1]
-    q = apply_rope(q, pos_bt, cfg.rope_theta)
-    k = apply_rope(k, pos_bt, cfg.rope_theta)
+    q = rope(q, pos_bt)
+    k = rope(k, pos_bt)
     scale = 1.0 / np.sqrt(cfg.head_dim)
     if pages is not None:
-        assert per_row and cfg.sliding_window is None, \
-            "paged decode needs per-row positions and no sliding window"
+        assert per_row, "paged decode needs per-row positions"
         if kernel_ops.paged_kernel_enabled(paged_kernel):
             # Pallas path: in-kernel paged write + flash-decode walking
-            # the page table — no dense [B, M*bs, ...] view in HBM
+            # the page table — no dense [B, M*bs, ...] view in HBM; a
+            # windowed layer's walk starts at the window's first page
             ck = kernel_ops.paged_write_token(cache["k"], pages, pos,
                                               k[:, 0])
             cv = kernel_ops.paged_write_token(cache["v"], pages, pos,
                                               v[:, 0])
             out = kernel_ops.paged_flash_decode_gqa(q, ck, cv, pages, pos,
-                                                    scale=scale)
+                                                    window, scale=scale)
         else:
             # XLA fallback / parity reference: scatter + dense gather of
             # the (caller-tightened) active block prefix
@@ -411,9 +441,12 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
             cv = _paged_write(cache["v"], pages, pos[:, None], v[:, 0:1])
             kk = gather_blocks(ck, pages)
             vv = gather_blocks(cv, pages)
-            mask = (jnp.arange(kk.shape[1])[None, :]
-                    <= pos[:, None])[:, None, :]
-            out = _attend(q, kk.astype(q.dtype), vv.astype(q.dtype), mask,
+            idx = jnp.arange(kk.shape[1])[None, :]
+            mask = idx <= pos[:, None]
+            if window is not None:
+                mask = mask & (idx > pos[:, None] - window)
+            out = _attend(q, kk.astype(q.dtype), vv.astype(q.dtype),
+                          mask[:, None, :],
                           scale, ctx)
         y = jnp.einsum("bthk,hkd->btd", out, params["wo"],
                        preferred_element_type=jnp.float32).astype(x.dtype)
@@ -443,14 +476,14 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
             mask = (abs_pos >= 0)[None, :]                 # [1, S]
     elif per_row:
         mask = idx[None, :] <= pos[:, None]                # [B, S]
-        if cfg.sliding_window:
-            mask = mask & (idx[None, :] > pos[:, None] - cfg.sliding_window)
+        if window is not None:
+            mask = mask & (idx[None, :] > pos[:, None] - window)
         mask = mask[:, None, :]                            # [B, 1, S]
     else:
         mask = (idx <= pos)[None, :]
-        if cfg.sliding_window:
+        if window is not None:
             # linear cache larger than the window: restrict attendance
-            mask = mask & (idx > pos - cfg.sliding_window)[None, :]
+            mask = mask & (idx > pos - window)[None, :]
     out = None
     if ctx.mesh is not None:
         out = _flash_decode_sharded(q, ck.astype(q.dtype),

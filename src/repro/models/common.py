@@ -8,6 +8,7 @@ in one of two modes through `ParamFactory`:
 """
 from __future__ import annotations
 
+import math
 import zlib
 from typing import Optional, Sequence
 
@@ -110,14 +111,58 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
     return 1.0 / (theta ** exponents)                       # [head_dim/2]
 
 
+def yarn_frequencies(head_dim: int, spec) -> np.ndarray:
+    """YaRN inverse frequencies [head_dim/2] (arXiv:2309.00071, as
+    `transformers`' `_compute_yarn_parameters` with truncation): dimension
+    i keeps its frequency where it turns more than `beta_fast` times over
+    `original_max_positions`, is divided by `factor` where it turns fewer
+    than `beta_slow` times, and is blended linearly in between."""
+    d, base, L = head_dim, spec.theta, spec.original_max_positions
+
+    def dim_of(rotations):
+        return d * math.log(L / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    lo = max(math.floor(dim_of(spec.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(spec.beta_slow)), d - 1)
+    if lo == hi:
+        hi += 0.001
+    pos_freqs = base ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    ramp = np.clip((np.arange(d // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1: extrapolate (keep as is)
+    inv = (1.0 / (spec.factor * pos_freqs)) * (1 - keep) + (
+        1.0 / pos_freqs) * keep
+    return inv.astype(np.float32)
+
+
+def rope_inv_freq(head_dim: int, theta: float = 10000.0, spec=None):
+    """(inverse frequencies [head_dim/2] float32, cos/sin scale) of a
+    rotary embedding: `spec` (a `configs.RopeSpec`) overrides `theta`; a
+    YaRN spec rescales the frequencies and scales cos and sin by its
+    attention factor."""
+    if spec is not None and spec.kind == "yarn":
+        return yarn_frequencies(head_dim, spec), float(spec.attention_factor)
+    th = theta if spec is None else spec.theta
+    return (1.0 / (th ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                          / head_dim))).astype(np.float32), 1.0
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
                theta: float = 10000.0) -> jnp.ndarray:
     """x: [..., T, H, D]; positions: broadcastable to [..., T]."""
+    return apply_rope_freqs(x, positions, rope_frequencies(x.shape[-1], theta))
+
+
+def apply_rope_freqs(x: jnp.ndarray, positions: jnp.ndarray, freqs,
+                     scale=1.0) -> jnp.ndarray:
+    """Rotate the halves of x [..., T, H, D] by positions * freqs [D/2]
+    (may be traced: per-layer rope), cos and sin scaled by `scale`."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                      # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs   # [..., T, D/2]
     cos = jnp.cos(angles)[..., None, :]                     # [..., T, 1, D/2]
     sin = jnp.sin(angles)[..., None, :]
+    if not (isinstance(scale, float) and scale == 1.0):
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -19,6 +19,15 @@ MoE design (TPU-adapted, MaxText-style, FLOP-honest):
 
 Capacity overflow drops assignments (standard GShard semantics, gates NOT
 renormalized); the router aux load-balance loss keeps overflow rare.
+
+Held experts (`MoEConfig.n_held`, one device's share of an expert-parallel
+deployment served with no exchange): the layer routes every token over
+all `n_experts` and computes only the contributions of the experts it
+holds, with no capacity and so no drop. Its output is one share of the
+layer: the shares of all devices sum to the whole layer. The decode step
+runs the Pallas grouped matmul (`kernels/moe_gmm.py`, op
+`moe_gmm_decode`), which reads only the held experts that received a
+token; prefill and the XLA fallback compute every held expert densely.
 """
 from __future__ import annotations
 
@@ -51,6 +60,10 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     normalize_gates: bool = True   # renormalize top-k gates to sum 1
+    # experts this device holds, [first_held, first_held + n_held); None =
+    # every expert (the paths above). Routing always runs over n_experts.
+    n_held: Optional[int] = None
+    first_held: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -95,6 +108,7 @@ def init_moe(pf: ParamFactory, cfg: MoEConfig, stacked: int = 0) -> dict:
     L = (stacked,) if stacked else ()
     LA = ("layers",) if stacked else ()
     d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    Eh = E if cfg.n_held is None else cfg.n_held    # expert weights held
     p = {
         "router": pf.param("router", L + (d, E), LA + ("embed", "experts"),
                            fan_in=d, dtype=jnp.float32),
@@ -102,13 +116,13 @@ def init_moe(pf: ParamFactory, cfg: MoEConfig, stacked: int = 0) -> dict:
         # re-ruled independently of dense params (hillclimb: "gather tokens,
         # not weights" at decode). Defaults reproduce the old
         # embed->data / ffn->() sharding exactly.
-        "w_gate": pf.param("we_gate", L + (E, d, f),
+        "w_gate": pf.param("we_gate", L + (Eh, d, f),
                            LA + ("experts", "expert_embed", "expert_ffn"),
                            fan_in=d),
-        "w_up": pf.param("we_up", L + (E, d, f),
+        "w_up": pf.param("we_up", L + (Eh, d, f),
                          LA + ("experts", "expert_embed", "expert_ffn"),
                          fan_in=d),
-        "w_down": pf.param("we_down", L + (E, f, d),
+        "w_down": pf.param("we_down", L + (Eh, f, d),
                            LA + ("experts", "expert_ffn", "expert_embed"),
                            fan_in=f),
     }
@@ -133,6 +147,66 @@ def _route(router_w: jnp.ndarray, x2d: jnp.ndarray, cfg: MoEConfig):
     frac_prob = probs.mean(axis=0)
     aux = E * jnp.sum(frac_tokens * frac_prob)
     return gates, idx.astype(jnp.int32), aux
+
+
+def held_gates(router_w: jnp.ndarray, x2d: jnp.ndarray, cfg: MoEConfig,
+               row_mask: Optional[jnp.ndarray] = None):
+    """Route every token over all experts; return (gates [T, n_held] fp32
+    of the held experts, 0 where a token is not routed to one, aux loss).
+    Rows where `row_mask` is False (inactive decode slots) get no gate."""
+    gates, idx, aux = _route(router_w, x2d, cfg)
+    local = idx - cfg.first_held                            # [T, k]
+    onehot = (local[..., None] == jnp.arange(cfg.n_held)).astype(
+        jnp.float32)                                        # [T, k, n_held]
+    g = jnp.einsum("tk,tke->te", gates, onehot)
+    if row_mask is not None:
+        g = jnp.where(row_mask[:, None], g, 0.0)
+    return g, aux
+
+
+def held_expert_ffn(x2d: jnp.ndarray, gates: jnp.ndarray, w_gate, w_up,
+                    w_down) -> jnp.ndarray:
+    """XLA path of the held-expert layer: every held expert over every
+    token, each scaled by its gate (zero when not routed). Exact, with no
+    capacity; computes n_held experts per token."""
+    a = jnp.einsum("td,edf->etf", x2d, w_gate,
+                   preferred_element_type=jnp.float32)
+    b = jnp.einsum("td,edf->etf", x2d, w_up,
+                   preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(a) * b * gates.T[..., None]).astype(w_down.dtype)
+    y = jnp.einsum("etf,efd->td", h, w_down,
+                   preferred_element_type=jnp.float32)
+    return y.astype(x2d.dtype)
+
+
+def moe_held_forward(params: dict, cfg: MoEConfig, x: jnp.ndarray,
+                     row_mask: Optional[jnp.ndarray] = None,
+                     kernel: bool = False, layer=None):
+    """The held experts' share of the MoE layer. x [B, T, d]; `row_mask`
+    [B] marks live rows (decode); `kernel` runs the Pallas grouped matmul
+    (one-token decode). With `layer` (may be traced) the expert leaves of
+    `params` are every layer's, stacked, and `layer` picks this one (a
+    layer scan passes them whole, so the kernel reads them in place).
+    Returns (y, aux, stats) with stats int32 [hit, assign]: held experts
+    with at least one token, and assignments to held experts, over the
+    live rows."""
+    from repro.kernels import ops as kernel_ops
+    assert cfg.n_shared_experts == 0, "held experts with a shared expert"
+    B, T, d = x.shape
+    x2d = x.reshape(B * T, d)
+    rm = None if row_mask is None else jnp.repeat(row_mask, T)
+    gates, aux = held_gates(params["router"], x2d, cfg, rm)
+    routed = gates > 0
+    stats = jnp.stack([jnp.any(routed, axis=0).sum(),
+                       routed.sum()]).astype(jnp.int32)
+    w = [params[k] for k in ("w_gate", "w_up", "w_down")]
+    if kernel:
+        y = kernel_ops.moe_gmm_decode(x2d, gates, *w, layer)
+    else:
+        if layer is not None:
+            w = [a[layer] for a in w]
+        y = held_expert_ffn(x2d, gates, *w)
+    return y.reshape(B, T, d), aux, stats
 
 
 def _expert_ffn(xs: jnp.ndarray, w_gate, w_up, w_down,

@@ -21,13 +21,16 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import ModelConfig
+from repro.kernels import ops as kernel_ops
 from repro.models import attention as attn_lib
 from repro.models import ffn as ffn_lib
 from repro.models import ssm as ssm_lib
 from repro.models.common import (ParamFactory, embed_tokens, init_embedding,
-                                 init_rms_norm, rms_norm, unembed)
+                                 init_rms_norm, rms_norm, rope_inv_freq,
+                                 unembed)
 from repro.sharding import ParallelContext
 
 
@@ -63,7 +66,31 @@ def moe_config(cfg: ModelConfig) -> ffn_lib.MoEConfig:
         d_model=cfg.d_model, d_ff=m.d_ff_expert, n_experts=m.n_experts,
         top_k=m.top_k, n_shared_experts=m.n_shared_experts,
         shared_d_ff=m.shared_d_ff, capacity_factor=m.capacity_factor,
-        router_aux_weight=m.router_aux_weight)
+        router_aux_weight=m.router_aux_weight, n_held=m.n_held,
+        first_held=m.first_held)
+
+
+def held_experts(cfg: ModelConfig) -> bool:
+    """Does every MoE layer hold only a share of the experts (and report
+    per-layer expert counters from its decode step)?"""
+    return cfg.moe is not None and cfg.moe.n_held is not None
+
+
+FULL_WINDOW = 1 << 30       # the traced window of a layer with none
+
+
+def layer_kind_arrays(cfg: ModelConfig, lo: int, hi: int) -> dict:
+    """Layers [lo, hi)'s attention kinds (`cfg.layer_kinds`, repeated) as
+    stacked arrays, scanned beside the layers' weights: "window" [n]
+    int32 (FULL_WINDOW for a full layer), "inv_freq" [n, head_dim/2] and
+    "rope_scale" [n] float32."""
+    kinds = [cfg.layer_kinds[i % len(cfg.layer_kinds)] for i in range(lo, hi)]
+    ropes = [rope_inv_freq(cfg.head_dim, cfg.rope_theta, k.rope)
+             for k in kinds]
+    return {"window": jnp.asarray([k.window or FULL_WINDOW for k in kinds],
+                                  jnp.int32),
+            "inv_freq": jnp.asarray(np.stack([f for f, _ in ropes])),
+            "rope_scale": jnp.asarray([c for _, c in ropes], jnp.float32)}
 
 
 def rwkv_config(cfg: ModelConfig) -> ssm_lib.RWKVConfig:
@@ -213,49 +240,65 @@ def _maybe_remat(fn, cfg: ModelConfig):
 
 def _attn_ffn_block(p, cfg: ModelConfig, x, positions, ctx,
                     cache=None, cache_offset=0, decode=False, position=None,
-                    ffn_kind="mlp", pages=None, paged_kernel=None):
+                    ffn_kind="mlp", pages=None, paged_kernel=None,
+                    layer=None, row_mask=None, experts=None):
     """One pre-norm transformer block (attention or MLA + dense/MoE FFN).
-    Returns (x, new_cache, aux). `pages` selects the block-paged cache
-    layout; `paged_kernel` the Pallas-vs-XLA paged decode implementation
-    (see models.attention)."""
+    Returns (x, new_cache, aux, stats). `pages` selects the block-paged
+    cache layout; `paged_kernel` the Pallas-vs-XLA paged decode
+    implementation (see models.attention) and, with held experts, the
+    decode step's grouped-matmul kernel. `layer` holds the layer's
+    attention kind as traced values (`layer_kind_arrays`); `row_mask` [B]
+    marks live decode rows. A held-expert layer takes every layer's
+    expert weights stacked (`experts`) and its index (`p["layer"]`).
+    `stats` is int32 [hit, assign] of a held-expert layer
+    (`ffn.moe_held_forward`), else zeros."""
     ac = attn_config(cfg)
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    kw = {} if layer is None else {"layer": layer}
     if decode:
         fwd = attn_lib.mla_decode if cfg.mla else attn_lib.gqa_decode
         y, new_cache = fwd(p["attn"], ac, h, position, cache, ctx,
-                           pages=pages, paged_kernel=paged_kernel)
+                           pages=pages, paged_kernel=paged_kernel, **kw)
     else:
         fwd = attn_lib.mla_forward if cfg.mla else attn_lib.gqa_forward
         y, new_cache = fwd(p["attn"], ac, h, positions, ctx, cache,
-                           cache_offset, pages=pages)
+                           cache_offset, pages=pages, **kw)
     x = x + y
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if ffn_kind == "moe":
+    stats = jnp.zeros((2,), jnp.int32)
+    if ffn_kind == "moe" and held_experts(cfg):
+        kernel = decode and kernel_ops.paged_kernel_enabled(paged_kernel)
+        y, aux, stats = ffn_lib.moe_held_forward(
+            {**p["moe"], **experts}, moe_config(cfg), h, row_mask,
+            kernel=kernel, layer=p["layer"])
+    elif ffn_kind == "moe":
         y, aux = ffn_lib.moe_forward(p["moe"], moe_config(cfg), h, ctx,
                                      decode=decode)
     else:
         y = ffn_lib.mlp_forward(p["mlp"], mlp_config(cfg), h, ctx)
-    return x + y, new_cache, aux
+    return x + y, new_cache, aux, stats
 
 
 def _scan_group(block_fn, stacked_params, x, stacked_cache, cfg: ModelConfig):
     """Fold a homogeneous stacked group. block_fn(p_layer, x, cache_layer) ->
-    (x, new_cache_layer, aux). Returns (x, new_stacked_cache, aux_sum)."""
+    (x, new_cache_layer, aux, stats). Returns (x, new_stacked_cache,
+    aux_sum, stats stacked per layer)."""
     if not cfg.scan_layers:
         n = jax.tree.leaves(stacked_params)[0].shape[0]
         blk = _maybe_remat(block_fn, cfg)
-        caches, aux_sum = [], jnp.zeros((), jnp.float32)
+        caches, stats, aux_sum = [], [], jnp.zeros((), jnp.float32)
         for i in range(n):
             p_i = jax.tree.map(lambda a, i=i: a[i], stacked_params)
             c_i = (None if stacked_cache is None
                    else jax.tree.map(lambda a, i=i: a[i], stacked_cache))
-            x, nc, aux = blk(p_i, x, c_i)
+            x, nc, aux, st = blk(p_i, x, c_i)
             caches.append(nc)
+            stats.append(st)
             aux_sum = aux_sum + aux
         new_cache = (None if stacked_cache is None else
                      jax.tree.map(lambda *ls: jnp.stack(ls), *caches))
-        return x, new_cache, aux_sum
+        return x, new_cache, aux_sum, jnp.stack(stats)
 
     def body(carry, xs):
         x, aux_sum = carry
@@ -263,14 +306,15 @@ def _scan_group(block_fn, stacked_params, x, stacked_cache, cfg: ModelConfig):
             p_layer, c_layer = xs, None
         else:
             p_layer, c_layer = xs
-        x, new_c, aux = block_fn(p_layer, x, c_layer)
-        return (x, aux_sum + aux), new_c
+        x, new_c, aux, st = block_fn(p_layer, x, c_layer)
+        return (x, aux_sum + aux), (new_c, st)
 
     wrapped = _maybe_remat(body, cfg)
     xs = stacked_params if stacked_cache is None else (stacked_params,
                                                        stacked_cache)
-    (x, aux_sum), new_cache = jax.lax.scan(wrapped, (x, jnp.zeros((), jnp.float32)), xs)
-    return x, new_cache, aux_sum
+    (x, aux_sum), (new_cache, stats) = jax.lax.scan(
+        wrapped, (x, jnp.zeros((), jnp.float32)), xs)
+    return x, new_cache, aux_sum, stats
 
 
 # ---------------------------------------------------------------------------
@@ -279,41 +323,66 @@ def _scan_group(block_fn, stacked_params, x, stacked_cache, cfg: ModelConfig):
 
 def _trunk(params, cfg: ModelConfig, x, positions, ctx,
            cache=None, cache_offset=0, decode=False, position=None,
-           pages=None, paged_kernel=None):
-    """Runs all layer groups. x [B,T,d] embeddings. Returns (x, cache, aux).
-    `pages` [B, M] routes attention caches through a page table (the
-    physical block storage is shared by value, the table by structure:
-    every stacked layer's leaf is indexed by the same table);
-    `paged_kernel` selects the Pallas paged-decode kernels per layer."""
+           pages=None, paged_kernel=None, row_mask=None):
+    """Runs all layer groups. x [B,T,d] embeddings. Returns (x, cache, aux,
+    stats). `pages` [B, M] routes attention caches through a page table
+    (the physical block storage is shared by value, the table by
+    structure: every stacked layer's leaf is indexed by the same table);
+    `paged_kernel` selects the Pallas paged-decode kernels per layer.
+    With `cfg.layer_kinds` each layer's kind rides the scan beside its
+    weights (`layer_kind_arrays`). `stats` is {} or, with held experts,
+    {"moe": int32 [n_moe_layers, 2]} (hit, assign per layer)."""
     blocks = params["blocks"]
     new_cache: Dict[str, Any] = {}
     aux_total = jnp.zeros((), jnp.float32)
     groups = _group_sizes(cfg)
+    stats: Dict[str, Any] = {}
 
+    lo = 0
     for kind in ("dense", "moe"):
         if kind not in blocks or not groups.get(kind):
             continue
-        def block_fn(p, x_, c_, _kind=kind):
+        n = groups[kind]
+        kinds = (layer_kind_arrays(cfg, lo, lo + n) if cfg.layer_kinds
+                 else None)
+        lo += n
+        xs, experts = blocks[kind], None
+        if kind == "moe" and held_experts(cfg):
+            # held experts stay stacked outside the scan (a slice per
+            # layer would be copied before the kernel reads it); the scan
+            # carries each layer's index instead
+            m = dict(xs["moe"])
+            experts = {k: m.pop(k) for k in ("w_gate", "w_up", "w_down")}
+            xs = {**xs, "moe": m, "layer": jnp.arange(n)}
+
+        def block_fn(p, x_, c_, _kind=kind, _experts=experts):
+            p, layer = p if cfg.layer_kinds else (p, None)
             return _attn_ffn_block(p, cfg, x_, positions, ctx, c_,
                                    cache_offset, decode, position,
                                    ffn_kind=("moe" if _kind == "moe" else "mlp"),
-                                   pages=pages, paged_kernel=paged_kernel)
+                                   pages=pages, paged_kernel=paged_kernel,
+                                   layer=layer, row_mask=row_mask,
+                                   experts=_experts)
         c = cache.get(kind) if cache is not None else None
-        x, nc, aux = _scan_group(block_fn, blocks[kind], x, c, cfg)
+        xs = (xs, kinds) if cfg.layer_kinds else xs
+        x, nc, aux, st = _scan_group(block_fn, xs, x, c, cfg)
         if nc is not None:
             new_cache[kind] = nc
+        if kind == "moe" and held_experts(cfg):
+            stats["moe"] = st
         aux_total = aux_total + aux
 
     if "rwkv" in blocks:
         rc = rwkv_config(cfg)
         def rwkv_fn(p, x_, c_):
             x_, st = ssm_lib.rwkv_block_forward(p, rc, x_, ctx, c_)
-            return x_, st, jnp.zeros((), jnp.float32)
+            return x_, st, jnp.zeros((), jnp.float32), jnp.zeros((2,),
+                                                                 jnp.int32)
         c = cache.get("rwkv") if cache is not None else None
         if c is None:   # states are mandatory carries; make fresh ones
             c = ssm_lib.init_rwkv_state(rc, x.shape[0], x.dtype,
                                         stacked=groups["rwkv"])
-        x, nc, _ = _scan_group(rwkv_fn, blocks["rwkv"], x, c, cfg)
+        x, nc, _, _ = _scan_group(rwkv_fn, blocks["rwkv"], x, c, cfg)
         new_cache["rwkv"] = nc
 
     if "mamba" in blocks:
@@ -322,12 +391,13 @@ def _trunk(params, cfg: ModelConfig, x, positions, ctx,
         every = cfg.shared_attn_every
         def mamba_fn(p, x_, c_):
             x_, st = ssm_lib.mamba2_block_forward(p, mc, x_, ctx, c_)
-            return x_, st, jnp.zeros((), jnp.float32)
+            return x_, st, jnp.zeros((), jnp.float32), jnp.zeros((2,),
+                                                                 jnp.int32)
         c = cache.get("mamba") if cache is not None else None
         if c is None:
             c = ssm_lib.init_mamba2_state(mc, x.shape[0], x.dtype, stacked=n)
         if not every:
-            x, nc, _ = _scan_group(mamba_fn, blocks["mamba"], x, c, cfg)
+            x, nc, _, _ = _scan_group(mamba_fn, blocks["mamba"], x, c, cfg)
             new_cache["mamba"] = nc
         else:
             # zamba2: super-blocks of `every` mamba layers + SHARED attn block
@@ -341,12 +411,12 @@ def _trunk(params, cfg: ModelConfig, x, positions, ctx,
                 p_g = jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi],
                                    blocks["mamba"])
                 c_g = jax.tree.map(lambda a, lo=lo, hi=hi: a[lo:hi], c)
-                x, nc_g, _ = _scan_group(mamba_fn, p_g, x, c_g, cfg)
+                x, nc_g, _, _ = _scan_group(mamba_fn, p_g, x, c_g, cfg)
                 mamba_new.append(nc_g)
                 if g < n_inv:
                     c_sa = (None if sa_cache is None else
                             jax.tree.map(lambda a, g=g: a[g], sa_cache))
-                    x, nc_sa, _ = _attn_ffn_block(
+                    x, nc_sa, _, _ = _attn_ffn_block(
                         blocks["shared_attn"], sa_cfg, x, positions, ctx,
                         c_sa, cache_offset, decode, position, ffn_kind="mlp")
                     if nc_sa is not None:
@@ -356,7 +426,7 @@ def _trunk(params, cfg: ModelConfig, x, positions, ctx,
             if sa_new:
                 new_cache["shared_attn"] = jax.tree.map(
                     lambda *ls: jnp.stack(ls), *sa_new)
-    return x, (new_cache if new_cache else None), aux_total
+    return x, (new_cache if new_cache else None), aux_total, stats
 
 
 # ---------------------------------------------------------------------------
@@ -385,7 +455,7 @@ def forward(params, cfg: ModelConfig, tokens, ctx: ParallelContext,
     x = _embed_inputs(params, cfg, tokens, extra_embeds, ctx)
     B, T, _ = x.shape
     positions = jnp.arange(T)[None, :]
-    x, _, aux = _trunk(params, cfg, x, positions, ctx)
+    x, _, aux, _ = _trunk(params, cfg, x, positions, ctx)
     logits = _logits(params, cfg, x, ctx)
     if return_aux:
         return logits, aux
@@ -414,8 +484,8 @@ def prefill(params, cfg: ModelConfig, tokens, cache, ctx: ParallelContext,
     x = _embed_inputs(params, cfg, tokens, extra_embeds, ctx)
     B, T, _ = x.shape
     positions = jnp.arange(T)[None, :] + cache_offset
-    x, new_cache, _ = _trunk(params, cfg, x, positions, ctx, cache=cache,
-                             cache_offset=cache_offset, pages=pages)
+    x, new_cache, _, _ = _trunk(params, cfg, x, positions, ctx, cache=cache,
+                                cache_offset=cache_offset, pages=pages)
     if last_index is not None:
         idx = jnp.broadcast_to(jnp.asarray(last_index), (B,))
         x = x[jnp.arange(B), idx][:, None, :]
@@ -425,21 +495,28 @@ def prefill(params, cfg: ModelConfig, tokens, cache, ctx: ParallelContext,
 
 
 def decode_step(params, cfg: ModelConfig, token, position, cache,
-                ctx: ParallelContext, pages=None, paged_kernel=None):
+                ctx: ParallelContext, pages=None, paged_kernel=None,
+                row_mask=None, with_stats: bool = False):
     """One-token decode. token [B] or [B,1]; position scalar OR int vector
     [B] of per-row decode depths (continuous batching over a slot pool —
     each row attends/writes at its own position). `pages` [B, M] routes
     the per-row cache access through a page table (block-paged backend;
     requires vector positions); `paged_kernel` picks the Pallas paged
     flash-decode kernels over the XLA gather fallback (None = backend
-    default). Returns (logits [B, V], cache)."""
+    default). `row_mask` [B] marks live rows: held experts route only
+    those. Returns (logits [B, V], cache), and with `with_stats` also the
+    step's per-layer counters (`_trunk`)."""
     if token.ndim == 1:
         token = token[:, None]
     x = _embed_inputs(params, cfg, token, None, ctx)
     pos = jnp.asarray(position)
     positions = pos[:, None] if pos.ndim == 1 else jnp.full((1, 1), position)
-    x, new_cache, _ = _trunk(params, cfg, x, positions, ctx, cache=cache,
-                             decode=True, position=position, pages=pages,
-                             paged_kernel=paged_kernel)
+    x, new_cache, _, stats = _trunk(params, cfg, x, positions, ctx,
+                                    cache=cache, decode=True,
+                                    position=position, pages=pages,
+                                    paged_kernel=paged_kernel,
+                                    row_mask=row_mask)
     logits = _logits(params, cfg, x, ctx)
+    if with_stats:
+        return logits[:, 0, :], new_cache, stats
     return logits[:, 0, :], new_cache

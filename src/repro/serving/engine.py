@@ -89,6 +89,14 @@ def _neg_entropy(logits: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(jnp.exp(logp) * logp, axis=-1)
 
 
+def _zero_moe(state: dict) -> dict:
+    """A dispatch's held-expert counters start from zero (dense configs
+    carry none)."""
+    if "moe" not in state:
+        return state
+    return {**state, "moe": jnp.zeros_like(state["moe"])}
+
+
 @dataclasses.dataclass
 class ServeResult:
     """Static-engine output batch (rid order == input row order)."""
@@ -567,10 +575,20 @@ class ContinuousCascadeEngine:
         n_slots = state["active"].shape[0]
         if pages is not None:
             pages = jnp.where(state["active"][:, None], pages, 0)
-        logits, cache = tfm.decode_step(params, cfg, state["last_tok"],
-                                        state["pos"], cache, ctx,
-                                        pages=pages,
-                                        paged_kernel=paged_kernel)
+        extra = {}
+        if "moe" in state:
+            # held experts: route live rows only and count, per layer,
+            # the held experts hit and the assignments they took
+            logits, cache, stats = tfm.decode_step(
+                params, cfg, state["last_tok"], state["pos"], cache, ctx,
+                pages=pages, paged_kernel=paged_kernel,
+                row_mask=state["active"], with_stats=True)
+            extra["moe"] = state["moe"] + stats["moe"]
+        else:
+            logits, cache = tfm.decode_step(params, cfg, state["last_tok"],
+                                            state["pos"], cache, ctx,
+                                            pages=pages,
+                                            paged_kernel=paged_kernel)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         neg_ent = _neg_entropy(logits)
         act = state["active"]
@@ -588,6 +606,7 @@ class ContinuousCascadeEngine:
             "active": act & (n_gen < state["budget"]),
             "tokens": state["tokens"].at[rows, col].set(
                 jnp.where(act, tok, cur)),
+            **extra,
         }
         return cache, state
 
@@ -609,6 +628,7 @@ class ContinuousCascadeEngine:
             cache = scatter_rows(cache, fresh, slots, batch_axes)
             row0 = jnp.zeros((k, max_new), jnp.int32).at[:, 0].set(tok0)
             state = {
+                **state,
                 "last_tok": state["last_tok"].at[slots].set(tok0),
                 "pos": state["pos"].at[slots].set(P),
                 "n_gen": state["n_gen"].at[slots].set(1),
@@ -620,6 +640,8 @@ class ContinuousCascadeEngine:
             return cache, state
 
         def step_fn(params, cache, state):
+            state = _zero_moe(state)
+
             def one(carry, _):
                 params, cache, state = carry
                 cache, state = self._decode_body(params, cache, state,
@@ -655,6 +677,7 @@ class ContinuousCascadeEngine:
             conf0 = _neg_entropy(logits)[0]
             row0 = jnp.zeros((max_new,), jnp.int32).at[0].set(tok0)
             return {
+                **state,
                 "last_tok": state["last_tok"].at[slot].set(tok0),
                 "pos": state["pos"].at[slot].set(prompt_len),
                 "n_gen": state["n_gen"].at[slot].set(1),
@@ -665,6 +688,8 @@ class ContinuousCascadeEngine:
             }
 
         def step_fn(params, cache, state, tables):
+            state = _zero_moe(state)
+
             def one(carry, _):
                 params, cache, state = carry
                 cache, state = self._decode_body(params, cache, state,
@@ -684,6 +709,7 @@ class ContinuousCascadeEngine:
             over restored/recomputed KV is bit-exact with never having
             been preempted."""
             return {
+                **state,
                 "last_tok": state["last_tok"].at[slot].set(last_tok),
                 "pos": state["pos"].at[slot].set(pos),
                 "n_gen": state["n_gen"].at[slot].set(n_gen),
@@ -847,10 +873,21 @@ class ContinuousCascadeEngine:
                 "active": jnp.zeros((S,), bool),
                 "tokens": jnp.zeros((S, max_new), jnp.int32),
             }
+            if tfm.held_experts(self.small.cfg):
+                # per-layer held-expert counters of the last dispatch
+                state["moe"] = jnp.zeros((self.small.cfg.n_layers
+                                          - self.small.cfg.moe.n_dense_layers,
+                                          2), jnp.int32)
             # paged: requests admitted to a slot but still prefilling,
             # FIFO of [request, slot, next chunk offset]
             prefilling: List[List] = []
             n_steps = 0
+            moe_totals = [0, 0]     # held experts hit, assignments taken
+            # the window of each windowed layer (decode span args)
+            kinds = self.small.cfg.layer_kinds
+            windows = [kinds[i % len(kinds)].window
+                       for i in range(self.small.cfg.n_layers)] if kinds else []
+            windows = [w for w in windows if w]
             n_prefill_chunks = 0
             n_prefill_dispatches = 0
             n_prefill_tokens = 0
@@ -971,16 +1008,28 @@ class ContinuousCascadeEngine:
                                   wait_ms=round(
                                       (now - req.t_submit_large) * 1e3, 3))
 
-            def sync_retire():
+            def sync_retire(step: Optional[int] = None):
                 """Pull the tiny control vectors, retire finished /
                 in-flight deferred slots, release them, and deactivate on
                 device. Slots still prefilling are skipped — their device
-                state is stale until the final chunk seeds it."""
+                state is stale until the final chunk seeds it. After a
+                decode dispatch (`step`) of a held-expert model the same
+                pull brings its per-layer expert counters, written to the
+                audit log under the step's index."""
+                moe = step is not None and "moe" in state
                 with span("sync"):
-                    n_gen = np.asarray(state["n_gen"])
-                    conf_sum = np.asarray(state["conf_sum"])
+                    pulled = jax.device_get(
+                        (state["n_gen"], state["conf_sum"])
+                        + ((state["moe"],) if moe else ()))
+                if moe:
+                    hit, assign = pulled[2][:, 0], pulled[2][:, 1]
+                    moe_totals[0] += int(hit.sum())
+                    moe_totals[1] += int(assign.sum())
+                    tel.m_moe_assign.inc(int(assign.sum()))
+                    tel.event("moe", step=step, hit=hit.tolist(),
+                              assign=assign.tolist())
                 with span("retire"):
-                    retire(n_gen, conf_sum)
+                    retire(pulled[0], pulled[1])
 
             def retire(n_gen, conf_sum):
                 """Host side of `sync_retire`: gate each decoding slot on
@@ -1461,7 +1510,9 @@ class ContinuousCascadeEngine:
                 `kv_pages` (the pages the paged kernel reads per layer:
                 each decoding row's `pos // bs + 1`) and `table_pages`
                 (rows x columns of the table, what a walk of all of it
-                would visit)."""
+                would visit) and, for a model with windowed layers,
+                `win_pages` (the pages those layers' walks read, summed
+                over rows and windowed layers)."""
                 while True:
                     decoding = decoding_slots()
                     if not decoding:
@@ -1488,10 +1539,17 @@ class ContinuousCascadeEngine:
                         if policy is None or not relieve_pressure():
                             raise
                 tbl = pool.tables_device(pool.active_prefix_blocks(need))
-                return decoding, tbl, {
-                    "kv_pages": sum(int(pos_host[slot]) // bs + 1
-                                    for slot in decoding),
-                    "table_pages": tbl.shape[0] * tbl.shape[1]}
+                args = {"kv_pages": sum(int(pos_host[slot]) // bs + 1
+                                        for slot in decoding),
+                        "table_pages": tbl.shape[0] * tbl.shape[1]}
+                if windows:
+                    # pages the windowed layers' walks read: from the page
+                    # holding pos - window + 1 through pos's, per layer
+                    args["win_pages"] = sum(
+                        int(p) // bs - max(int(p) - w + 1, 0) // bs + 1
+                        for w in windows
+                        for p in (pos_host[slot] for slot in decoding))
+                return decoding, tbl, args
 
             def drain_ladder():
                 """All M_S work is done: drain the ladder edge by edge.
@@ -1575,7 +1633,7 @@ class ContinuousCascadeEngine:
                                               ml_pending=ml.n_pending)
                                     if tr is not None:
                                         record_conf_trace(decoding)
-                                    sync_retire()
+                                    sync_retire(step)
                             if decoding:
                                 tel.m_decode_step.observe(
                                     dec.seconds / self.steps_per_sync)
@@ -1622,6 +1680,8 @@ class ContinuousCascadeEngine:
                                 "seconds": b["seconds"]}
                                for b in ml.batch_log if "seconds" in b]
         stats["compiles"] = compiles.counts
+        if tfm.held_experts(self.small.cfg):
+            stats["moe_hit_total"], stats["moe_assign_total"] = moe_totals
         stats["ml_queue_depth_peak"] = int(max(ml_depths, default=0))
         stats["ml_queue_depth_mean"] = (float(np.mean(ml_depths))
                                         if ml_depths else 0.0)

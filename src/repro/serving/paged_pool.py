@@ -143,7 +143,10 @@ def prefix_block_keys(tokens: np.ndarray, block_size: int) -> List[bytes]:
 
 def validate_pageable(cfg: ModelConfig, max_len: int) -> None:
     """Raise NotImplementedError unless every cache leaf is a linear
-    attention cache (has a full-length ``cache_seq`` axis)."""
+    attention cache (has a full-length ``cache_seq`` axis). Windowed
+    layers of a model with per-layer attention kinds (``layer_kinds``)
+    pass: they keep every page of a row and mask by position; only a
+    config-wide ``sliding_window`` ring cache is refused."""
     abstract = tfm.init_cache(cfg, 1, max_len, abstract=True)
     for leaf in jax.tree.leaves(abstract, is_leaf=_is_abstract):
         axes = leaf.logical_axes

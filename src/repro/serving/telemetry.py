@@ -121,6 +121,10 @@ class ServingTelemetry:
         self.m_requests = reg.counter(
             "serving_requests_total", "requests retired by outcome",
             ("outcome",))
+        self.m_moe_assign = reg.counter(
+            "serving_moe_assignments_total",
+            "decode-step token assignments to the experts this device "
+            "holds (held-expert models)")
         # host-side phase accumulators mirrored into the registry
         # (plain dicts: summary() must not depend on scrape state)
         self.phase_s: Dict[str, float] = {}

@@ -79,7 +79,8 @@ def test_smoke_train_step(arch):
 
 @pytest.mark.parametrize("arch", ["internlm2-1.8b", "deepseek-v2-236b",
                                   "rwkv6-3b", "zamba2-1.2b",
-                                  "kimi-k2-1t-a32b", "qwen1.5-4b"])
+                                  "kimi-k2-1t-a32b", "qwen1.5-4b",
+                                  "mellum2-12b-a2.5b"])
 def test_prefill_decode_matches_forward(arch):
     cfg = reduced(get_config(arch))
     key = jax.random.PRNGKey(2)

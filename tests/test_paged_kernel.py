@@ -3,6 +3,8 @@ GQA/MLA paged decode kernels (and the in-kernel single-token paged write)
 against the XLA dense-gather path, active-prefix gather equivalence, and
 engine-level greedy parity of the kernel path and of batched paged
 prefill vs the serial chunk loop on a ragged Poisson stream."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -90,6 +92,28 @@ GQA_CASES = {
 }
 
 
+# windowed cases: a row attends to (pos - window, pos]; its walk starts
+# at the page holding pos - window + 1, so every page before it holds NaN
+WINDOW_CASES = {
+    # window 6 over 4-token pages (not a multiple): rows below, at and
+    # past the window, and the all-trash row
+    "tiny_not_multiple": dict(dims=(4, 2, 16, 4), tables=TABLES,
+                              pos=POS, window=6, dtype=jnp.float32,
+                              atol=1e-5),
+    # window 20 over 16-token pages: rows below (10), at (19, 20) and
+    # far past it (200, the walk starting mid-table)
+    "served_not_multiple": dict(dims=SERVED, M=13, pos=[10, 19, 20, 200],
+                                window=20, dtype=jnp.bfloat16, atol=2e-5),
+    # window 32 (a multiple) ending on page boundaries, one row in it
+    "served_multiple": dict(dims=SERVED, M=13, pos=[31, 32, 47, 150],
+                            window=32, dtype=jnp.bfloat16, atol=2e-5),
+    # all-trash first and last rows beside windowed ones
+    "all_trash": dict(dims=SERVED, M=13, pos=[150, 100, 9, 60], window=37,
+                      unmapped=[(0, list(range(13))), (3, list(range(13)))],
+                      dtype=jnp.bfloat16, atol=2e-5),
+}
+
+
 def _gqa_case(case, seed=1):
     """(q, k_pages, v_pages, tables, pos) of a case. The served cases
     share one pool, so each dtype and table width compiles once."""
@@ -108,7 +132,9 @@ def _gqa_case(case, seed=1):
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((B, 1, H, hd), np.float32)
     kp, vp = rng.standard_normal((2, N, bs, KV, hd), np.float32)
-    live = (tables > 0) & (np.arange(M)[None, :] <= pos[:, None] // bs)
+    first = np.maximum(pos - case.get("window", 1 << 30) + 1, 0)
+    live = ((tables > 0) & (np.arange(M)[None, :] <= pos[:, None] // bs)
+            & (np.arange(M)[None, :] >= first[:, None] // bs))
     dead = np.setdiff1d(np.arange(N), tables[live])
     kp[dead] = np.nan
     vp[dead] = np.nan
@@ -117,9 +143,10 @@ def _gqa_case(case, seed=1):
             tables, pos)
 
 
-def _gqa_reference(q, kp, vp, tables, pos):
-    """float64 masked softmax over each row's mapped `idx <= pos` tokens;
-    rows without one give zeros."""
+def _gqa_reference(q, kp, vp, tables, pos, window=None):
+    """float64 masked softmax over each row's mapped `idx <= pos` tokens
+    (and `idx > pos - window` with a window); rows without one give
+    zeros."""
     q, kp, vp = (np.asarray(a.astype(jnp.float32), np.float64)
                  for a in (q, kp, vp))
     B, _, H, hd = q.shape
@@ -128,6 +155,8 @@ def _gqa_reference(q, kp, vp, tables, pos):
     vv = vp[tables].reshape(B, -1, KV, hd)
     idx = np.arange(kk.shape[1])[None, :]
     ok = (idx <= pos[:, None]) & np.repeat(tables > 0, bs, axis=1)
+    if window is not None:
+        ok &= idx > pos[:, None] - window
     kk = np.where(ok[..., None, None], kk, 0.0)
     vv = np.where(ok[..., None, None], vv, 0.0)
     qg = q[:, 0].reshape(B, KV, H // KV, hd)
@@ -159,6 +188,43 @@ def test_gqa_kernel_parity_ragged(name):
                                atol=case["atol"], rtol=0)
     np.testing.assert_array_equal(out[~readable],
                                   np.zeros_like(out[~readable]))
+
+
+@pytest.mark.parametrize("name", list(WINDOW_CASES))
+def test_gqa_kernel_window(name):
+    """A windowed layer's call against a float64 dense windowed softmax:
+    rows below, at and past the window agree; pages before the window's
+    first page hold NaN and are never read; all-trash rows are zeros."""
+    case = WINDOW_CASES[name]
+    q, kp, vp, tables, pos = _gqa_case(case)
+    out = np.asarray(kops.paged_flash_decode_gqa(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(pos),
+        jnp.int32(case["window"])), np.float64)
+    ref = _gqa_reference(q, kp, vp, tables, pos, case["window"])
+    readable = (tables > 0).any(axis=1)
+    np.testing.assert_allclose(out[readable], ref[readable],
+                               atol=case["atol"], rtol=0)
+    np.testing.assert_array_equal(out[~readable],
+                                  np.zeros_like(out[~readable]))
+
+
+@pytest.mark.parametrize("window", [6, 8, 3])
+def test_gqa_decode_window_layer_paths(window):
+    """One windowed attention layer: the paged kernel, the paged XLA path
+    and dense (unpaged) windowed attention over the same rows agree on
+    every mapped row, for windows off and on the 4-token page size."""
+    ac, params, cache, x = _gqa_layer(jax.random.PRNGKey(6))
+    ac = dataclasses.replace(ac, sliding_window=window)
+    ctx = ParallelContext()
+    tables, pos = jnp.asarray(TABLES), jnp.asarray(POS)
+    outs = [attn.gqa_decode(params, ac, x, pos, cache, ctx, pages=tables,
+                            paged_kernel=k)[0] for k in (False, True)]
+    # dense: each row's pages gathered into a linear cache, no table
+    dense = {k: gather_blocks(v, tables) for k, v in cache.items()}
+    y_d, _ = attn.gqa_decode(params, ac, x, pos, dense, ctx)
+    for y in outs:
+        np.testing.assert_allclose(np.asarray(y[:3]), np.asarray(y_d[:3]),
+                                   atol=1e-5)
 
 
 def test_mla_kernel_parity_ragged():

@@ -227,3 +227,72 @@ def test_spans_feed_phases_and_the_chrome_track(monkeypatch):
     assert [(s["name"], s["cat"], s["ts"], s["dur"]) for s in spans_out] == \
         [("ml_poll", "engine", 1e6, 3e6), ("sync", "engine", 1.5e6, 0.5e6)]
     assert spans_out[1]["args"] == {"step": 3}
+
+
+def test_dense_steps_carry_no_expert_counters(profiled):
+    """A dense model's dispatches carry no window pages and its audit log
+    no expert counters: nothing of the held-expert path runs."""
+    res, spans, events = profiled
+    disp = [s[3] for line in spans.values() for s in line
+            if s[0] == "engine.decode_dispatch"]
+    assert disp and all("win_pages" not in d for d in disp)
+    assert not [e for e in events if e["event"] == "moe"]
+    assert "moe_assign_total" not in res.stats
+
+
+def test_windowed_moe_dispatch_pages_and_expert_counters(tmp_path):
+    """Mellum (reduced: three 16-token-window layers and a full one, 2 of
+    4 experts held): each dispatch's `win_pages` is the pages the
+    windowed layers' walks read, from its rows' positions; the same
+    step's audit event holds each layer's held experts hit and
+    assignments, pulled with the step's sync, and their sum is the
+    registry's counter."""
+    key = jax.random.PRNGKey(3)
+    cfg = reduced(get_config("mellum2-12b-a2.5b"))
+    small = ModelRunner(cfg, tfm.init_params(cfg, key))
+    large = ModelRunner(cfg.replace(name="l"), tfm.init_params(
+        cfg, jax.random.fold_in(key, 1)))
+    prompts = make_lm_stream(jax.random.fold_in(key, 2), 3, 30,
+                             cfg.vocab_size)
+    obs = Observability(ObsConfig())
+    eng = ContinuousCascadeEngine(
+        CascadeSpec.two_tier(small, large, tau=-1e9, min_tokens=2),
+        EngineConfig(n_slots=3, backend="paged", early_exit=False,
+                     paged=PagedConfig(block_size=4, prefill_chunk=8)))
+    audit = tmp_path / "audit.jsonl"
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = eng.run(make_requests(prompts, 8), 8, audit_path=str(audit),
+                      obs=obs)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb")))
+    spans = _host_spans(path[-1])
+    events = [json.loads(line) for line in audit.read_text().splitlines()]
+    disp = {s[3]["step"]: s[3] for line in spans.values() for s in line
+            if s[0] == "engine.decode_dispatch"}
+    steps = {e["step"]: e for e in events if e["event"] == "step"}
+    moe = {e["step"]: e for e in events if e["event"] == "moe"}
+    assert set(disp) == set(steps) == set(moe) and moe
+    pos = {}
+    for e in events:          # each slot's position at every step
+        if e["event"] == "prefill_done":
+            pos[e["slot"]] = 30
+        elif e["event"] == "step":
+            want = sum(p // 4 - max(p - 16 + 1, 0) // 4 + 1
+                       for p in (pos[s] for s in e["slots"])) * 3
+            assert disp[e["step"]]["win_pages"] == want
+            for s in e["slots"]:
+                pos[s] += 1
+    for step, e in moe.items():
+        rows = len(steps[step]["slots"])
+        assert len(e["hit"]) == len(e["assign"]) == cfg.n_layers
+        assert all(0 <= h <= min(2, a) for h, a in zip(e["hit"],
+                                                       e["assign"]))
+        assert all(a <= rows * cfg.moe.top_k for a in e["assign"])
+    total = sum(sum(e["assign"]) for e in moe.values())
+    assert res.stats["moe_assign_total"] == total > 0
+    assert res.stats["moe_hit_total"] == sum(sum(e["hit"])
+                                             for e in moe.values())
+    snap = obs.registry.snapshot()
+    assert snap["serving_moe_assignments_total"][()] == total

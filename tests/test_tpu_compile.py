@@ -112,3 +112,37 @@ def test_mla_paged_decode_compiles(one_chip):
              ((SLOTS, 1, H, r), dt), ((SLOTS, 1, H, dr), dt),
              ((N_BLOCKS, BLOCK, r), dt), ((N_BLOCKS, BLOCK, dr), dt),
              ((r,), dt), ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+def test_windowed_gqa_paged_decode_compiles(one_chip):
+    """Mellum's windowed layers: the window arrives as a traced scalar (the
+    layer scan carries each layer's kind), at 4 KV heads of 128 (16 pages
+    per block) and 16 slots at a 145-column table, q in fp32."""
+    cfg = get_config("mellum2-12b-a2.5b")
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    assert pa.gqa_pages_per_block(BLOCK, KV, hd, jnp.bfloat16) == 16
+    fn = functools.partial(pa.paged_flash_decode_gqa, interpret=False)
+    _compile(lambda q, k, v, t, p, w: fn(q, k, v, t, p, window=w), one_chip,
+             ((16, 1, H, hd), jnp.float32),
+             ((N_BLOCKS, BLOCK, KV, hd), jnp.bfloat16),
+             ((N_BLOCKS, BLOCK, KV, hd), jnp.bfloat16),
+             ((16, 145), jnp.int32), ((16,), jnp.int32), ((), jnp.int32))
+
+
+@pytest.mark.parametrize("rows", [1, 16])
+def test_moe_gmm_decode_compiles(one_chip, rows):
+    """The held-expert decode kernel at Mellum's widths (8 held experts of
+    2304 x 896, the whole width per grid step) over all 28 layers'
+    experts stacked, the layer a traced scalar, named so the device
+    trace finds it."""
+    from repro.kernels import moe_gmm
+    cfg = get_config("mellum2-12b-a2.5b")
+    d, f, E, L = (cfg.d_model, cfg.moe.d_ff_expert, cfg.moe.n_held,
+                  cfg.n_layers)
+    assert moe_gmm.ffn_tile(d, f, 2) == f
+    compiled = _compile(
+        functools.partial(moe_gmm.moe_gmm_decode, interpret=False),
+        one_chip, ((rows, d), jnp.bfloat16), ((rows, E), jnp.float32),
+        ((L, E, d, f), jnp.bfloat16), ((L, E, d, f), jnp.bfloat16),
+        ((L, E, f, d), jnp.bfloat16), ((), jnp.int32))
+    assert moe_gmm.NAME in compiled.as_text()
