@@ -88,29 +88,32 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_flash_decode_gqa(q, k_pages, v_pages, tables, positions,
-                           window=None, *, scale: Optional[float] = None,
+                           window=None, layer=None, *,
+                           scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
     """One-token GQA attention directly against the block-paged cache
-    (see paged_attention.py). q [B,1,H,hd]; k/v_pages [N, bs, KV, hd];
+    (see paged_attention.py). q [B,1,H,hd]; k/v_pages [N, bs, KV, hd], or
+    stacked `[L, N, bs, KV*hd]` with `layer` (may be traced) picking one;
     tables [B, M]; positions [B]; `window` (None, or a scalar that may
     be traced) limits each row to positions in (pos - window, pos]. No
     dense gather is materialized."""
     interpret = _default_interpret() if interpret is None else interpret
     return _pa.paged_flash_decode_gqa(q, k_pages, v_pages, tables,
-                                      positions, scale=scale, window=window,
-                                      interpret=interpret)
+                                      positions, layer, scale=scale,
+                                      window=window, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("scale", "eps", "interpret"))
 def paged_flash_decode_mla(q_abs, q_rope, ckv_pages, kr_pages, kv_norm,
-                           tables, positions, *, scale: float,
+                           tables, positions, layer=None, *, scale: float,
                            eps: float = 1e-6,
                            interpret: Optional[bool] = None):
-    """Weight-absorbed MLA decode against the paged compressed cache;
-    returns the latent context [B,1,H,kv_lora] (caller applies W_uv/W_o)."""
+    """Weight-absorbed MLA decode against the paged compressed cache
+    (single-layer leaves, or stacked with `layer` picking one); returns
+    the latent context [B,1,H,kv_lora] (caller applies W_uv/W_o)."""
     interpret = _default_interpret() if interpret is None else interpret
     return _pa.paged_flash_decode_mla(q_abs, q_rope, ckv_pages, kr_pages,
-                                      kv_norm, tables, positions,
+                                      kv_norm, tables, positions, layer,
                                       scale=scale, eps=eps,
                                       interpret=interpret)
 
@@ -128,12 +131,13 @@ def moe_gmm_decode(x, gates, w_gate, w_up, w_down, layer=None, *,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def paged_write_token(leaf, tables, positions, values, *,
+def paged_write_token(leaf, tables, positions, values, layer=None, *,
                       interpret: Optional[bool] = None):
     """Single-token paged scatter through the page table (in-kernel
-    replacement for the XLA `_paged_write` on the decode hot path)."""
+    replacement for the XLA `_paged_write` on the decode hot path); a
+    leaf stacked over layers is written at block (`layer`, page)."""
     interpret = _default_interpret() if interpret is None else interpret
-    return _pa.paged_write_token(leaf, tables, positions, values,
+    return _pa.paged_write_token(leaf, tables, positions, values, layer,
                                  interpret=interpret)
 
 

@@ -10,6 +10,15 @@ shared trash block) and pages past a row's `idx <= pos` prefix never reach
 the online softmax, whose fp32 (m, l, acc) state lives in VMEM; rows whose
 table is all zeros produce exact zeros.
 
+Stacked pools. The serving pool keeps each leaf stacked over the layers of
+its group in the kernels' own view, `[L, n_blocks+1, block_size, F]` (GQA:
+F = KV*hd; MLA: kv_lora or rope_dim), and the layer scan carries it whole.
+Every kernel here takes such a leaf and the layer as a scalar (may be
+traced) and reads or writes block `(layer, page)` in place: no layer's
+slice of the pool is ever copied out, and no relayout is needed. A single
+layer's leaf `[n_blocks+1, block_size, ...]` with no layer reads as a
+stack of one.
+
 GQA — paged K/V `[n_blocks+1, block_size, KV, hd]`. The grid is one step
 per row; inside it a loop runs over the row's LIVE pages only (through its
 last mapped page at or before `pos // block_size`, from the page holding
@@ -29,7 +38,7 @@ pages, not with `B * M`.
 
 MLA — weight-absorbed decode against the COMPRESSED cache (`ckv`
 `[*, kv_lora]` + shared rope key `[*, rope_dim]`): the grid is `(B, M)`,
-step (b, j) DMAs ONE `[block_size, ...]` block, `tables[b, j]`, and
+step (b, j) DMAs ONE `[block_size, ...]` block, `(layer, tables[b, j])`, and
 masks it out when unmapped or past `pos`; the kernel applies the kv
 rms-norm per block in fp32 and returns the latent context
 `[B, 1, H, kv_lora]`; the caller applies W_uv / W_o.
@@ -37,12 +46,13 @@ rms-norm per block in fp32 and returns the latent context
 `paged_write_token` is the companion single-token scatter: grid (B,), the
 output BlockSpec selects the physical block holding each row's `pos`
 through the page table, and the kernel rewrites only `pos % block_size`
-(input/output aliased, so no dense copy of the leaf). Rows whose target
+(input/output aliased: with a stacked leaf carried through the scan and
+donated to the step, the write is in place). Rows whose target
 block is unmapped write into the trash block — same contract as the XLA
 `_paged_write` they replace. Mapped blocks are pairwise disjoint across
 rows (pool invariant), so block revisits only ever hit the trash block.
-The GQA decode kernel reads the `[N, block_size, KV*hd]` view this kernel
-writes, so behind it the view costs no copy.
+The GQA decode kernel reads the `[L, N, block_size, KV*hd]` leaf this
+kernel writes.
 
 Oracle: the dense-gather paths in `models/attention.py` (parity pinned by
 tests/test_paged_kernel.py). Wrappers with interpret-mode defaults live in
@@ -96,8 +106,20 @@ def _fold(r, n: int):
     return r if r.shape[0] == n else r[:n] + r[n:]
 
 
+def _stacked(leaf, layer):
+    """(leaf as `[L, N, bs, F]`, layer as int32 `[1]`). A single layer's
+    leaf `[N, bs, ...]` (layer None) gains a leading axis of one and reads
+    layer 0. The reshape is free for a leaf already in the kernels'
+    layout; a `[.., KV, hd]` leaf pays a relayout."""
+    if layer is None:
+        leaf, layer = leaf[None], 0
+    L, N, bs = leaf.shape[:3]
+    return (leaf.reshape(L, N, bs, -1),
+            jnp.asarray(layer, jnp.int32).reshape(1))
+
+
 def _gqa_kernel(tbl_ref, pos_ref, first_ref, live_ref, blk0_ref, nxt_ref,
-                q_ref, k_hbm, v_hbm, o_ref,
+                lyr_ref, q_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
                 n_rows: int, M: int, P: int, bs: int, KV: int, group: int,
                 scale: float):
@@ -114,7 +136,10 @@ def _gqa_kernel(tbl_ref, pos_ref, first_ref, live_ref, blk0_ref, nxt_ref,
         return pid, (col < live_ref[row]) & (pid > 0)
 
     def dmas(row, blk, slot, op):
-        # one contiguous [bs, KV*hd] copy per live page, K and V each
+        # one contiguous [bs, KV*hd] copy per live page, K and V each,
+        # from the stacked leaf's block (layer, page)
+        lyr = lyr_ref[0]
+
         def one(i, carry):
             pid, live = page(row, blk, i)
 
@@ -123,7 +148,8 @@ def _gqa_kernel(tbl_ref, pos_ref, first_ref, live_ref, blk0_ref, nxt_ref,
                 for n, (src, buf) in enumerate(((k_hbm, kbuf),
                                                 (v_hbm, vbuf))):
                     getattr(pltpu.make_async_copy(
-                        src.at[pid], buf.at[slot, i], sems.at[n, slot]),
+                        src.at[lyr, pid], buf.at[slot, i],
+                        sems.at[n, slot]),
                         op)()
             return carry
         jax.lax.fori_loop(0, P, one, 0)
@@ -201,11 +227,13 @@ def _gqa_kernel(tbl_ref, pos_ref, first_ref, live_ref, blk0_ref, nxt_ref,
 
 def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, tables: jnp.ndarray,
-                           positions: jnp.ndarray, *,
+                           positions: jnp.ndarray, layer=None, *,
                            scale: float | None = None,
                            window=None,
                            interpret: bool = False) -> jnp.ndarray:
-    """q [B,1,H,hd]; k/v_pages [n_blocks+1, block_size, KV, hd];
+    """q [B,1,H,hd]; k/v_pages [n_blocks+1, block_size, KV, hd], or
+    stacked over layers `[L, n_blocks+1, block_size, KV*hd]` with `layer`
+    (a scalar, may be traced) picking one, read in place;
     tables [B, M] int32 (0 = unmapped); positions [B] int32. Returns
     [B,1,H,hd] — masked-softmax attention over each row's valid prefix,
     equal to the dense-gather path up to fp32 online-softmax rounding
@@ -217,7 +245,10 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
     that page are masked."""
     B, T, H, hd = q.shape
     assert T == 1
-    N, bs, KV = k_pages.shape[:3]
+    k_pages, lyr = _stacked(k_pages, layer)
+    v_pages, _ = _stacked(v_pages, layer)
+    bs = k_pages.shape[2]
+    KV = k_pages.shape[3] // hd
     M = tables.shape[1]
     group = H // KV
     scale = scale or 1.0 / np.sqrt(hd)
@@ -242,10 +273,10 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
     kernel = functools.partial(_gqa_kernel, n_rows=B, M=M, P=P, bs=bs,
                                KV=KV, group=group, scale=scale)
     row = pl.BlockSpec((1, 1, H, hd),
-                       lambda b, tbl, pos, first, live, blk0, nxt: (
+                       lambda b, tbl, pos, first, live, blk0, nxt, lyr: (
                            b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(B,),
         in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -267,16 +298,16 @@ def paged_flash_decode_gqa(q: jnp.ndarray, k_pages: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables.reshape(-1), positions, first, live, blk0, nxt, q,
-      k_pages.reshape(N, bs, KV * hd), v_pages.reshape(N, bs, KV * hd))
+    )(tables.reshape(-1), positions, first, live, blk0, nxt, lyr, q,
+      k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
 # MLA paged flash-decode (weight-absorbed, compressed cache)
 # ---------------------------------------------------------------------------
 
-def _mla_kernel(tbl_ref, pos_ref, qa_ref, qr_ref, ckv_ref, kr_ref, w_ref,
-                o_ref, m_ref, l_ref, acc_ref, *, n_mb: int, bs: int,
+def _mla_kernel(tbl_ref, pos_ref, lyr_ref, qa_ref, qr_ref, ckv_ref, kr_ref,
+                w_ref, o_ref, m_ref, l_ref, acc_ref, *, n_mb: int, bs: int,
                 scale: float, eps: float):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -325,33 +356,40 @@ def _mla_kernel(tbl_ref, pos_ref, qa_ref, qr_ref, ckv_ref, kr_ref, w_ref,
 def paged_flash_decode_mla(q_abs: jnp.ndarray, q_rope: jnp.ndarray,
                            ckv_pages: jnp.ndarray, kr_pages: jnp.ndarray,
                            kv_norm: jnp.ndarray, tables: jnp.ndarray,
-                           positions: jnp.ndarray, *, scale: float,
-                           eps: float = 1e-6,
+                           positions: jnp.ndarray, layer=None, *,
+                           scale: float, eps: float = 1e-6,
                            interpret: bool = False) -> jnp.ndarray:
     """Weight-absorbed MLA decode against the paged compressed cache.
 
     q_abs [B,1,H,r] (q_nope absorbed through W_uk); q_rope [B,1,H,dr];
-    ckv_pages [n_blocks+1, bs, r]; kr_pages [n_blocks+1, bs, dr];
-    kv_norm [r]. Returns the latent context [B,1,H,r] in fp32 — the
+    ckv_pages [n_blocks+1, bs, r]; kr_pages [n_blocks+1, bs, dr], or both
+    stacked over layers (`[L, n_blocks+1, bs, ...]`) with `layer` picking
+    one; kv_norm [r]. Returns the latent context [B,1,H,r] in fp32 — the
     caller applies W_uv and W_o (scores AND values stay O(kv_lora))."""
     B, T, H, r = q_abs.shape
     assert T == 1
     dr = q_rope.shape[-1]
-    bs = ckv_pages.shape[1]
+    ckv_pages, lyr = _stacked(ckv_pages, layer)
+    kr_pages, _ = _stacked(kr_pages, layer)
+    bs = ckv_pages.shape[2]
     M = tables.shape[1]
     kernel = functools.partial(_mla_kernel, n_mb=M, bs=bs, scale=scale,
                                eps=eps)
+    sq = pl.Squeezed()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, M),
         in_specs=[
-            pl.BlockSpec((1, 1, H, r), lambda b, j, t, p: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1, H, dr), lambda b, j, t, p: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, r), lambda b, j, t, p: (t[b, j], 0, 0)),
-            pl.BlockSpec((1, bs, dr), lambda b, j, t, p: (t[b, j], 0, 0)),
-            pl.BlockSpec((r,), lambda b, j, t, p: (0,)),
+            pl.BlockSpec((1, 1, H, r), lambda b, j, t, p, l: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, H, dr), lambda b, j, t, p, l: (b, 0, 0, 0)),
+            pl.BlockSpec((sq, 1, bs, r),
+                         lambda b, j, t, p, l: (l[0], t[b, j], 0, 0)),
+            pl.BlockSpec((sq, 1, bs, dr),
+                         lambda b, j, t, p, l: (l[0], t[b, j], 0, 0)),
+            pl.BlockSpec((r,), lambda b, j, t, p, l: (0,)),
         ],
-        out_specs=pl.BlockSpec((1, 1, H, r), lambda b, j, t, p: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, H, r),
+                               lambda b, j, t, p, l: (b, 0, 0, 0)),
         scratch_shapes=[pltpu.VMEM((H,), jnp.float32),
                         pltpu.VMEM((H,), jnp.float32),
                         pltpu.VMEM((H, r), jnp.float32)],
@@ -360,7 +398,7 @@ def paged_flash_decode_mla(q_abs: jnp.ndarray, q_rope: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, r), jnp.float32),
         interpret=interpret,
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32),
+    )(tables.astype(jnp.int32), positions.astype(jnp.int32), lyr,
       q_abs, q_rope, ckv_pages, kr_pages, kv_norm)
 
 
@@ -368,7 +406,8 @@ def paged_flash_decode_mla(q_abs: jnp.ndarray, q_rope: jnp.ndarray,
 # In-kernel single-token paged write
 # ---------------------------------------------------------------------------
 
-def _write_kernel(phys_ref, pos_ref, val_ref, leaf_ref, out_ref, *, bs: int):
+def _write_kernel(phys_ref, pos_ref, lyr_ref, val_ref, leaf_ref, out_ref, *,
+                  bs: int):
     # masked whole-block write: a store at a dynamic row of a packed bf16
     # tile cannot be proven tile-aligned by the TPU compiler, a select
     # over the whole [bs, F] block can
@@ -380,40 +419,45 @@ def _write_kernel(phys_ref, pos_ref, val_ref, leaf_ref, out_ref, *, bs: int):
 
 
 def paged_write_token(leaf: jnp.ndarray, tables: jnp.ndarray,
-                      positions: jnp.ndarray, values: jnp.ndarray, *,
-                      interpret: bool = False) -> jnp.ndarray:
+                      positions: jnp.ndarray, values: jnp.ndarray,
+                      layer=None, *, interpret: bool = False) -> jnp.ndarray:
     """Scatter one token per row through the page table.
 
-    leaf [n_blocks+1, block_size, ...]; tables [B, M]; positions [B];
-    values [B, ...]. Row b writes into block `tables[b, pos//bs]` at
-    offset `pos % bs`; unmapped targets land in the trash block (id 0) —
+    leaf [n_blocks+1, block_size, ...], or stacked over layers
+    `[L, n_blocks+1, block_size, F]` with `layer` (a scalar, may be
+    traced) picking one; tables [B, M]; positions [B]; values [B, ...].
+    Row b writes into block `(layer, tables[b, pos//bs])` at offset
+    `pos % bs`; unmapped targets land in the trash block (id 0) —
     identical contract to the XLA `_paged_write` scatter. The leaf is
-    input/output aliased: only the touched blocks move through VMEM."""
-    N, bs = leaf.shape[:2]
-    rest = leaf.shape[2:]
+    input/output aliased: only the touched blocks move through VMEM, and
+    a stacked leaf keeps its shape (the other layers are untouched)."""
+    shape = leaf.shape
+    leaf4, lyr = _stacked(leaf, layer)
+    bs, F = leaf4.shape[2:]
     B, M = tables.shape
-    F = int(np.prod(rest)) if rest else 1
-    leaf2 = leaf.reshape(N, bs, F)
     # [B, 1, F]: a (1, 1, F) block keeps its last two dims equal to the
     # array's, as the TPU block-shape rule needs
     vals2 = values.reshape(B, 1, F)
     blk = jnp.clip(positions.astype(jnp.int32) // bs, 0, M - 1)
     phys = jnp.take_along_axis(tables.astype(jnp.int32),
                                blk[:, None], axis=1)[:, 0]     # [B]
+    sq = pl.Squeezed()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, F), lambda b, ph, p: (b, 0, 0)),
-            pl.BlockSpec((1, bs, F), lambda b, ph, p: (ph[b], 0, 0)),
+            pl.BlockSpec((1, 1, F), lambda b, ph, p, l: (b, 0, 0)),
+            pl.BlockSpec((sq, 1, bs, F),
+                         lambda b, ph, p, l: (l[0], ph[b], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bs, F), lambda b, ph, p: (ph[b], 0, 0)),
+        out_specs=pl.BlockSpec((sq, 1, bs, F),
+                               lambda b, ph, p, l: (l[0], ph[b], 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_write_kernel, bs=bs),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(leaf2.shape, leaf.dtype),
-        input_output_aliases={3: 0},
+        out_shape=jax.ShapeDtypeStruct(leaf4.shape, leaf.dtype),
+        input_output_aliases={4: 0},
         interpret=interpret,
-    )(phys, positions.astype(jnp.int32), vals2, leaf2)
-    return out.reshape(leaf.shape)
+    )(phys, positions.astype(jnp.int32), lyr, vals2, leaf4)
+    return out.reshape(shape)

@@ -14,7 +14,12 @@ Cache layouts (per layer-sliced leaf):
     (pages[b, pos // block_size], pos % block_size); reads gather the
     row's blocks back into a dense [B, M*block_size, ...] view and apply
     the same per-row validity mask — so paged and dense attention compute
-    identical masked softmaxes over the valid prefix.
+    identical masked softmaxes over the valid prefix. The serving pool
+    instead hands every layer the leaf of its whole group,
+    [L, n_blocks, block_size, F] with the features flat (GQA: F =
+    KV*head_dim), and the layer's index (`layer_index`): each write and
+    gather then indexes (layer, block) in place, so no layer's slice of
+    the pool is ever copied out.
   Paged mode is selected by passing `pages`; sliding-window ring caches
   cannot be paged (serving.paged_pool rejects those configs), but a
   windowed layer over a full-length paged cache can: it keeps every page
@@ -80,10 +85,13 @@ class AttnConfig:
 # Paged-cache primitives (shared by GQA and MLA)
 # ---------------------------------------------------------------------------
 
-def gather_blocks(leaf: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
+def gather_blocks(leaf: jnp.ndarray, pages: jnp.ndarray,
+                  layer=None) -> jnp.ndarray:
     """Gather a paged cache leaf into a dense per-row view.
 
-    leaf  [n_blocks, block_size, ...] — physical block storage
+    leaf  [n_blocks, block_size, ...] — physical block storage, or
+          [L, n_blocks, block_size, ...] with `layer` (may be traced)
+          picking one: one gather of (layer, block) pairs
     pages [B, M] int32               — per-row page table (logical -> physical)
 
     Returns [B, M*block_size, ...]: row b's logical sequence, blocks
@@ -97,25 +105,35 @@ def gather_blocks(leaf: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
     masked softmax over the shorter view is exactly the full-view one.
     """
     B, M = pages.shape
-    g = jnp.take(leaf, pages.reshape(-1), axis=0)        # [B*M, bs, ...]
-    return g.reshape((B, M * leaf.shape[1]) + leaf.shape[2:])
+    flat = pages.reshape(-1)
+    if layer is None:
+        g = jnp.take(leaf, flat, axis=0)                 # [B*M, bs, ...]
+    else:
+        g = leaf[jnp.full_like(flat, layer), flat]
+    return g.reshape((B, M * g.shape[1]) + g.shape[2:])
 
 
 def _paged_write(leaf: jnp.ndarray, pages: jnp.ndarray, tpos: jnp.ndarray,
-                 values: jnp.ndarray) -> jnp.ndarray:
+                 values: jnp.ndarray, layer=None) -> jnp.ndarray:
     """Scatter `values` [B, T, ...] at absolute token positions `tpos`
-    ([T] shared across rows, or [B, T]) through the page table. Positions
-    whose logical block is unmapped (table entry 0) land in the trash
-    block — callers rely on this for padded prefill chunks and for
-    inactive decode rows (see engine one_step)."""
-    bs = leaf.shape[1]
+    ([T] shared across rows, or [B, T]) through the page table, into a
+    leaf of one layer or, with `layer`, into that layer of a stacked leaf
+    (one scatter of (layer, block, offset) triples; the features reshape
+    to the leaf's). Positions whose logical block is unmapped (table
+    entry 0) land in the trash block — callers rely on this for padded
+    prefill chunks and for inactive decode rows (see engine one_step)."""
+    feat = leaf.shape[2 if layer is None else 3:]
+    bs = leaf.shape[1 if layer is None else 2]
     B = pages.shape[0]
     if tpos.ndim == 1:
         tpos = jnp.broadcast_to(tpos[None, :], (B, tpos.shape[0]))
     blk_idx = jnp.clip(tpos // bs, 0, pages.shape[1] - 1)   # [B, T]
     blk = jnp.take_along_axis(pages, blk_idx, axis=1)       # [B, T]
     off = tpos % bs
-    return leaf.at[blk, off].set(values.astype(leaf.dtype))
+    values = values.reshape(tpos.shape + feat).astype(leaf.dtype)
+    if layer is None:
+        return leaf.at[blk, off].set(values)
+    return leaf.at[jnp.full_like(blk, layer), blk, off].set(values)
 
 
 # ---------------------------------------------------------------------------
@@ -133,6 +151,14 @@ def _rope_and_window(cfg: AttnConfig, layer: Optional[dict]):
     return (lambda t, p: apply_rope_freqs(t, p, layer["inv_freq"],
                                           layer["rope_scale"]),
             layer["window"])
+
+
+def _gather_heads(leaf, pages, layer_index, heads):
+    """`gather_blocks` as [B, M*block_size, *heads]: a pool leaf keeps its
+    features flat, the gathered view (a row's active prefix) regains the
+    head axes attention needs."""
+    g = gather_blocks(leaf, pages, layer_index)
+    return g.reshape(g.shape[:2] + tuple(heads))
 
 
 def init_gqa(pf: ParamFactory, cfg: AttnConfig, stacked: int = 0) -> dict:
@@ -246,7 +272,8 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
                 cache: Optional[dict] = None,
                 cache_offset=0,
                 pages: Optional[jnp.ndarray] = None,
-                layer: Optional[dict] = None
+                layer: Optional[dict] = None,
+                layer_index=None
                 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Full-sequence self-attention (training / prefill).
 
@@ -255,7 +282,9 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     `cache_offset` may be a traced scalar (chunked prefill resumes at the
     chunk's start). With `pages` [B, M] the cache is block-paged
     ([n_blocks, block_size, ...] leaves): the chunk's K/V scatter through
-    the page table and attention runs over the gathered logical view.
+    the page table and attention runs over the gathered logical view;
+    with `layer_index` the leaves are the group's stacked pool and this
+    layer's blocks are written and gathered in place.
     `layer` gives this layer's window and rope as traced values (see
     `_rope_and_window`).
     """
@@ -281,11 +310,11 @@ def gqa_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
         # positions at or before pos - window
         assert cache is not None
         tpos = cache_offset + jnp.arange(T)
-        ck = _paged_write(cache["k"], pages, tpos, k)
-        cv = _paged_write(cache["v"], pages, tpos, v)
+        ck = _paged_write(cache["k"], pages, tpos, k, layer_index)
+        cv = _paged_write(cache["v"], pages, tpos, v, layer_index)
         new_cache = {"k": ck, "v": cv}
-        kk = gather_blocks(ck, pages)
-        vv = gather_blocks(cv, pages)
+        kk = _gather_heads(ck, pages, layer_index, k.shape[2:])
+        vv = _gather_heads(cv, pages, layer_index, v.shape[2:])
         if window is not None:
             mask = make_sliding_mask(T, kk.shape[1], cache_offset, window)
         else:
@@ -383,7 +412,8 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
                position, cache: dict, ctx: ParallelContext,
                pages: Optional[jnp.ndarray] = None,
                paged_kernel: Optional[bool] = None,
-               layer: Optional[dict] = None
+               layer: Optional[dict] = None,
+               layer_index=None
                ) -> Tuple[jnp.ndarray, dict]:
     """One-token decode. x [B,1,d]; position is either a scalar int (whole
     batch at the same depth — the static serving engine) or an int vector
@@ -397,7 +427,9 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     token-identical to the dense path. Requires per-row positions.
     `paged_kernel` picks the Pallas paged flash-decode kernel (walks the
     page table in-kernel, no dense gather) vs the XLA gather fallback;
-    None = backend default.
+    None = backend default. With `layer_index` the paged leaves are the
+    group's stacked pool ([L, n_blocks, block_size, KV*head_dim]) and both
+    implementations write and read this layer's blocks in place.
 
     For sliding-window configs the cache is a ring buffer of size `window`;
     the write slot is position % window and relative order is handled by
@@ -429,18 +461,20 @@ def gqa_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
             # the page table — no dense [B, M*bs, ...] view in HBM; a
             # windowed layer's walk starts at the window's first page
             ck = kernel_ops.paged_write_token(cache["k"], pages, pos,
-                                              k[:, 0])
+                                              k[:, 0], layer_index)
             cv = kernel_ops.paged_write_token(cache["v"], pages, pos,
-                                              v[:, 0])
-            out = kernel_ops.paged_flash_decode_gqa(q, ck, cv, pages, pos,
-                                                    window, scale=scale)
+                                              v[:, 0], layer_index)
+            out = kernel_ops.paged_flash_decode_gqa(
+                q, ck, cv, pages, pos, window, layer_index, scale=scale)
         else:
             # XLA fallback / parity reference: scatter + dense gather of
             # the (caller-tightened) active block prefix
-            ck = _paged_write(cache["k"], pages, pos[:, None], k[:, 0:1])
-            cv = _paged_write(cache["v"], pages, pos[:, None], v[:, 0:1])
-            kk = gather_blocks(ck, pages)
-            vv = gather_blocks(cv, pages)
+            ck = _paged_write(cache["k"], pages, pos[:, None], k[:, 0:1],
+                              layer_index)
+            cv = _paged_write(cache["v"], pages, pos[:, None], v[:, 0:1],
+                              layer_index)
+            kk = _gather_heads(ck, pages, layer_index, k.shape[2:])
+            vv = _gather_heads(cv, pages, layer_index, v.shape[2:])
             idx = jnp.arange(kk.shape[1])[None, :]
             mask = idx <= pos[:, None]
             if window is not None:
@@ -588,12 +622,13 @@ def _mla_qkr(params, cfg, x, positions):
 def mla_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
                 positions: jnp.ndarray, ctx: ParallelContext,
                 cache: Optional[dict] = None, cache_offset=0,
-                pages: Optional[jnp.ndarray] = None
+                pages: Optional[jnp.ndarray] = None, layer_index=None
                 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Training / prefill path: materializes per-head K/V (compute-friendly);
     the cache still stores only (ckv, kr). `pages` selects the block-paged
     cache layout (chunked prefill): the chunk's compressed kv / rope key
-    scatter through the page table, attention gathers the logical view."""
+    scatter through the page table, attention gathers the logical view
+    (of layer `layer_index` of a stacked pool, in place)."""
     B, T, d = x.shape
     dn, dr, dv = cfg.head_dim, cfg.rope_dim, cfg.v_head_dim or cfg.head_dim
     q_nope, q_rope = _mla_qkr(params, cfg, x, positions)
@@ -604,11 +639,11 @@ def mla_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     if pages is not None:
         assert cache is not None
         tpos = cache_offset + jnp.arange(T)
-        cckv = _paged_write(cache["ckv"], pages, tpos, ckv)
-        ckr = _paged_write(cache["kr"], pages, tpos, kr)
+        cckv = _paged_write(cache["ckv"], pages, tpos, ckv, layer_index)
+        ckr = _paged_write(cache["kr"], pages, tpos, kr, layer_index)
         new_cache = {"ckv": cckv, "kr": ckr}
-        ckv_all = gather_blocks(cckv, pages).astype(x.dtype)
-        kr_all = gather_blocks(ckr, pages).astype(x.dtype)
+        ckv_all = gather_blocks(cckv, pages, layer_index).astype(x.dtype)
+        kr_all = gather_blocks(ckr, pages, layer_index).astype(x.dtype)
         S = ckv_all.shape[1]
         mask = make_causal_mask(T, S, cache_offset)
     elif cache is not None:
@@ -656,7 +691,8 @@ def mla_forward(params: dict, cfg: AttnConfig, x: jnp.ndarray,
 def mla_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
                position, cache: dict, ctx: ParallelContext,
                pages: Optional[jnp.ndarray] = None,
-               paged_kernel: Optional[bool] = None
+               paged_kernel: Optional[bool] = None,
+               layer_index=None
                ) -> Tuple[jnp.ndarray, dict]:
     """Weight-absorbed decode: scores/values computed directly against the
     compressed cache — per-step FLOPs and cache reads are O(kv_lora), not
@@ -666,7 +702,8 @@ def mla_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     (continuous batching), mirroring `gqa_decode`. `pages` [B, M] selects
     the block-paged cache layout (requires per-row positions);
     `paged_kernel` picks the Pallas paged flash-decode kernel over the
-    XLA gather fallback (None = env / backend default, see ops.py)."""
+    XLA gather fallback (None = env / backend default, see ops.py);
+    `layer_index` picks this layer's blocks of a stacked pool."""
     B, T, d = x.shape
     assert T == 1
     dn, dr, dv = cfg.head_dim, cfg.rope_dim, cfg.v_head_dim or cfg.head_dim
@@ -681,25 +718,27 @@ def mla_decode(params: dict, cfg: AttnConfig, x: jnp.ndarray,
     if pages is not None and kernel_ops.paged_kernel_enabled(paged_kernel):
         assert per_row, "paged decode needs per-row positions"
         cckv = kernel_ops.paged_write_token(cache["ckv"], pages, pos,
-                                            ckv_new[:, 0])
+                                            ckv_new[:, 0], layer_index)
         ckr = kernel_ops.paged_write_token(cache["kr"], pages, pos,
-                                           kr_new[:, 0])
+                                           kr_new[:, 0], layer_index)
         # absorb W_uk into q; the kernel rms-norms each ckv block in
         # fp32 and returns the latent context — no dense gather
         q_abs = jnp.einsum("bthk,rhk->bthr", q_nope, params["wuk"])
         ctx_lat = kernel_ops.paged_flash_decode_mla(
             q_abs, q_rope, cckv, ckr, params["kv_norm"], pages, pos,
-            scale=scale).astype(x.dtype)
+            layer_index, scale=scale).astype(x.dtype)
         out = jnp.einsum("bthr,rhk->bthk", ctx_lat, params["wuv"])
         y = jnp.einsum("bthk,hkd->btd", out, params["wo"],
                        preferred_element_type=jnp.float32).astype(x.dtype)
         return y, {"ckv": cckv, "kr": ckr}
     if pages is not None:
         assert per_row, "paged decode needs per-row positions"
-        cckv = _paged_write(cache["ckv"], pages, pos[:, None], ckv_new)
-        ckr = _paged_write(cache["kr"], pages, pos[:, None], kr_new)
-        ckv_seq = gather_blocks(cckv, pages)               # [B, M*bs, r]
-        kr_seq = gather_blocks(ckr, pages)
+        cckv = _paged_write(cache["ckv"], pages, pos[:, None], ckv_new,
+                            layer_index)
+        ckr = _paged_write(cache["kr"], pages, pos[:, None], kr_new,
+                           layer_index)
+        ckv_seq = gather_blocks(cckv, pages, layer_index)  # [B, M*bs, r]
+        kr_seq = gather_blocks(ckr, pages, layer_index)
     elif per_row:
         rows = jnp.arange(B)
         cckv = cache["ckv"].at[rows, pos].set(

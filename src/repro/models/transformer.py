@@ -244,7 +244,9 @@ def _attn_ffn_block(p, cfg: ModelConfig, x, positions, ctx,
                     layer=None, row_mask=None, experts=None):
     """One pre-norm transformer block (attention or MLA + dense/MoE FFN).
     Returns (x, new_cache, aux, stats). `pages` selects the block-paged
-    cache layout; `paged_kernel` the Pallas-vs-XLA paged decode
+    cache layout, whose `cache` is then the group's whole stacked pool,
+    read and written at this layer's index `p["layer"]` and returned
+    whole; `paged_kernel` the Pallas-vs-XLA paged decode
     implementation (see models.attention) and, with held experts, the
     decode step's grouped-matmul kernel. `layer` holds the layer's
     attention kind as traced values (`layer_kind_arrays`); `row_mask` [B]
@@ -255,6 +257,8 @@ def _attn_ffn_block(p, cfg: ModelConfig, x, positions, ctx,
     ac = attn_config(cfg)
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     kw = {} if layer is None else {"layer": layer}
+    if pages is not None:
+        kw["layer_index"] = p["layer"]
     if decode:
         fwd = attn_lib.mla_decode if cfg.mla else attn_lib.gqa_decode
         y, new_cache = fwd(p["attn"], ac, h, position, cache, ctx,
@@ -280,25 +284,45 @@ def _attn_ffn_block(p, cfg: ModelConfig, x, positions, ctx,
     return x + y, new_cache, aux, stats
 
 
-def _scan_group(block_fn, stacked_params, x, stacked_cache, cfg: ModelConfig):
+def _scan_group(block_fn, stacked_params, x, stacked_cache, cfg: ModelConfig,
+                carry_cache: bool = False):
     """Fold a homogeneous stacked group. block_fn(p_layer, x, cache_layer) ->
     (x, new_cache_layer, aux, stats). Returns (x, new_stacked_cache,
-    aux_sum, stats stacked per layer)."""
+    aux_sum, stats stacked per layer).
+
+    With `carry_cache` (the paged pool) the stacked cache rides the scan's
+    carry whole instead of being sliced per layer as `xs` and stacked back
+    as `ys`: block_fn(p_layer, x, stacked_cache) updates its own layer in
+    place (the layer's index rides in `p_layer`) and returns the stack."""
     if not cfg.scan_layers:
         n = jax.tree.leaves(stacked_params)[0].shape[0]
         blk = _maybe_remat(block_fn, cfg)
         caches, stats, aux_sum = [], [], jnp.zeros((), jnp.float32)
         for i in range(n):
             p_i = jax.tree.map(lambda a, i=i: a[i], stacked_params)
-            c_i = (None if stacked_cache is None
-                   else jax.tree.map(lambda a, i=i: a[i], stacked_cache))
-            x, nc, aux, st = blk(p_i, x, c_i)
-            caches.append(nc)
+            if carry_cache:
+                x, stacked_cache, aux, st = blk(p_i, x, stacked_cache)
+            else:
+                c_i = (None if stacked_cache is None
+                       else jax.tree.map(lambda a, i=i: a[i], stacked_cache))
+                x, nc, aux, st = blk(p_i, x, c_i)
+                caches.append(nc)
             stats.append(st)
             aux_sum = aux_sum + aux
-        new_cache = (None if stacked_cache is None else
-                     jax.tree.map(lambda *ls: jnp.stack(ls), *caches))
+        new_cache = (stacked_cache if carry_cache or stacked_cache is None
+                     else jax.tree.map(lambda *ls: jnp.stack(ls), *caches))
         return x, new_cache, aux_sum, jnp.stack(stats)
+
+    if carry_cache:
+        def body(carry, p_layer):
+            x, aux_sum, cache = carry
+            x, cache, aux, st = block_fn(p_layer, x, cache)
+            return (x, aux_sum + aux, cache), st
+
+        (x, aux_sum, new_cache), stats = jax.lax.scan(
+            _maybe_remat(body, cfg),
+            (x, jnp.zeros((), jnp.float32), stacked_cache), stacked_params)
+        return x, new_cache, aux_sum, stats
 
     def body(carry, xs):
         x, aux_sum = carry
@@ -326,8 +350,10 @@ def _trunk(params, cfg: ModelConfig, x, positions, ctx,
            pages=None, paged_kernel=None, row_mask=None):
     """Runs all layer groups. x [B,T,d] embeddings. Returns (x, cache, aux,
     stats). `pages` [B, M] routes attention caches through a page table
-    (the physical block storage is shared by value, the table by
-    structure: every stacked layer's leaf is indexed by the same table);
+    (every layer's blocks are indexed by the same table); the group's
+    stacked pool then rides the layer scan's carry and each layer writes
+    and reads its own blocks in place by index, so no layer's slice of
+    the pool is copied out and stacked back;
     `paged_kernel` selects the Pallas paged-decode kernels per layer.
     With `cfg.layer_kinds` each layer's kind rides the scan beside its
     weights (`layer_kind_arrays`). `stats` is {} or, with held experts,
@@ -347,13 +373,19 @@ def _trunk(params, cfg: ModelConfig, x, positions, ctx,
                  else None)
         lo += n
         xs, experts = blocks[kind], None
+        c = cache.get(kind) if cache is not None else None
+        carry = pages is not None and c is not None
         if kind == "moe" and held_experts(cfg):
             # held experts stay stacked outside the scan (a slice per
             # layer would be copied before the kernel reads it); the scan
             # carries each layer's index instead
             m = dict(xs["moe"])
             experts = {k: m.pop(k) for k in ("w_gate", "w_up", "w_down")}
-            xs = {**xs, "moe": m, "layer": jnp.arange(n)}
+            xs = {**xs, "moe": m}
+        if carry or experts is not None:
+            # the layer's index in its group: the held experts' and the
+            # paged pool's
+            xs = {**xs, "layer": jnp.arange(n)}
 
         def block_fn(p, x_, c_, _kind=kind, _experts=experts):
             p, layer = p if cfg.layer_kinds else (p, None)
@@ -363,9 +395,9 @@ def _trunk(params, cfg: ModelConfig, x, positions, ctx,
                                    pages=pages, paged_kernel=paged_kernel,
                                    layer=layer, row_mask=row_mask,
                                    experts=_experts)
-        c = cache.get(kind) if cache is not None else None
         xs = (xs, kinds) if cfg.layer_kinds else xs
-        x, nc, aux, st = _scan_group(block_fn, xs, x, c, cfg)
+        x, nc, aux, st = _scan_group(block_fn, xs, x, c, cfg,
+                                     carry_cache=carry)
         if nc is not None:
             new_cache[kind] = nc
         if kind == "moe" and held_experts(cfg):

@@ -662,7 +662,10 @@ class ContinuousCascadeEngine:
         mode is just B_chunk == 1); `finish` seeds a slot's decode state
         from its row's last-real-position logits; `step` mirrors the slot
         backend but routes every cache access through the (active-prefix
-        sliced) page table, via Pallas kernels when `paged_kernel`."""
+        sliced) page table, via Pallas kernels when `paged_kernel`.
+        `prefill_chunk` and `step` take the pool's cache donated: XLA
+        updates it in place and the caller rebinds `pool.cache` to what
+        they return (the old leaves are deleted)."""
         cfg, ctx = self.small.cfg, self.small.ctx
 
         def prefill_chunk_fn(params, tokens, tables, offset, last_index,
@@ -719,8 +722,9 @@ class ContinuousCascadeEngine:
                 "tokens": state["tokens"].at[slot].set(row),
             }
 
-        return (jax.jit(prefill_chunk_fn), jax.jit(finish_fn),
-                jax.jit(step_fn), jax.jit(resume_fn))
+        return (jax.jit(prefill_chunk_fn, donate_argnames="cache"),
+                jax.jit(finish_fn), jax.jit(step_fn, donate_argnums=(1,)),
+                jax.jit(resume_fn))
 
     # -- host-side control loop -------------------------------------------
     def run(self, requests: List[Request], max_new: Optional[int] = None,
@@ -1208,9 +1212,11 @@ class ContinuousCascadeEngine:
                     tbl[i] = pool.tables[slot]          # chunk; padded
                     last_idx[i] = min(prefill_end(req) - 1 - off, C - 1)
                     n_prefill_tokens += int(piece.shape[0])  # K/V -> trash
+                donated = jax.tree.leaves(pool.cache)
                 logits, pool.cache = prefill_fn(
                     self.small.params, jnp.asarray(chunks), jnp.asarray(tbl),
                     off0, jnp.asarray(last_idx), pool.cache)
+                pool.count_inplace("prefill", donated)
                 if dev_timer.enabled:
                     t_dev = tel.now
                     jax.block_until_ready((logits, pool.cache))
@@ -1618,9 +1624,12 @@ class ContinuousCascadeEngine:
                                     step = n_steps // self.steps_per_sync
                                     with span("decode_dispatch", step=step,
                                               rows=len(decoding), **pages):
+                                        donated = jax.tree.leaves(pool.cache)
                                         pool.cache, state = step_fn(
                                             self.small.params, pool.cache,
                                             state, *tables)
+                                    if paged:
+                                        pool.count_inplace("step", donated)
                                     if dev_timer.enabled:
                                         t_dev = tel.now
                                         jax.block_until_ready(state)
@@ -1717,6 +1726,7 @@ class ContinuousCascadeEngine:
                          shared_tokens=n_shared_tokens,
                          shared_blocks=pool.shared_blocks_total,
                          cow_clones=pool.cow_clones,
+                         pool_inplace=dict(pool.inplace_total),
                          paged_kernel=use_kernel)
             if pressure is not None:
                 stats.update(oversubscribe=pressure.oversubscribe,

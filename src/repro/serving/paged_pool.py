@@ -5,10 +5,13 @@ request pays for the longest request's memory and mixed-length traffic
 caps batch size. `PagedCachePool` instead stores the cache in fixed-size
 **blocks** of `block_size` tokens shared by all slots:
 
-  * every attention-cache leaf becomes ``[n_blocks + 1, block_size, ...]``
-    (physical block 0 is a shared **trash block** that is never allocated
-    — unmapped page-table entries and writes from inactive decode rows
-    land there harmlessly);
+  * every attention-cache leaf becomes ``[L, n_blocks + 1, block_size, F]``
+    — stacked over its layer group, features flat (GQA: F = KV*head_dim;
+    MLA: kv_lora or rope_dim), the paged kernels' own view, so the
+    jitted programs update it in place with no relayout (the decode step
+    and the prefill chunk take it donated). Physical block 0 is a shared
+    **trash block** that is never allocated — unmapped page-table
+    entries and writes from inactive decode rows land there harmlessly;
   * each slot owns a row of the **page table** ``[n_slots, M]`` mapping
     its logical block ``m`` (tokens ``[m*block_size, (m+1)*block_size)``)
     to a physical block id, 0 meaning unmapped;
@@ -162,10 +165,35 @@ def validate_pageable(cfg: ModelConfig, max_len: int) -> None:
                 f"max_len {max_len}); use the slot backend")
 
 
+def paged_layout(cfg: ModelConfig, n_blocks: int, block_size: int,
+                 dtype=None) -> Tuple[Any, Any]:
+    """(shapes, block axes) of a paged pool's leaves: `init_cache` with
+    batch = blocks gives `[L, n_blocks+1, block_size, ...]` per leaf (block
+    0 = trash); the features after `block_size` are flattened into the
+    kernels' view `[L, n_blocks+1, block_size, F]`. `shapes` is a tree of
+    `jax.ShapeDtypeStruct`, so a pool's programs can be compiled without
+    one."""
+    dtype = dtype or cfg.cdtype()
+    abstract = tfm.init_cache(cfg, n_blocks + 1, block_size, abstract=True)
+
+    def _axes(a):
+        b = a.logical_axes.index("batch")
+        s = a.logical_axes.index("cache_seq")
+        assert s == b + 1, "paged gather assumes [block, block_size] adjacency"
+        return b
+    axes = jax.tree.map(_axes, abstract, is_leaf=_is_abstract)
+    shapes = jax.tree.map(
+        lambda a, b: jax.ShapeDtypeStruct(
+            a.shape[:b + 2] + (math.prod(a.shape[b + 2:]),), dtype),
+        abstract, axes, is_leaf=_is_abstract)
+    return shapes, axes
+
+
 def gather_pages(cache: Any, tables: jnp.ndarray, block_axes: Any) -> Any:
     """Tree-wide page-table gather: paged cache -> dense per-slot view
-    ``[..., n_slots_in_tables, M*block_size, ...]``. Host-side test/debug
-    helper; the jitted paths gather leaf-wise inside attention."""
+    ``[..., n_slots_in_tables, M*block_size, F]`` (features flat, as the
+    pool keeps them). Host-side test/debug helper; the jitted paths
+    gather leaf-wise inside attention."""
     def one(leaf, ax):
         if ax == 0:
             return gather_blocks(leaf, tables)
@@ -212,19 +240,13 @@ class PagedCachePool:
         # logical blocks a single slot may address (covers max_len plus
         # one block of slack for padded-chunk clamping)
         self.max_blocks = math.ceil(max_len / block_size) + 1
-        # physical storage: init_cache with batch = blocks gives exactly
-        # the paged layout [n_blocks+1, block_size, ...] per leaf
-        # (block 0 = trash)
-        self.cache = tfm.init_cache(cfg, n_blocks + 1, block_size,
-                                    dtype=dtype or cfg.cdtype())
-        abstract = tfm.init_cache(cfg, n_blocks + 1, block_size,
-                                  abstract=True)
-        def _axes(a):
-            b = a.logical_axes.index("batch")
-            s = a.logical_axes.index("cache_seq")
-            assert s == b + 1, "paged gather assumes [block, block_size] adjacency"
-            return b
-        self.block_axes = jax.tree.map(_axes, abstract, is_leaf=_is_abstract)
+        shapes, self.block_axes = paged_layout(cfg, n_blocks, block_size,
+                                               dtype)
+        self.cache = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                  shapes)
+        # dispatches, by program, that updated the pool in place (their
+        # donated leaves read deleted after the call): `count_inplace`
+        self.inplace_total = {"step": 0, "prefill": 0}
 
         # host bookkeeping
         self.tables = np.zeros((n_slots, self.max_blocks), np.int32)
@@ -743,6 +765,21 @@ class PagedCachePool:
                       "lifetime host swap-tier transfers", ("dir",))
         s.labels(dir="out").set_fn(lambda: self.swap_outs)
         s.labels(dir="in").set_fn(lambda: self.swap_ins)
+
+        i = reg.gauge("serving_pool_inplace_total",
+                      "dispatches that updated the pool in place (XLA "
+                      "honoured the donation)", ("fn",))
+        for fn in self.inplace_total:
+            i.labels(fn=fn).set_fn(lambda fn=fn: self.inplace_total[fn])
+
+    def count_inplace(self, fn: str, donated) -> None:
+        """Count a dispatch of program `fn` ("step" or "prefill") that
+        updated the pool in place: XLA honoured the donation when every
+        leaf handed to it (`donated`, the pool's leaves before the call)
+        reads deleted afterwards. A host check, no device sync; a count
+        below the dispatches means a program copied the pool."""
+        if all(a.is_deleted() for a in donated):
+            self.inplace_total[fn] += 1
 
     def active_prefix_blocks(self, n_tokens: int) -> int:
         """Logical blocks needed to cover `n_tokens` cache entries,

@@ -334,6 +334,68 @@ def test_active_prefix_gather_equivalence():
                                           np.asarray(c_full[leaf]))
 
 
+# the serving pool's layout: every layer of a group stacked, features flat
+STACK_LAYERS = 3
+
+
+def _stacked_cache(key, leaves):
+    """A stacked pool `{name: [L, 8, 4, F]}` of random values."""
+    ks = jax.random.split(key, len(leaves))
+    return {name: jax.random.normal(k, (STACK_LAYERS, 8, 4, F)) * .1
+            for k, (name, F) in zip(ks, leaves.items())}
+
+
+def _mla_layer(key):
+    cfg = reduced(get_config("deepseek-v2-236b"))
+    cfg = cfg.replace(moe=None, family="dense", n_layers=2)
+    ac = tfm.attn_config(cfg)
+    params = jax.tree.map(lambda a: a[0],
+                          tfm.init_params(cfg, key)["blocks"]["dense"]["attn"])
+    cache = _stacked_cache(jax.random.fold_in(key, 7),
+                           {"ckv": ac.kv_lora, "kr": ac.rope_dim})
+    x = jax.random.normal(jax.random.fold_in(key, 8), (4, 1, cfg.d_model)) * .3
+    return ac, params, cache, x
+
+
+@pytest.mark.parametrize("layer", [0, STACK_LAYERS - 1])
+@pytest.mark.parametrize("kind", ["gqa", "gqa_window", "mla"])
+def test_stacked_pool_kernel_vs_fallback(kind, layer):
+    """The serving pool's stacked leaves `[L, N, bs, F]` with the layer as
+    a traced index: the kernels' write plus flash-decode matches the XLA
+    fallback's scatter plus gather (outputs to epsilon on mapped rows, the
+    written pools bit for bit), and neither changes a block of any other
+    layer."""
+    key = jax.random.PRNGKey(20)
+    if kind == "mla":
+        ac, params, cache, x = _mla_layer(key)
+        fwd = attn.mla_decode
+    else:
+        ac, params, _, x = _gqa_layer(key)
+        if kind == "gqa_window":
+            ac = dataclasses.replace(ac, sliding_window=6)
+        F = ac.n_kv_heads * ac.head_dim
+        cache = _stacked_cache(jax.random.fold_in(key, 7), {"k": F, "v": F})
+        fwd = attn.gqa_decode
+    ctx = ParallelContext()
+    tables, pos = jnp.asarray(TABLES), jnp.asarray(POS)
+    run = jax.jit(lambda c, lyr, k: fwd(params, ac, x, pos, c, ctx,
+                                        pages=tables, paged_kernel=k,
+                                        layer_index=lyr),
+                  static_argnums=2)
+    y_f, c_f = run(cache, jnp.int32(layer), False)
+    y_k, c_k = run(cache, jnp.int32(layer), True)
+    np.testing.assert_allclose(np.asarray(y_k[:3]), np.asarray(y_f[:3]),
+                               atol=1e-5)
+    assert np.isfinite(np.asarray(y_k)).all()
+    others = [i for i in range(STACK_LAYERS) if i != layer]
+    for name, old in cache.items():
+        np.testing.assert_array_equal(np.asarray(c_k[name]),
+                                      np.asarray(c_f[name]))
+        new = np.asarray(c_k[name])
+        np.testing.assert_array_equal(new[others], np.asarray(old)[others])
+        assert not np.array_equal(new[layer], np.asarray(old)[layer])
+
+
 # ---------------------------------------------------------------------------
 # Engine-level: kernel path + batched prefill greedy parity
 # ---------------------------------------------------------------------------

@@ -17,8 +17,10 @@ from repro.models.attention import gather_blocks
 from repro.serving import (CascadeEngine, ContinuousCascadeEngine,
                            ModelRunner, PagedCachePool, SlotCachePool,
                            make_requests, poisson_arrivals)
+from repro.serving.obs import ObsConfig, Observability
 from repro.serving.paged_pool import gather_pages
 from repro.serving.request import DONE
+from repro.sharding import ParallelContext
 
 
 @pytest.fixture(scope="module")
@@ -121,8 +123,9 @@ def test_gather_blocks_matches_manual():
 
 def test_page_table_gather_equals_dense_slot_read(tiny_cfg):
     """Write the same prefilled rows into a dense slot pool and (block by
-    block) into a paged pool; the page-table gather must reproduce the
-    dense per-slot view exactly."""
+    block) into a paged pool, whose leaves keep each token's features
+    flat; the page-table gather must reproduce the dense per-slot view
+    exactly."""
     bs, max_len = 4, 12
     paged = PagedCachePool(tiny_cfg, n_slots=2, n_blocks=6, block_size=bs,
                            max_len=max_len)
@@ -146,7 +149,8 @@ def test_page_table_gather_equals_dense_slot_read(tiny_cfg):
                 sl_p = (slice(None),) * ax + (blk,)
                 sl_r = (slice(None),) * ax + (slot,
                                               slice(m * bs, (m + 1) * bs))
-                paged_leaf = paged_leaf.at[sl_p].set(row_leaf[sl_r])
+                paged_leaf = paged_leaf.at[sl_p].set(
+                    row_leaf[sl_r].reshape(paged_leaf[sl_p].shape))
         return paged_leaf
     paged.cache = jax.tree.map(scatter, paged.cache, row, paged.block_axes)
 
@@ -157,9 +161,8 @@ def test_page_table_gather_equals_dense_slot_read(tiny_cfg):
         d_np = np.moveaxis(np.asarray(d), ax, 0) if ax else np.asarray(d)
         g_np = np.moveaxis(np.asarray(g), ax, 0) if ax else np.asarray(g)
         # gathered view is max_blocks*bs long; valid prefix must match
-        np.testing.assert_array_equal(g_np[:, :max_len] if ax == 0
-                                      else g_np[:, :, :max_len],
-                                      d_np if ax == 0 else d_np)
+        g_np = g_np[:, :max_len] if ax == 0 else g_np[:, :, :max_len]
+        np.testing.assert_array_equal(g_np.reshape(d_np.shape), d_np)
 
 
 # ---------------------------------------------------------------------------
@@ -302,6 +305,70 @@ def test_mla_paged_parity():
                                    block_size=4, prefill_chunk=3)
     cres = cont.run(make_requests(prompts, 3), 3)
     np.testing.assert_array_equal(cres.tokens, sres.tokens)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_paged_step_unrolled_layers_match_scan(tiny_cfg, kernel):
+    """With `scan_layers` off the layers run as a Python loop; the paged
+    pool still rides through them whole, each layer writing its own
+    blocks: one decode step's logits and pool equal the scanned step's
+    (to fp32 rounding: the two compile to different fusions)."""
+    pool = PagedCachePool(tiny_cfg, n_slots=2, n_blocks=6, block_size=4,
+                          max_len=12)
+    for slot in range(2):
+        pool.alloc()
+        pool.reserve(slot, 12)
+        pool.ensure_mapped(slot, 9)
+    params = tfm.init_params(tiny_cfg, jax.random.PRNGKey(4))
+    cache = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(5), a.shape,
+                                    a.dtype), pool.cache)
+    tok, pos = jnp.asarray([3, 7], jnp.int32), jnp.asarray([8, 5], jnp.int32)
+    tables = pool.tables_device()
+    out = {}
+    for scan in (True, False):
+        cfg = tiny_cfg.replace(scan_layers=scan)
+        out[scan] = jax.jit(lambda c, cfg=cfg: tfm.decode_step(
+            params, cfg, tok, pos, c, ParallelContext(),
+            pages=tables, paged_kernel=kernel))(cache)
+    np.testing.assert_allclose(np.asarray(out[False][0]),
+                               np.asarray(out[True][0]), rtol=1e-5,
+                               atol=1e-5)
+    for a, b in zip(jax.tree.leaves(out[False][1]),
+                    jax.tree.leaves(out[True][1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_donated_pool_kernel_parity_and_inplace_count(runners):
+    """The decode step and the prefill chunk take the pool donated: greedy
+    outputs stay bit-exact between the Pallas and the XLA paths (and with
+    standalone generation), and `serving_pool_inplace_total` counts every
+    step and prefill dispatch, i.e. XLA honoured each donation."""
+    small, large, _ = runners
+    prompts = ragged_prompts(jax.random.PRNGKey(19), [5, 9, 4, 12, 7],
+                             small.cfg.vocab_size)
+    out = {}
+    for kernel in (False, True):
+        eng = ContinuousCascadeEngine(small, large, n_slots=3, tau=-1e9,
+                                      early_exit=False, backend="paged",
+                                      block_size=4, prefill_chunk=4,
+                                      steps_per_sync=2, paged_kernel=kernel)
+        obs = Observability(ObsConfig())
+        res = eng.run(make_requests(prompts, 5), 5, obs=obs)
+        inplace = obs.registry.snapshot()["serving_pool_inplace_total"]
+        obs.finish()
+        n_steps = res.steps // eng.steps_per_sync
+        assert n_steps > 0 and res.stats["prefill_dispatches"] > 0
+        assert inplace == {("prefill",): res.stats["prefill_dispatches"],
+                           ("step",): n_steps}
+        assert res.stats["pool_inplace"] == {
+            "step": n_steps, "prefill": res.stats["prefill_dispatches"]}
+        out[kernel] = res
+    np.testing.assert_array_equal(out[True].tokens, out[False].tokens)
+    for r in out[True].requests:
+        t, _ = small.generate(r.prompt[None, :], r.prompt_len, 5)
+        np.testing.assert_array_equal(r.tokens, t[0])
 
 
 # ---------------------------------------------------------------------------
